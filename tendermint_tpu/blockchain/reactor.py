@@ -27,6 +27,7 @@ from tendermint_tpu.p2p.peer import Peer
 from tendermint_tpu.p2p.switch import Reactor
 from tendermint_tpu.blockchain.pool import BlockPool
 from tendermint_tpu.state.execution import apply_block
+from tendermint_tpu.telemetry import launchlog as _launchlog
 from tendermint_tpu.types.block import Block
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
@@ -378,13 +379,18 @@ class BlockchainReactor(Reactor):
                 return "redo"
             entries.append((block_ids[i], blocks[i].header.height, commit))
         try:
-            handle = self.state.validators.verify_commit_batched_async(
-                self.state.chain_id,
-                entries,
-                verifier=self.verifier,
-                queue=self._queue(),
-                consumer="fastsync",
-            )
+            # the launch record names the heights it covers, so the
+            # ledger can say which backend answered for each height
+            with _launchlog.tag(
+                height_lo=entries[0][1], height_hi=entries[-1][1]
+            ):
+                handle = self.state.validators.verify_commit_batched_async(
+                    self.state.chain_id,
+                    entries,
+                    verifier=self.verifier,
+                    queue=self._queue(),
+                    consumer="fastsync",
+                )
         except ValidationError:
             # malformed commit caught during prep — same treatment as a
             # failed verdict on this window

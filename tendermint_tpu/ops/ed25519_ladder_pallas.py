@@ -20,10 +20,10 @@ Shape of the computation per lane:
   table path's encode-and-compare (`_finish_encode_compare`) — R is
   never decompressed, halving the XLA prologue's sequential field work.
 
-A width-2 windowed variant (127 steps, 16-entry table) was measured
-SLOWER on this device (69k vs 91k @8k): the 16-way masked-sum select +
-int16 conversions cost more than the madds it saves. Bit-serial with a
-4-way select is the keeper (docs/PLATFORM_NOTES.md).
+A width-2 windowed variant (127 steps, 16-entry table) was tried and
+dropped: its 16-way masked-sum select + int16 conversions cost more
+than the madds it saves (not re-measured on v5e). Bit-serial with a
+4-way select is the keeper.
 
 Tiles are as wide as VMEM allows (up to 4096 lanes -> (8, 512) planes):
 fewer, fatter grid steps amortize Mosaic's per-step overhead the same

@@ -34,9 +34,8 @@ Two device pipelines share these tables:
 * the FUSED pallas kernel (production, TPU): selection happens inside
   the kernel from int16 table blocks, the accumulator lives in VMEM,
   and the table streams from HBM exactly once per launch — see the
-  "fused select+accumulate" section below and
-  docs/PLATFORM_NOTES.md for measured rates (1.44M verifies/s at
-  K=64 x 10,240 on the bench chip);
+  "fused select+accumulate" section below (rates on v5e: not
+  measured; chip_smoke.py checks bits, not speed);
 * the materialized-entries path (XLA scan or the earlier pallas madd
   chain): portable, used for shapes that don't tile the fused kernel
   (single commits, tiny valsets) and by the CPU test mesh.
@@ -411,9 +410,8 @@ def build_key_tables(pub_bytes: np.ndarray, chunk: int = 2048):
 
     On TPU every chunk pads to the FULL chunk size so all builds of any
     N share ONE compiled executable — a fresh pow2 shape would pay its
-    own ~20 s per-process program upload (docs/PLATFORM_NOTES.md), while
-    the extra pad columns cost <1 s of device work. Off-TPU (tests) the
-    pad stays at the next power of two."""
+    own compile, while the pad columns cost only device work. Off-TPU
+    (tests) the pad stays at the next power of two."""
     n = pub_bytes.shape[0]
     on_tpu = jax.default_backend() == "tpu"
     tbls, oks = [], []
@@ -629,7 +627,9 @@ def _sum_entries_pallas(ent):
         out_specs=pl.BlockSpec(
             (1, 80, 8, 128), lambda i, t: (i, 0, 0, 0), memory_space=pltpu.VMEM
         ),
-        out_shape=jax.ShapeDtypeStruct((tiles, 80, 8, 128), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct(
+            (tiles, 80, 8, 128), jnp.int32, vma=jax.typeof(e).vma
+        ),
         scratch_shapes=[pltpu.VMEM((80, 8, 128), jnp.int32)],
     )(e)
     # (tiles, 80, 8, 128) -> 4 coords of (B, 20)
@@ -644,8 +644,7 @@ from functools import partial  # noqa: E402
 #
 # The materialized-entries pipeline above streams a (96, B, 60) int32
 # array through HBM twice (write at selection, read at accumulation) —
-# 7.6 GB of traffic at the K=16 x 10,240 bench shape on a device
-# measured at ~25 GB/s (docs/PLATFORM_NOTES.md). The fused kernel
+# 7.6 GB of traffic at the K=16 x 10,240 bench shape. The fused kernel
 # removes that array entirely: each grid step selects its operands
 # INSIDE the kernel from the (int16, read-once) table block and feeds
 # them straight to the VMEM-resident mixed-add accumulator. To make
@@ -812,7 +811,9 @@ def _fused_chain_pallas(a_tables, digits, v_tile, c_tile, interpret=False):
         out_specs=pl.BlockSpec(
             (1, 80, 8, w), lambda i, t: (i, 0, 0, 0), memory_space=pltpu.VMEM
         ),
-        out_shape=jax.ShapeDtypeStruct((tiles, 80, 8, w), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct(
+            (tiles, 80, 8, w), jnp.int32, vma=jax.typeof(dig).vma
+        ),
         scratch_shapes=[
             pltpu.VMEM((80, 8, w), jnp.int32),
             pltpu.VMEM((60, 8, w), jnp.int32),

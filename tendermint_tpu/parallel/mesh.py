@@ -28,12 +28,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from tendermint_tpu.ops.ed25519_kernel import verify_kernel
 from tendermint_tpu.ops.ed25519_tables import verify_tables_kernel

@@ -10,8 +10,8 @@ anchoring, RPC/light-client certifiers — and, since the ingress
 pipeline, mempool CheckTx windows as the fifth, `consumer="mempool"`,
 and, since the light-client serving layer, bisection-walk rounds as
 the sixth, `consumer="lightclient"` — one batched launch per bisection
-round, `lightclient/bisect.py`) pays the fixed ~86 ms device launch
-(docs/PLATFORM_NOTES.md) on its own small, partially-duplicate batch.
+round, `lightclient/bisect.py`) pays a device launch's fixed cost on
+its own small, partially-duplicate batch.
 Two layers remove both costs:
 
 * `VerifiedSigCache` — a sharded, thread-safe LRU of PROVEN triples,

@@ -33,9 +33,9 @@ def _observe_hash(
     # record (host micro-roots outside a dispatch handle record nothing)
     _launchlog.observe(kind, backend, leaves, seconds)
 
-# Below this leaf count the ~60 ms per-launch dispatch floor
-# (docs/PLATFORM_NOTES.md) makes host hashlib strictly faster; the device
-# tree only wins on big blocks (BASELINE config 4 is 65k leaves).
+# Below this leaf count host hashlib answers; the device tree is for big
+# blocks (BASELINE config 4 is 65k leaves). The value is not measured on
+# v5e (ROADMAP Queue 1 item 2(d) owns the retune).
 DEVICE_MIN_LEAVES = int(os.environ.get("TENDERMINT_TPU_MIN_DEVICE_LEAVES", "8192"))
 
 

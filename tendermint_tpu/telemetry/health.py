@@ -35,9 +35,11 @@ is exactly when an operator wants per-message attribution, so the next
 status itself still doesn't change (see above).
 
 The **device** section (device observatory, telemetry/launchlog.py) is
-reported under the same discipline: mesh width active/total, a
-compile-in-progress flag, and seconds since the last successful device
-launch — operator signals, never folded into the routing status.
+reported under the same discipline: the platform, device kind and
+device count JAX resolved for this process (what tells a chip run from
+a quiet host fallback), mesh width active/total, a compile-in-progress
+flag, and seconds since the last successful device launch — operator
+signals, never folded into the routing status.
 
 The **pipeline** section (cross-height pipelined consensus,
 consensus/state.py) follows suit: whether height H's apply is in
@@ -133,10 +135,33 @@ def _mesh_check(node) -> dict:
     }
 
 
+def _resolved_devices() -> dict:
+    """Platform, device kind and device count as JAX reports them in
+    this process. Reads the `jax` module only if something already
+    imported it (every composed node has: `default_verifier()` asks it
+    for the backend), and never the kernel modules."""
+    import sys as _sys
+
+    none = {"platform": None, "device_kind": None, "device_count": 0}
+    jax = _sys.modules.get("jax")
+    if jax is None:
+        return none
+    try:
+        devices = jax.devices()
+    except Exception as e:
+        return {**none, "error": f"{type(e).__name__}: {e}"[:120]}
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
 def _device_section(node) -> dict:
-    """The device observatory's health view: mesh width (active/total
-    from the verifier snapshot — the same node-local object the mesh
-    check reads), whether a compiled-step build is in flight right now,
+    """The device observatory's health view: the devices JAX resolved,
+    mesh width (active/total from the verifier snapshot — the same
+    node-local object the mesh check reads), whether a compiled-step
+    build is in flight right now,
     and the age of the last successful device launch. REPORTED, never
     folded into the status (same discipline as the finality SLO): a
     compile stall or a quiet device is an operator signal, not a
@@ -153,6 +178,7 @@ def _device_section(node) -> dict:
             snap = {}
     mesh = snap.get("mesh") if isinstance(snap.get("mesh"), dict) else {}
     out: dict = {
+        **_resolved_devices(),
         "mesh_active": int(mesh.get("devices_active", 0)) if mesh else None,
         "mesh_total": int(mesh.get("devices_total", 0)) if mesh else None,
     }

@@ -161,6 +161,21 @@ XLA_CACHE_ENABLED = Gauge(
     "tendermint_xla_persistent_cache_enabled",
     "1 when the persistent XLA executable cache is active",
 )
+XLA_CACHE_EVENTS = Counter(
+    "tendermint_xla_persistent_cache_events_total",
+    "Persistent executable cache lookups by outcome, as JAX reports "
+    "them: a restarted process that finds its executables shows hits",
+    labelnames=("event",),
+)
+# `fun` is the jitted function's name — bounded by the code, not by
+# traffic; each compiled shape of one function is one observation
+XLA_COMPILE_SECONDS = Histogram(
+    "tendermint_xla_compile_seconds",
+    "Backend compile (or cache retrieval) seconds per jitted function, "
+    "as JAX reports them — apart from any launch's run time",
+    labelnames=("fun",),
+    buckets=LATENCY_BUCKETS,
+)
 
 # -- device observatory (telemetry/launchlog.py, tools/device_report.py) ------
 #
@@ -400,6 +415,7 @@ for _direction in ("shrink", "restore"):
 for _result in ("hit", "miss"):
     MESH_COMPILE.labels(result=_result).inc(0)
     TABLE_DEVICE_CACHE.labels(result=_result).inc(0)
+    XLA_CACHE_EVENTS.labels(event=_result).inc(0)
 for _kind in ("verify", "hash", "tables", "leaf_hashes"):
     for _state in ("useful", "padded", "cached"):
         LAUNCH_ROWS.labels(kind=_kind, state=_state).inc(0)
@@ -539,7 +555,7 @@ for _result in ("ok", "corrupt", "timeout"):
 # an alertable provider offense. `mode` distinguishes
 # the legacy header-by-header walk (sequential — the
 # InquiringCertifier baseline) from the skipping walk (bisect).
-# `kind` on the proofs-served counter is the fixed query taxonomy
+# `kind` on the proofs-served counter is the fixed query vocabulary
 # (full_commit / commit / validators / tx / abci_query) — never
 # heights or peer ids.
 
@@ -616,7 +632,7 @@ P2P_SEND_WAIT = Histogram(
     buckets=LATENCY_BUCKETS,
 )
 # Adversarial-input defense (p2p/score.py + Switch.report_misbehavior):
-# `kind` is the fixed offense taxonomy (bad_frame/oversize_frame/
+# `kind` is the fixed offense vocabulary (bad_frame/oversize_frame/
 # bad_msg/bad_sig/bad_vote/forged_block/forged_fullcommit/
 # bad_evidence/flood) — never
 # peer ids (per-peer scores live in the scorer's diagnostics snapshot).
@@ -702,7 +718,7 @@ GOSSIP_KINDS = (
     "pong",
     "other",
 )
-# The silent-dedup taxonomy: kinds whose duplicate deliveries used to
+# The silent-dedup vocabulary: kinds whose duplicate deliveries used to
 # vanish (VoteSet exact-dup adds, PartSet already-have parts, mempool
 # dup-cache hits on re-arrival, evidence-pool re-offers).
 GOSSIP_REDUNDANT_KINDS = ("vote", "block_part", "tx", "evidence")
@@ -739,7 +755,7 @@ for _kind in GOSSIP_REDUNDANT_KINDS:
 
 # -- WAN link chaos + scenario engine (p2p/transport.py, testing/) ------------
 #
-# `result` on the link-send counter is the fixed delivery taxonomy of
+# `result` on the link-send counter is the fixed delivery vocabulary of
 # the chaos layer: delivered (immediate), delayed (rode the delivery
 # wheel), dup (extra copy scheduled), dropped, partitioned. No per-link
 # labels — a WAN harness runs O(n^2) links and peer-pair series would

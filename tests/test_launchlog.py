@@ -559,6 +559,28 @@ class TestHealthDeviceSection:
         assert h["device"]["last_launch_age_s"] < 5.0
         assert h["status"] == "ok"
 
+    def test_resolved_devices_reported(self, monkeypatch):
+        """The section names the platform / device_kind / count JAX
+        resolved (what tells a chip run from a quiet host fallback) and
+        reads them from the already-imported jax module only."""
+        import sys
+
+        import jax
+
+        from tendermint_tpu.telemetry import health
+
+        dev = health.build_health(_stub_node())["device"]
+        assert dev["platform"] == jax.devices()[0].platform == "cpu"
+        assert dev["device_kind"] == jax.devices()[0].device_kind
+        assert dev["device_count"] == len(jax.devices())
+        # a process that never imported jax reports none, and the probe
+        # does not import it (nor the kernel modules) to find out
+        monkeypatch.delitem(sys.modules, "jax")
+        assert health._resolved_devices() == {
+            "platform": None, "device_kind": None, "device_count": 0,
+        }
+        assert "jax" not in sys.modules
+
     def test_meshless_node_reports_none_widths(self):
         from tendermint_tpu.telemetry.health import build_health
 

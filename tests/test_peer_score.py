@@ -84,7 +84,7 @@ class TestPeerScorer:
         assert s.debit("liar", "forged_block")
         assert s.is_banned("liar")
 
-    def test_weights_cover_the_registered_taxonomy(self):
+    def test_weights_cover_the_registered_kinds(self):
         for kind in (
             "bad_frame",
             "oversize_frame",
