@@ -1,0 +1,168 @@
+"""What decides `correct`, outside the window (copies of `chip_smoke.py`'s
+checks, cut to what a catch-up run can show).
+
+1. A seeded sample of applied heights, read back over RPC: block hash,
+   `data_hash`, `app_hash`, `validators_hash` and the commit's block id
+   equal the generator's record; `reference.py` checks the same sample
+   with `hashlib` and the host ed25519 library alone.
+2. The last applied height's write is read back through `abci_query`.
+3. Every verify launch of k*n >= 512 lanes was answered by a device
+   backend, no breaker moved, no call fell back to the host.
+4. The node's `/health` names the device JAX gave this process.
+5. The planted fault: the chain's last 16-commit window (heights the node
+   never reaches in a run) with one seeded bit of one signature's R
+   flipped goes through `ValidatorSet.verify_commit_batched` on the
+   process's own verifier and is refused at exactly that height and
+   validator; the clean window passes.
+
+Limits: every comparison is exact (limit 0). Each line printed gives the
+number compared beside its limit.
+"""
+
+from __future__ import annotations
+
+import random
+import re
+
+from . import chain as chainlib
+from . import reference, rpc
+from .ledger import DEVICE_BACKENDS, VERIFY_KINDS
+
+DEVICE_MIN_LANES = 512  # services/verifier.py DEVICE_MIN_BATCH
+REFUSED = re.compile(r"validator (\d+) \(batch entry (\d+), height (\d+)\)")
+
+
+def host_fallbacks(launches: list[dict], metrics: dict) -> int:
+    """Verify launches of k*n >= 512 lanes a host backend answered (or
+    that failed), plus the spine's own fallback and dispatch-failure
+    counters."""
+    n = 0
+    for r in launches:
+        if r.get("kind") not in VERIFY_KINDS:
+            continue
+        lanes = int(r.get("rows", 0)) + int(r.get("rows_cached", 0))
+        if lanes >= DEVICE_MIN_LANES and (r.get("error") or r.get("backend") not in DEVICE_BACKENDS):
+            n += 1
+    n += int(rpc.metric(metrics, "tendermint_device_fallback_calls_total", kind="verify"))
+    n += int(rpc.metric(metrics, "tendermint_device_dispatch_failures_total", kind="verify"))
+    return n
+
+
+def sample_heights(seed: int, top: int, n: int) -> list[int]:
+    rng = random.Random(seed ^ 0xC0FFEE)
+    if top <= n:
+        return list(range(1, top + 1))
+    return sorted({top, *rng.sample(range(1, top), n - 1)})
+
+
+def check_sample(port: int, record, heights: list[int]) -> tuple[list[str], int]:
+    bad: list[str] = []
+    pubkeys = [bytes.fromhex(p) for p in record.pubkeys]
+    compared = 0
+    for h in heights:
+        blk = rpc.call(port, f"block?height={h}")["block"]
+        hdr = blk["header"]
+        want = {
+            "hash": record.block_hash[h - 1],
+            "data_hash": record.data_hash[h - 1],
+            "app_hash": record.app_hash[h - 2] if h >= 2 else "",
+            "validators_hash": record.validators_hash,
+        }
+        for field, value in want.items():
+            compared += 1
+            if hdr[field] != value:
+                bad.append(f"block {h}: served {field} {hdr[field][:16]} != source {value[:16]}")
+        bad += reference.check_block(blk)
+        com = rpc.call(port, f"commit?height={h}")["commit"]
+        compared += 1
+        if com["block_id"]["hash"] != record.block_hash[h - 1]:
+            bad.append(f"commit {h}: block id differs from the source chain")
+        bad += reference.check_commit(
+            record.chain_id, h, record.block_hash[h - 1], com, pubkeys, record.powers
+        )
+    return bad, compared
+
+
+def check_last_write(port: int, record) -> list[str]:
+    """The write of the last applied height, read back. The node keeps
+    syncing while we ask, and a fixed key space is rewritten by every
+    block: any height applied between the two status reads may answer."""
+    h1 = int(rpc.call(port, "status")["sync_info"]["latest_block_height"])
+    key, _ = record.last_write[h1 - 1]
+    got = rpc.call(port, f"abci_query?data={key}")
+    h2 = int(rpc.call(port, "status")["sync_info"]["latest_block_height"])
+    value = got.get("value", "")
+    allowed = {record.last_write[h - 1][1] for h in range(h1, h2 + 1) if record.last_write[h - 1][0] == key}
+    if value not in allowed:
+        return [f"abci_query: key {bytes.fromhex(key)!r} holds {value!r}, not the write of heights {h1}..{h2}"]
+    return []
+
+
+def validator_set(record):
+    """The chain's validator set as the program's type, from the record."""
+    from tendermint_tpu.crypto.keys import PubKey
+    from tendermint_tpu.types import Validator, ValidatorSet
+
+    keys = [PubKey(bytes.fromhex(p)) for p in record.pubkeys]
+    return ValidatorSet(
+        [Validator(address=k.address, pub_key=k, voting_power=w) for k, w in zip(keys, record.powers)]
+    )
+
+
+def check_planted_fault(record, seed: int) -> list[str]:
+    from tendermint_tpu.services.verifier import default_verifier
+    from tendermint_tpu.types.errors import ValidationError
+
+    valset = validator_set(record)
+    entries = record.tail_entries()
+    rng = random.Random(seed ^ 0xFA17)
+    at = rng.randrange(len(entries))
+    bid, height, commit = entries[at]
+    forged, idx = chainlib.tamper(commit, seed)
+    tampered = list(entries)
+    tampered[at] = (bid, height, forged)
+    verifier = default_verifier()
+    bad: list[str] = []
+    try:
+        valset.verify_commit_batched(record.chain_id, tampered, verifier)
+        bad.append(
+            f"planted fault: a window with one flipped signature bit (height {height}, "
+            f"validator {idx}) was accepted"
+        )
+    except ValidationError as e:
+        m = REFUSED.search(str(e))
+        if not m or (int(m.group(1)), int(m.group(3))) != (idx, height):
+            bad.append(f"planted fault at height {height}, validator {idx}: refused as {e}")
+    try:
+        valset.verify_commit_batched(record.chain_id, entries, verifier)
+    except ValidationError as e:
+        bad.append(f"planted fault: the clean window was refused: {e}")
+    return bad
+
+
+def run_checks(*, port, record, seed, h_close, launches, metrics, health, devices, log):
+    failures: list[str] = []
+    heights = sample_heights(seed, h_close, 32)
+    bad, compared = check_sample(port, record, heights)
+    log(f"check sample: {len(heights)} heights, {compared} fields and {len(heights)} commits compared, {len(bad)} differ (limit 0)")
+    failures += bad
+    bad = check_last_write(port, record)
+    log(f"check last write: {len(bad)} differ (limit 0)")
+    failures += bad
+    fallbacks = host_fallbacks(launches, metrics)
+    log(f"check device answers: {fallbacks} host answers or faults where a device answer was due (limit 0)")
+    if fallbacks:
+        failures.append(f"{fallbacks} verify launches of >= {DEVICE_MIN_LANES} lanes were not answered by the device")
+    verify_launches = [r for r in launches if r.get("kind") in VERIFY_KINDS and r.get("backend") in DEVICE_BACKENDS]
+    if devices[0].platform != "cpu" and not verify_launches:
+        failures.append("the launch ledger holds no device verify launch")
+    dev = health.get("device", {})
+    got = (dev.get("platform"), dev.get("device_kind"), dev.get("device_count"))
+    want = (devices[0].platform, devices[0].device_kind, len(devices))
+    log(f"check health.device: {got} against {want}")
+    if got != want:
+        failures.append(f"health.device says {got}, JAX gave this process {want}")
+    bad = check_planted_fault(record, seed)
+    log(f"check planted fault: {len(bad)} wrong verdicts (limit 0)")
+    failures += bad
+    return failures, fallbacks

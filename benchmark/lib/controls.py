@@ -1,0 +1,31 @@
+"""Controls: the program with one guarantee broken, to show that `correct`
+can come out false. Used by `benchmark/tests` and by the on-chip control
+runs (`run.py --control <name>`); a benchmark run never installs one.
+
+* `accept_all`: the process's verifier accepts every present signature, so
+  a commit passes without 2/3 of the power in *valid* signatures.
+* `host_answers` (in `drivers/catchup.py`): every commit-shaped batch is
+  answered by the host library where a device answer is due.
+* `apphash_off_by_one` (in `drivers/catchup.py`): the record the node is
+  held to has every app hash one height off, as a node that applied the
+  wrong state would show.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+
+def install_accept_all() -> None:
+    from tendermint_tpu.services.verifier import BatchVerifier, set_default_verifier
+
+    class AcceptAll(BatchVerifier):
+        def verify_batch(self, triples):
+            return np.ones(len(triples), dtype=bool)
+
+        def verify_commits(self, pubkeys, commits, force_fused=None):
+            return np.array(
+                [[s is not None for s in sigs] for _msgs, sigs in commits], dtype=bool
+            ).reshape(len(commits), len(pubkeys))
+
+    set_default_verifier(AcceptAll())

@@ -1,0 +1,343 @@
+"""CPU rehearsals of the benchmark. One command runs them all:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q -p no:cacheprovider
+
+They drive `run.py` as the driver does (a new process per run) on a tiny
+deployment, behind the test-only override of the no-chip refusal. Nothing
+here yields a device number.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+sys.path.insert(0, ROOT)
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def bench_json() -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+# -- the contract's form -------------------------------------------------------
+
+
+def test_benchmark_json_keeps_to_the_contract():
+    raw = open(os.path.join(ROOT, "BENCHMARK.json"), "rb").read()
+    assert len(raw) <= 64 * 1024
+    b = json.loads(raw)
+    assert set(b) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
+    assert b["paths"] == ["benchmark"] and 1 <= b["run_seconds"] <= 51
+    assert len(b["command"]) <= 32 and all(1 <= len(w) <= 200 for w in b["command"])
+    configs = {c["name"]: c for c in b["configs"]}
+    for c in b["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and PATH.match(c["file"]) and c["file"].startswith("benchmark/")
+        assert 1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
+        doc = json.load(open(os.path.join(ROOT, c["file"])))
+        assert doc["reduced"] == c["reduced"] and all(NAME.match(k) and k in doc for k in c["reduced"])
+        assert doc["guarantees"], "a deployment states its guarantees"
+    cells = [w["name"] for w in b["workloads"]]
+    assert len(set(cells)) == len(cells) and len({(w["config"], w["traffic"]) for w in b["workloads"]}) == len(cells)
+    for w in b["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"]) and w["config"] in configs
+        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
+        for part in (("cells", w["name"]), ("traffic", w["traffic"])):
+            assert os.path.isfile(os.path.join(BENCH, part[0], part[1] + ".json"))
+    assert sum(w["chips"] == 4 for w in b["workloads"]) <= max(1, len(cells) // 2)
+    assert {c["name"] for c in b["configs"]} == {w["config"] for w in b["workloads"]}
+    e2e = {m["name"]: m for m in b["end_to_end"]}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    for m in b["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+        assert m["source"] in ("host_clock", "device_trace") and 0.01 <= m["bound"] <= 0.25
+    names = list(e2e)
+    for m in b["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]) and m["source"] in SOURCES
+        assert m["moves"] in e2e and m["moves"] != "setup_s" and 1 <= len(m["layer"]) <= 200
+        assert set(m.get("workloads", cells)) <= set(cells)
+        names.append(m["name"])
+    assert len(set(names)) == len(names)
+
+
+def test_every_metric_and_cell_is_a_file_of_its_own():
+    b = bench_json()
+    e2e = {m["name"] for m in b["end_to_end"]}
+    per_layer = {m["name"]: m for m in b["per_layer"]}
+    for w in b["workloads"]:
+        cell = json.load(open(os.path.join(BENCH, "cells", w["name"] + ".json")))
+        assert set(cell["metrics"]) <= e2e and "setup_s" in cell["metrics"] and len(cell["metrics"]) >= 2
+        assert cell["layer_metrics"]
+        for name in cell["layer_metrics"]:
+            assert w["name"] in per_layer[name].get("workloads", [w["name"]])
+    for name, m in per_layer.items():
+        meta = json.load(open(os.path.join(BENCH, "layer_metrics", name + ".json")))
+        assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
+        for key in ("name", "unit", "better", "source", "layer", "moves"):
+            assert meta[key] == m[key], (name, key)
+
+
+# -- the yardstick's arithmetic -------------------------------------------------
+
+
+def test_counts_against_hand_worked_bytes():
+    from benchmark.lib import counts
+
+    # the table: 64 windows x 16 entries x 60 limbs x 2 bytes = 122,880 B a
+    # validator; a lane: 3 x 32 B in, 1 B out
+    assert counts.TABLE_BYTES_PER_VALIDATOR == 122_880
+    assert counts.verify_launch_bytes(100, 16) == 100 * 122_880 + 1_600 * 97 == 12_443_200
+    assert counts.verify_launch_bytes(1024, 16) == 1024 * 122_880 + 16_384 * 97 == 127_418_368
+    assert counts.verify_launch_bytes(1024, 1) == 125_829_120 + 99_328
+    assert counts.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        counts.peaks("TPU v9 imaginary")
+
+
+def test_kernel_metrics_count_only_what_the_device_answered():
+    """At 100 validators a window of 5 commits or fewer (under 512 lanes)
+    goes to the host library: its signatures and its table read are no
+    work of the kernel's, and the kernel's metrics leave them out."""
+    import importlib.util
+
+    from benchmark.lib import counts, ledger
+
+    def launch(t, k, backend):
+        return {"kind": "tables" if backend == "tables" else "verify", "t": t, "backend": backend,
+                "height_lo": 10, "height_hi": 10 + k - 1, "rows": 100 * k}
+
+    obs = {
+        "config": {"validators": 100}, "device_kind": "TPU v5 lite",
+        "trace": {"wall0": 1000.0, "window_s": 4.0, "chips": 1,
+                  "modules": {"jit_verify_tables_kernel(123)": 0.004, "jit_other(7)": 0.5}},
+        "launches": [launch(1001.0, 3, "host"), launch(1001.5, 16, "tables"), launch(1002.0, 5, "host"),
+                     launch(1003.0, 6, "tables"), launch(1007.0, 16, "tables")],
+    }
+    seconds, recs = ledger.traced_kernel(obs)
+    assert seconds == 0.004 and [ledger.commits(r) for r in recs] == [16, 6]
+
+    def reader(name):
+        spec = importlib.util.spec_from_file_location("m", os.path.join(BENCH, "layer_metrics", name + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.reduce
+
+    assert reader("kernel.verify_us_per_sig")(obs) == pytest.approx(1e6 * 0.004 / 2_200)
+    least = (counts.verify_launch_bytes(100, 16) + counts.verify_launch_bytes(100, 6)) / 819e9
+    assert reader("kernel.verify_tables_roofline")(obs) == pytest.approx(100 * least / 0.004)
+    # only host launches inside the stretch: nothing to read
+    obs["launches"] = obs["launches"][:1]
+    assert ledger.traced_kernel(obs) is None
+
+
+def test_a_slow_answer_is_a_latency_and_not_a_failure():
+    """A read that answered right, late, is counted by entry.reads_over_1s
+    and stays in the tail; only a read that errored is left out of both."""
+    import importlib.util
+
+    def reader(name):
+        spec = importlib.util.spec_from_file_location("m", os.path.join(BENCH, "layer_metrics", name + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.reduce
+
+    reads = [{"ok": True, "due": i / 20, "end": i / 20 + 0.005} for i in range(96)]
+    reads += [{"ok": True, "due": 5.0, "end": 6.2}, {"ok": True, "due": 5.05, "end": 6.06},
+              {"ok": True, "due": 5.1, "end": 6.1}, {"ok": False, "due": 5.2, "end": 15.2}]
+    obs = {"reads": reads}
+    assert reader("entry.reads_over_1s")(obs) == 2
+    assert reader("entry.rpc_p95_ms")(obs) == pytest.approx(5.0)
+    assert reader("entry.reads_over_1s")({"reads": reads[:96]}) == 0
+
+
+def test_trace_reduce_on_the_recorded_trace():
+    from benchmark.lib import trace_reduce
+
+    with open(os.path.join(BENCH, "lib", "recorded_trace.json")) as f:
+        rec = json.load(f)
+    red = trace_reduce.reduce(rec["trace"], rec["window_s"])
+    want = rec["expect"]
+    assert red["chips"] == want["chips"]
+    assert red["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
+    assert trace_reduce.module_seconds(red, "verify_tables_kernel") == pytest.approx(
+        want["verify_tables_kernel_s"], rel=1e-9
+    )
+    assert 0 < red["busy_s"] < red["window_s"]
+    gaps = sum(d for _s, d in red["gaps"])
+    assert gaps + red["busy_s"] == pytest.approx(red["window_s"], rel=1e-6)
+    assert trace_reduce.top_ops(red, 3)[0][0] == want["top_op"]
+    # an idle gap inside a launch's finalize is labelled by it
+    launch = {"kind": "verify", "t": 1000.0 + want["gap_at_s"] + 0.5, "finalize_s": 1.0, "in_flight_s": 0.0, "host_prep_s": 0.0}
+    labels = dict(trace_reduce.label_gaps(red, 1000.0, [launch]))
+    assert "verify.finalize" in labels
+    # a trace with no device plane gives nothing to read
+    assert trace_reduce.reduce({"planes": [{"name": "/host:CPU", "lines": []}]}, 1.0) is None
+
+
+def test_reference_merkle_and_sign_bytes_agree_with_the_generator(tmp_path):
+    from benchmark.lib import chain, reference
+
+    config = json.load(open(os.path.join(BENCH, "configs", "fastsync-100.json")))
+    config["validators"] = 7
+    mix = json.load(open(os.path.join(BENCH, "traffic", "sparse.json")))
+    rec = chain.build_chain(config, mix, seed=5, n_blocks=20, home=str(tmp_path / "home"), workers=0)
+    # ToValidators(20, 10): 20, 30, ... by key index, whatever the set's order
+    assert sorted(rec.powers) == [20 + 10 * i for i in range(7)]
+    entries = rec.tail_entries()
+    assert [h for _b, h, _c in entries] == list(range(4, 20))
+    from tendermint_tpu.types.block import Block
+
+    blk = Block.decode(bytes.fromhex(rec.tail_blocks[3]))
+    assert reference.merkle_root([bytes(t) for t in blk.data.txs]) == blk.header.data_hash
+    vote = next(v for v in entries[0][2].precommits if v is not None)
+    served = {
+        "height": vote.height, "round": vote.round, "timestamp": vote.timestamp, "type": vote.type,
+        "block_id": {"hash": vote.block_id.hash.hex(), "parts": {
+            "total": vote.block_id.parts_header.total, "hash": vote.block_id.parts_header.hash.hex()}},
+    }
+    assert reference.sign_bytes(rec.chain_id, served) == vote.sign_bytes(rec.chain_id)
+
+
+# -- a whole run, on a tiny deployment ---------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def copy(tmp_path_factory):
+    """A temp copy of the benchmark with one more deployment, traffic mix,
+    per-layer metric and cell dropped in as new files and entries: no file
+    that was there is edited."""
+    top = tmp_path_factory.mktemp("bench_copy")
+    shutil.copytree(BENCH, top / "benchmark", ignore=shutil.ignore_patterns("out", "cache", "__pycache__"))
+    os.symlink(os.path.join(ROOT, "tendermint_tpu"), top / "tendermint_tpu")
+    b = bench_json()
+    for n_vals in (16, 40):
+        cfg = json.load(open(os.path.join(BENCH, "configs", "fastsync-100.json")))
+        cfg.update(name=f"tiny{n_vals}", validators=n_vals, absent_votes=1)
+        json.dump(cfg, open(top / "benchmark" / "configs" / f"tiny{n_vals}.json", "w"))
+        b["configs"].append({"name": f"tiny{n_vals}", "source": "test", "file": f"benchmark/configs/tiny{n_vals}.json", "reduced": [], "why": "test"})
+        b["workloads"].append({"name": f"tiny{n_vals}.trickle", "config": f"tiny{n_vals}", "traffic": "trickle", "chips": 1, "why": "test"})
+        json.dump(
+            {"chain_blocks": 1200, "metrics": ["catchup_blocks_per_s", "setup_s"],
+             "layer_metrics": ["entry.rpc_median_ms", "entry.reads_issued", "verify.host_fallbacks", "device.idle_share"]},
+            open(top / "benchmark" / "cells" / f"tiny{n_vals}.trickle.json", "w"),
+        )
+    json.dump(
+        {"name": "trickle", "driver": "catchup", "txs": {"kind": "fresh_keys", "per_block": 2},
+         "reads": {"kinds": ["status"], "per_s": 10}, "warm_blocks": 8},
+        open(top / "benchmark" / "traffic" / "trickle.json", "w"),
+    )
+    json.dump(
+        {"name": "entry.reads_issued", "layer": "entry", "unit": "count", "better": "higher",
+         "source": "host_clock", "moves": "catchup_blocks_per_s"},
+        open(top / "benchmark" / "layer_metrics" / "entry.reads_issued.json", "w"),
+    )
+    (top / "benchmark" / "layer_metrics" / "entry.reads_issued.py").write_text(
+        "def reduce(obs):\n    return len(obs['reads'])\n"
+    )
+    json.dump(b, open(top / "BENCHMARK.json", "w"))
+    return top
+
+
+def run_cell(top, *args, allow_cpu=True):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    argv = [sys.executable, "benchmark/run.py", *args]
+    if allow_cpu:
+        argv.append("--allow-cpu-for-tests")
+    return subprocess.run(argv, cwd=top, env=env, capture_output=True, text=True, timeout=300)
+
+
+def last_line(proc) -> dict:
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_a_tiny_cell_end_to_end_from_new_files_alone(copy):
+    proc = run_cell(copy, "--workload", "tiny16.trickle", "--seed", "2147483659", "--seconds", "2", "--trace", "0")
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    line = last_line(proc)
+    assert set(line) == RESULT_KEYS
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 20
+    assert set(line["metrics"]) == {"catchup_blocks_per_s", "setup_s"}
+    assert line["metrics"]["catchup_blocks_per_s"]["value"] > 0
+    assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    assert line["device"]["platform"] == "cpu"
+    # every line that carries a number names the device
+    for row in proc.stdout.splitlines()[:-1]:
+        if re.search(r"\d", row.split("]", 1)[-1]):
+            assert "platform=" in row and "device_kind=" in row and "devices=" in row, row
+    detail = json.load(open(copy / "benchmark" / "out" / "tiny16.trickle-2147483659.json"))
+    assert detail["result"] == line and detail["checks"]["failures"] == []
+
+
+def test_a_traced_run_reports_the_layer_metrics_it_can_read(copy):
+    proc = run_cell(copy, "--workload", "tiny16.trickle", "--seed", "7", "--seconds", "2", "--trace", "1")
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    line = last_line(proc)
+    assert line["correct"] is True
+    # the dropped-in metric is found; the trace metric finds no device plane
+    # on the CPU and is left out of the line
+    assert line["metrics"]["entry.reads_issued"] == {"value": 20.0, "unit": "count"}
+    assert line["metrics"]["verify.host_fallbacks"]["value"] == 0.0
+    assert "device.idle_share" not in line["metrics"]
+
+
+@pytest.mark.parametrize("control", ["accept_all", "apphash_off_by_one"])
+def test_correct_turns_false_under_a_broken_guarantee(copy, control):
+    proc = run_cell(
+        copy, "--workload", "tiny16.trickle", "--seed", "11", "--seconds", "2", "--trace", "0", "--control", control
+    )
+    assert proc.returncode == 1, proc.stdout[-3000:] + proc.stderr[-3000:]
+    assert last_line(proc)["correct"] is False
+    assert "NOT CORRECT" in proc.stdout
+
+
+def test_correct_turns_false_when_the_host_answers_a_device_sized_launch(copy):
+    """40 validators x 16 commits = 640 lanes >= 512: a launch that size is
+    the device's to answer, and on the CPU the host library answers it."""
+    proc = run_cell(copy, "--workload", "tiny40.trickle", "--seed", "13", "--seconds", "3", "--trace", "1")
+    assert proc.returncode == 1, proc.stdout[-3000:] + proc.stderr[-3000:]
+    line = last_line(proc)
+    assert line["correct"] is False and line["metrics"]["verify.host_fallbacks"]["value"] > 0
+    assert "were not answered by the device" in proc.stdout
+
+
+def test_without_an_accelerator_nothing_is_printed(copy):
+    proc = run_cell(
+        copy, "--workload", "tiny16.trickle", "--seed", "1", "--seconds", "1", "--trace", "0", allow_cpu=False
+    )
+    assert proc.returncode == 3 and "{" not in proc.stdout, proc.stdout[-2000:]
+    assert not [p for p in os.listdir("/proc") if p.isdigit() and _is_child_of_run(p, str(copy))]
+
+
+def _is_child_of_run(pid: str, marker: str) -> bool:
+    try:
+        with open(f"/proc/{pid}/cmdline", "rb") as f:
+            return marker.encode() in f.read()
+    except OSError:
+        return False
+
+
+def test_alone_the_benchmark_fails_without_a_result(tmp_path):
+    shutil.copytree(BENCH, tmp_path / "benchmark", ignore=shutil.ignore_patterns("out", "cache", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path / "BENCHMARK.json")
+    proc = run_cell(tmp_path, "--workload", "fastsync-100.sparse", "--seed", "1", "--seconds", "1", "--trace", "0")
+    assert proc.returncode == 4 and proc.stdout == ""
