@@ -1,0 +1,5 @@
+from benchmark.lib import fastsync_stages
+
+
+def reduce(obs):
+    return fastsync_stages.share_of_window(obs, fastsync_stages.SYNC_THREAD)

@@ -20,6 +20,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+from functools import partial
 
 from tendermint_tpu.codec.binary import Reader, Writer
 from tendermint_tpu.p2p.connection import ChannelDescriptor
@@ -27,7 +28,14 @@ from tendermint_tpu.p2p.peer import Peer
 from tendermint_tpu.p2p.switch import Reactor
 from tendermint_tpu.blockchain.pool import BlockPool
 from tendermint_tpu.state.execution import apply_block
+from tendermint_tpu.telemetry import TRACER
 from tendermint_tpu.telemetry import launchlog as _launchlog
+from tendermint_tpu.telemetry.metrics import (
+    FASTSYNC_BLOCKS_APPLIED,
+    FASTSYNC_STAGE_SECONDS,
+    FASTSYNC_STAGES,
+    FASTSYNC_WINDOWS,
+)
 from tendermint_tpu.types.block import Block
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
@@ -52,6 +60,25 @@ VERIFY_WINDOW = 16  # commits batched per device call
 # 1 degenerates to the synchronous verify->apply loop (the bench
 # baseline); >2 only helps when launches are slower than applies.
 PIPELINE_DEPTH = int(os.environ.get("TENDERMINT_TPU_PIPELINE_DEPTH", "2"))
+
+_STAGE_SECONDS = {
+    s: FASTSYNC_STAGE_SECONDS.labels(stage=s) for s in FASTSYNC_STAGES
+}
+
+
+def _stage(name: str, window: dict | None = None):
+    """Stopwatch around one stage of a block's life (`TRACER.stage`):
+    the duration goes to `tendermint_fastsync_stage_seconds{stage}` and,
+    for a stage that belongs to a verify window, into that window's
+    per-stage seconds (its `fastsync.window` span); the stretch shows
+    as `fastsync.<name>` in a profiler trace."""
+
+    def sink(seconds: float) -> None:
+        _STAGE_SECONDS[name].observe(seconds)
+        if window is not None:
+            window[name] = window.get(name, 0.0) + seconds
+
+    return TRACER.stage("fastsync." + name, sink)
 
 
 def adaptive_pipeline_depth() -> int:
@@ -203,7 +230,8 @@ class BlockchainReactor(Reactor):
         self.pool.remove_peer(peer.id)
 
     def receive(self, chan_id: int, peer: Peer, payload: bytes) -> None:
-        kind, arg = decode_message(payload)
+        with TRACER.stage("fastsync.decode") as decode:
+            kind, arg = decode_message(payload)
         if kind == "block_request":
             block = self.store.load_block(arg)
             if block is not None:
@@ -213,6 +241,7 @@ class BlockchainReactor(Reactor):
             else:
                 peer.try_send(BLOCKCHAIN_CHANNEL, _enc(_MSG_NO_BLOCK, arg))
         elif kind == "block_response":
+            _STAGE_SECONDS["decode"].observe(decode.seconds)
             self.pool.add_block(peer.id, arg, size=len(payload))
         elif kind == "status_request":
             peer.try_send(
@@ -260,7 +289,8 @@ class BlockchainReactor(Reactor):
                 if self.on_caught_up is not None:
                     self.on_caught_up(self.state)
                 return
-            time.sleep(_SYNC_TICK_S)
+            with _stage("starved"):
+                time.sleep(_SYNC_TICK_S)
 
     def tip_lag(self) -> int:
         """Heights between the best-known peer tip and our store head
@@ -347,41 +377,17 @@ class BlockchainReactor(Reactor):
         yet), "boundary" (valset changes at `cursor` — needs a drained
         pipeline + `_sync_one`), or "redo" (linkage mismatch; the pool
         suffix is already dropped)."""
-        window = self.pool.peek(VERIFY_WINDOW + 1, from_height=cursor)
-        if len(window) < 2:
-            return None
-        # the batch spans consecutive blocks under ONE valset — windows
-        # beyond an EndBlock valset rotation never enter the pipeline,
-        # their headers carry a different validators_hash
-        val_hash = self.state.validators.hash()
-        usable = 0
-        for b in window:
-            if b.header.validators_hash != val_hash:
-                break
-            usable += 1
-        if usable < 2:
-            return "boundary"
-        blocks = window[:usable]
-        # commit for blocks[i] rides in blocks[i+1].last_commit; the
-        # final block waits for its successor in a later window, so
-        # only the applied prefix needs part sets / ids built
-        apply_n = usable - 1
-        parts = [b.make_part_set() for b in blocks[:apply_n]]
-        block_ids = [
-            BlockID(b.hash(), ps.header)
-            for b, ps in zip(blocks[:apply_n], parts)
-        ]
-        entries = []
-        for i in range(apply_n):
-            commit = blocks[i + 1].last_commit
-            if commit.block_id != block_ids[i]:
-                self._redo(blocks[i].header.height)
-                return "redo"
-            entries.append((block_ids[i], blocks[i].header.height, commit))
+        t0 = time.time()
+        stages: dict = {}
+        with _stage("part_set", stages):
+            claimed = self._claim_window(cursor)
+        if not isinstance(claimed, tuple):
+            return claimed
+        blocks, parts, entries, cut = claimed
         try:
             # the launch record names the heights it covers, so the
             # ledger can say which backend answered for each height
-            with _launchlog.tag(
+            with _stage("verify_submit", stages), _launchlog.tag(
                 height_lo=entries[0][1], height_hi=entries[-1][1]
             ):
                 handle = self.state.validators.verify_commit_batched_async(
@@ -400,17 +406,72 @@ class BlockchainReactor(Reactor):
             "blocks": blocks,
             "parts": parts,
             "handle": handle,
-            "apply_n": apply_n,
+            "apply_n": len(entries),
             "start_height": blocks[0].header.height,
-            "next_height": blocks[0].header.height + apply_n,
+            "next_height": blocks[0].header.height + len(entries),
+            "t0": t0,
+            "stages": stages,
+            "cut": cut,
         }
+
+    def _claim_window(self, cursor: int):
+        """The `part_set` stage of `_prep_window`: (blocks, part sets,
+        verify entries, cut) for the window at `cursor`, or one of
+        `_prep_window`'s None / "boundary" / "redo"."""
+        window = self.pool.peek(VERIFY_WINDOW + 1, from_height=cursor)
+        if len(window) < 2:
+            return None
+        # the batch spans consecutive blocks under ONE valset — windows
+        # beyond an EndBlock valset rotation never enter the pipeline,
+        # their headers carry a different validators_hash
+        val_hash = self.state.validators.hash()
+        usable = 0
+        for b in window:
+            if b.header.validators_hash != val_hash:
+                break
+            usable += 1
+        if usable < 2:
+            return "boundary"
+        # why the window ends where it does
+        if usable < len(window):
+            cut = "boundary"
+        elif usable == VERIFY_WINDOW + 1:
+            cut = "full"
+        else:
+            cut = "pool_gap"
+        blocks = window[:usable]
+        # commit for blocks[i] rides in blocks[i+1].last_commit; the
+        # final block waits for its successor in a later window, so
+        # only the applied prefix needs part sets / ids built
+        apply_n = usable - 1
+        parts = [b.make_part_set() for b in blocks[:apply_n]]
+        block_ids = [
+            BlockID(b.hash(), ps.header)
+            for b, ps in zip(blocks[:apply_n], parts)
+        ]
+        entries = []
+        for i in range(apply_n):
+            commit = blocks[i + 1].last_commit
+            if commit.block_id != block_ids[i]:
+                self._redo(blocks[i].header.height)
+                return "redo"
+            entries.append((block_ids[i], blocks[i].header.height, commit))
+        return blocks, parts, entries, cut
 
     def _join_and_apply(self, entry) -> bool:
         """Join one window's in-flight verdict, then store + apply its
         blocks. False means the window failed and the pool suffix was
         redone — the caller must discard younger in-flight windows."""
         try:
-            entry["handle"].result()
+            return self._apply_window(entry)
+        finally:
+            self._close_window(entry)
+
+    def _apply_window(self, entry) -> bool:
+        stages = entry["stages"]
+        try:
+            with _stage("verify_wait", stages):
+                entry["handle"].result()
         except ValidationError:
             self._redo(entry["start_height"])
             return False
@@ -418,27 +479,48 @@ class BlockchainReactor(Reactor):
         for i in range(entry["apply_n"]):
             commit = blocks[i + 1].last_commit
             try:
-                self.store.save_block(blocks[i], parts[i], commit)
-                apply_block(
-                    self.state,
-                    blocks[i],
-                    parts[i].header,
-                    self.app_conn,
-                    verifier=self.verifier,
-                    tx_indexer=self.tx_indexer,
-                    commit_preverified=True,
-                    hasher=self.hasher,
-                )
+                self._store_and_apply(blocks[i], parts[i], commit, stages)
             except ValidationError:
                 # commit verified but the block body is inconsistent
                 # (possible only past a 2/3-byzantine signer set):
                 # drop the suffix + serving peer rather than spin
                 self._redo(blocks[i].header.height)
                 return False
-            self.pool.pop()
-            self.blocks_synced += 1
             self._log_progress()
         return True
+
+    def _store_and_apply(self, block, parts, commit, stages: dict) -> None:
+        with _stage("store", stages):
+            self.store.save_block(block, parts, commit)
+        apply_block(
+            self.state,
+            block,
+            parts.header,
+            self.app_conn,
+            verifier=self.verifier,
+            tx_indexer=self.tx_indexer,
+            commit_preverified=True,
+            hasher=self.hasher,
+            stage=partial(_stage, window=stages),
+        )
+        self.pool.pop()
+        self.blocks_synced += 1
+        FASTSYNC_BLOCKS_APPLIED.inc()
+
+    def _close_window(self, entry) -> None:
+        """One `fastsync.window` span and one count a window, from its
+        prep to its last apply: the launch record of the same window
+        carries the same `height_lo`, so span and launch join."""
+        FASTSYNC_WINDOWS.labels(cut=entry["cut"]).inc()
+        TRACER.add(
+            "fastsync.window",
+            entry["t0"],
+            time.time(),
+            height_lo=entry["start_height"],
+            height_hi=entry["next_height"] - 1,
+            cut=entry["cut"],
+            **{f"{k}_s": round(v, 6) for k, v in entry["stages"].items()},
+        )
 
     def _drain(self, pipeline, apply: bool) -> None:
         """Empty the pipeline in submission order. While `apply` holds
@@ -452,7 +534,8 @@ class BlockchainReactor(Reactor):
                 apply = self._join_and_apply(entry)
             else:
                 try:
-                    entry["handle"].result()
+                    with _stage("verify_wait"):
+                        entry["handle"].result()
                 # tmlint: disable=T001 -- stale-suffix drain: joined only to release the dispatch slot, the failure was already handled upstream
                 except Exception:
                     pass
@@ -475,41 +558,40 @@ class BlockchainReactor(Reactor):
     def _sync_one(self, block, successor) -> None:
         if successor is None:
             return
-        parts = block.make_part_set()
-        block_id = BlockID(block.hash(), parts.header)
-        commit = successor.last_commit
+        height = block.header.height
+        window = {
+            "t0": time.time(),
+            "stages": {},
+            "cut": "boundary",
+            "start_height": height,
+            "next_height": height + 1,
+        }
+        try:
+            self._sync_one_window(block, successor.last_commit, window["stages"])
+        finally:
+            self._close_window(window)
+
+    def _sync_one_window(self, block, commit, stages: dict) -> None:
+        with _stage("part_set", stages):
+            parts = block.make_part_set()
+            block_id = BlockID(block.hash(), parts.header)
         if commit.block_id != block_id:
             self._redo(block.header.height)
             return
         try:
-            self.state.validators.verify_commit(
-                self.state.chain_id,
-                block_id,
-                block.header.height,
-                commit,
-                verifier=self.verifier,
-                consumer="fastsync",
-            )
+            # synchronous: the whole verify is a wait nothing hides
+            with _stage("verify_wait", stages):
+                self.state.validators.verify_commit(
+                    self.state.chain_id,
+                    block_id,
+                    block.header.height,
+                    commit,
+                    verifier=self.verifier,
+                    consumer="fastsync",
+                )
+            self._store_and_apply(block, parts, commit, stages)
         except ValidationError:
             self._redo(block.header.height)
-            return
-        try:
-            self.store.save_block(block, parts, commit)
-            apply_block(
-                self.state,
-                block,
-                parts.header,
-                self.app_conn,
-                verifier=self.verifier,
-                tx_indexer=self.tx_indexer,
-                commit_preverified=True,
-                hasher=self.hasher,
-            )
-        except ValidationError:
-            self._redo(block.header.height)
-            return
-        self.pool.pop()
-        self.blocks_synced += 1
 
     def _redo(self, height: int) -> None:
         """Bad block/commit: drop the chain suffix and the peer that

@@ -698,7 +698,7 @@ class Node:
         if getattr(self, "_span_log", None) is not None:
             from tendermint_tpu.telemetry import TRACER
 
-            TRACER.clear_sink(self._span_log.append)
+            TRACER.remove_sink(self._span_log.append)
             self._span_log.close()
         if getattr(self, "height_ledger", None) is not None:
             self.height_ledger.close()

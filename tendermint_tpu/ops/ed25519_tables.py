@@ -968,3 +968,24 @@ def prepare_commit_lanes(pubkeys, commits):
                 b"".join(blobs), dtype=np.uint8
             ).reshape(len(blobs), 32)
     return s, h, r, precheck
+
+
+# -- names in the profiler's trace --------------------------------------------
+#
+# `jax.named_scope` around the XLA stages of `verify_tables_kernel`, so a
+# device trace says `ed25519.select_entries/while` where it said
+# `while.249`. Applied HERE, by rebinding below every Pallas kernel and
+# call site, and never around a `pallas_call`: a Mosaic kernel's
+# serialized body carries the scope stack and the file lines of its
+# Python frames, and that body is part of the executable's compile-cache
+# key. A scope on plain XLA ops is metadata the key leaves out, so these
+# names change no executable; a line added above would recompile every
+# verify shape. (The table build and layout need no scope: each is an
+# executable of its own, named in the trace's `XLA Modules` line.)
+
+_select_entries = jax.named_scope("ed25519.select_entries")(_select_entries)
+_sum_entries_xla = jax.named_scope("ed25519.madd_chain")(_sum_entries_xla)
+_digits_w4 = jax.named_scope("ed25519.fused_digits")(_digits_w4)
+_finish_encode_compare = jax.named_scope("ed25519.tally")(
+    _finish_encode_compare
+)

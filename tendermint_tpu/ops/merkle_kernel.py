@@ -103,6 +103,7 @@ _ALGOS = {
 }
 
 
+@jax.named_scope("merkle.tree_reduce")
 def _forest_levels(nodes, cnt, levels: int, algo: str = "sha256"):
     """Shared level reduction: nodes (T, P, W) u32, cnt (T,) i32 valid leaf
     prefixes, P = 2**levels. Returns (T, W) root words. A pair exists only

@@ -10,7 +10,8 @@ exactly like the reference (`state/execution.go:224-243`).
 
 from __future__ import annotations
 
-from typing import Callable
+from contextlib import nullcontext
+from typing import Callable, ContextManager
 
 from tendermint_tpu.abci.client import AppConnConsensus
 from tendermint_tpu.abci.types import Result
@@ -24,6 +25,13 @@ from tendermint_tpu.utils.fail import fail_point
 
 class BlockExecutionError(Exception):
     pass
+
+
+_UNTIMED = nullcontext()
+
+
+def _untimed(_name: str) -> ContextManager:
+    return _UNTIMED
 
 
 def validate_block(
@@ -145,28 +153,41 @@ def apply_block(
     on_tx_result: Callable[[int, bytes, Result], None] | None = None,
     commit_preverified: bool = False,
     hasher=None,
+    stage: Callable[[str], ContextManager] | None = None,
 ) -> State:
     """Validate, execute, persist; returns the advanced state
     (reference `ApplyBlock state/execution.go:216-249`). Mutates and
-    returns `state`; callers pass a copy when they need the original."""
-    validate_block(
-        state,
-        block,
-        verifier=verifier,
-        commit_preverified=commit_preverified,
-        hasher=hasher,
-    )
+    returns `state`; callers pass a copy when they need the original.
+
+    `stage(name)` gives a context manager held around each stage of the
+    apply: `validate`, `exec` (the ABCI calls and the app commit with
+    the mempool update) and `state_save` (everything persisted). The
+    fast-sync reactor passes its stopwatch; without one (consensus)
+    nothing is timed."""
+    stage = stage or _untimed
+    with stage("validate"):
+        validate_block(
+            state,
+            block,
+            verifier=verifier,
+            commit_preverified=commit_preverified,
+            hasher=hasher,
+        )
 
     fail_point()  # before any execution effects
-    abci_responses = exec_block_on_proxy_app(app_conn, block, on_tx_result)
+    with stage("exec"):
+        abci_responses = exec_block_on_proxy_app(app_conn, block, on_tx_result)
 
     fail_point()  # after app execution, before saving responses
-    state.save_abci_responses(abci_responses)
+    with stage("state_save"):
+        state.save_abci_responses(abci_responses)
 
-    fail_point()  # responses saved, before state advance + app commit
-    if tx_indexer is not None:
-        tx_indexer.add_batch(block, abci_responses)
-    state.set_block_and_validators(block.header, part_set_header, abci_responses)
+        fail_point()  # responses saved, before state advance + app commit
+        if tx_indexer is not None:
+            tx_indexer.add_batch(block, abci_responses)
+        state.set_block_and_validators(
+            block.header, part_set_header, abci_responses
+        )
     if abci_responses.end_block_changes and hasattr(verifier, "prebuild"):
         # valset rotation decided: warm the NEXT set's verify tables in
         # the background so the first commit signed by the new set
@@ -176,18 +197,20 @@ def apply_block(
     # app Commit under the mempool lock, then recheck leftover txs
     # (reference CommitStateUpdateMempool `state/execution.go:254-277`)
     mempool = mempool if mempool is not None else NopMempool()
-    mempool.lock()
-    try:
-        res = app_conn.commit_sync()
-        if not res.is_ok:
-            raise BlockExecutionError(f"app commit failed: {res.log}")
-        state.app_hash = res.data
-        mempool.update(block.header.height, block.data.txs)
-    finally:
-        mempool.unlock()
+    with stage("exec"):
+        mempool.lock()
+        try:
+            res = app_conn.commit_sync()
+            if not res.is_ok:
+                raise BlockExecutionError(f"app commit failed: {res.log}")
+            state.app_hash = res.data
+            mempool.update(block.header.height, block.data.txs)
+        finally:
+            mempool.unlock()
 
     fail_point()  # app committed, before state save
-    state.save()
+    with stage("state_save"):
+        state.save()
     return state
 
 

@@ -395,6 +395,7 @@ SPAN_CATALOG = frozenset(
         "scenario.run",
         "batcher.flush",
         "dispatch.launch",
+        "fastsync.window",
         "tx.e2e",
         "vote.e2e",
     }
@@ -499,6 +500,48 @@ from tendermint_tpu.telemetry import process as _process  # noqa: E402
 PROCESS_RSS.set_function(_process.rss_bytes)
 PROCESS_FDS.set_function(_process.open_fds)
 PROCESS_THREADS.set_function(_process.thread_count)
+
+# -- fast sync (blockchain/reactor.py) ----------------------------------------
+#
+# Where a catching-up node's time goes, measured where the work happens
+# (`TRACER.stage`): `decode` runs on the p2p receive thread, every other
+# stage on the one sync thread, so the sync-thread sums over wall time
+# say how much of that thread the stages cover. `cut` is why a verify
+# window carried fewer than VERIFY_WINDOW commits.
+
+FASTSYNC_STAGES = (
+    "decode", "part_set", "verify_submit", "verify_wait", "store",
+    "validate", "exec", "state_save", "starved",
+)
+FASTSYNC_CUTS = ("full", "pool_gap", "boundary")
+
+FASTSYNC_STAGE_SECONDS = Histogram(
+    "tendermint_fastsync_stage_seconds",
+    "Fast-sync host time by stage: decode (a block_response, p2p thread), "
+    "part_set (window prep: peek, part sets, block ids, linkage), "
+    "verify_submit (sign-bytes, lanes, launch submit), verify_wait (the "
+    "verdict join the pipeline failed to hide, plus the tally), store "
+    "(save_block), validate / exec / state_save (apply_block), starved "
+    "(the sync loop's idle tick: nothing to prepare, nothing in flight)",
+    labelnames=("stage",),
+    buckets=LATENCY_BUCKETS,
+)
+FASTSYNC_BLOCKS_APPLIED = Counter(
+    "tendermint_fastsync_blocks_applied_total",
+    "Blocks stored and applied by fast-sync",
+)
+FASTSYNC_WINDOWS = Counter(
+    "tendermint_fastsync_windows_total",
+    "Verify windows joined, by why the window ended where it did: full "
+    "(VERIFY_WINDOW commits), pool_gap (the next height was not "
+    "downloaded yet), boundary (the validator set changes)",
+    labelnames=("cut",),
+)
+
+for _stage in FASTSYNC_STAGES:
+    FASTSYNC_STAGE_SECONDS.labels(stage=_stage)
+for _cut in FASTSYNC_CUTS:
+    FASTSYNC_WINDOWS.labels(cut=_cut).inc(0)
 
 # -- state sync ---------------------------------------------------------------
 

@@ -19,7 +19,12 @@ Exported through the catalog (`telemetry/metrics.py`):
   and stop paired on that thread, and collections never overlap, so a
   single module-global stamp is race-free. Installed idempotently by
   ``install_gc_telemetry()`` (node start / tests), ~100 ns per
-  collection when installed.
+  collection when installed. The hook never waits for a registry
+  lock: a collection can start on a thread that is inside these very
+  families' ``samples()`` (a scrape allocates under the family lock),
+  and waiting there is waiting for oneself, which left ``/metrics`` and
+  ``dump_telemetry`` unanswered for the rest of the node's life. What
+  it cannot count at once it keeps and counts at the next collection.
 """
 
 from __future__ import annotations
@@ -28,6 +33,7 @@ import gc
 import os
 import threading
 import time
+from collections import deque
 
 _PAGE_SIZE = 4096
 try:
@@ -69,6 +75,10 @@ def thread_count() -> float:
 _installed = False
 _install_lock = threading.Lock()
 _gc_started_at: float | None = None
+# collections seen but not yet in the registry (its lock was held)
+_uncounted_gens: "deque[str]" = deque()
+_uncounted_pauses: "deque[float]" = deque()
+_GENERATIONS = ("0", "1", "2")
 
 
 def _gc_callback(phase: str, info: dict) -> None:
@@ -78,11 +88,26 @@ def _gc_callback(phase: str, info: dict) -> None:
         return
     started = _gc_started_at
     _gc_started_at = None
+    gen = str(info.get("generation", "?"))
+    if gen in _GENERATIONS:
+        _uncounted_gens.append(gen)
+    if started is not None:
+        _uncounted_pauses.append(time.perf_counter() - started)
+    _count_what_can_be()
+
+
+def _count_what_can_be() -> None:
+    """Move the kept collections into the two families without ever
+    waiting: `Counter.labels()` takes the family lock too, so the
+    pre-seeded children are read straight from the map."""
     from tendermint_tpu.telemetry import metrics as _m
 
-    _m.PROCESS_GC_COLLECTIONS.labels(gen=str(info.get("generation", "?"))).inc()
-    if started is not None:
-        _m.PROCESS_GC_PAUSE.observe(time.perf_counter() - started)
+    collections = _m.PROCESS_GC_COLLECTIONS._children
+    while _uncounted_gens and collections[(_uncounted_gens[0],)].try_inc():
+        _uncounted_gens.popleft()
+    pause = _m.PROCESS_GC_PAUSE._child0()
+    while _uncounted_pauses and pause.try_observe(_uncounted_pauses[0]):
+        _uncounted_pauses.popleft()
 
 
 def install_gc_telemetry() -> bool:

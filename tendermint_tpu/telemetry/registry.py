@@ -152,6 +152,20 @@ class _CounterChild:
         with self._lock:
             self._value += n
 
+    def try_inc(self, n: float = 1.0) -> bool:
+        """`inc` that never waits: False, and nothing counted, while
+        the family's lock is held — by another thread or by THIS one.
+        For writers that can run in the middle of anything, such as a
+        `gc.callbacks` hook: a collection can start inside this very
+        family's `samples()`, under its lock."""
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            self._value += n
+        finally:
+            self._lock.release()
+        return True
+
     @property
     def value(self) -> float:
         with self._lock:
@@ -246,15 +260,28 @@ class _HistogramChild:
 
     def observe(self, v: float, exemplar: str | None = None) -> None:
         with self._lock:
-            self._sum += v
-            self._count += 1
-            if exemplar is not None:
-                self._exemplar = str(exemplar)
-            for i, ub in enumerate(self.buckets):
-                if v <= ub:
-                    self._counts[i] += 1
-                    return
-            self._counts[-1] += 1
+            self._observe_locked(v, exemplar)
+
+    def try_observe(self, v: float) -> bool:
+        """`observe` that never waits (see `_CounterChild.try_inc`)."""
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            self._observe_locked(v, None)
+        finally:
+            self._lock.release()
+        return True
+
+    def _observe_locked(self, v: float, exemplar: str | None) -> None:
+        self._sum += v
+        self._count += 1
+        if exemplar is not None:
+            self._exemplar = str(exemplar)
+        for i, ub in enumerate(self.buckets):
+            if v <= ub:
+                self._counts[i] += 1
+                return
+        self._counts[-1] += 1
 
     def snapshot(self) -> dict:
         # caller holds the family lock (or tolerates a torn read via .value)

@@ -14,10 +14,16 @@ span logs from N nodes and stitches same-trace spans into one
 cross-cluster timeline. Every span name recorded with a literal must be
 registered in `telemetry/metrics.py`'s SPAN_CATALOG (collection-time
 lint, same discipline as the metric catalog).
+
+`TRACER.stage(name)` is the stopwatch for stretches too frequent for a
+span each (fast-sync's per-block stages): a `perf_counter_ns` duration
+for the caller's histogram, shown in the profiler's host plane while a
+`jax.profiler` session runs, and nothing in the ring.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import deque
@@ -61,6 +67,44 @@ class Span:
         }
 
 
+class Stage:
+    """One timed stretch of host work: `with TRACER.stage(name) as st`
+    leaves the duration in `st.seconds` and, when given, calls
+    `sink(seconds)` on exit (errors included).
+
+    While open it holds a `jax.profiler.TraceAnnotation(name)`, so the
+    stretch sits in the profiler's host plane on the profiler's own
+    clock, beside the device's operations; with no profiler session the
+    annotation is a no-op. `telemetry/` imports no JAX: the annotation
+    is taken from `sys.modules`, and a process that never loaded JAX
+    (it has no device to trace) times the stage without one."""
+
+    __slots__ = ("name", "seconds", "_sink", "_t0", "_annotation")
+
+    def __init__(self, name: str, sink=None) -> None:
+        self.name = name
+        self.seconds = 0.0
+        self._sink = sink
+
+    def __enter__(self) -> "Stage":
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            self._annotation = None
+        else:
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = (time.perf_counter_ns() - self._t0) * 1e-9
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        if self._sink is not None:
+            self._sink(self.seconds)
+
+
 class Tracer:
     """Bounded ring of completed spans; thread-safe. Optional sink
     callbacks observe every completed span (the JSONL span log persists
@@ -86,17 +130,6 @@ class Tracer:
         object per attribute access, so `log.append` must still match."""
         with self._lock:
             self._sinks = tuple(s for s in self._sinks if s != fn)
-
-    def set_sink(self, fn) -> None:
-        """Legacy single-sink API: replace ALL sinks with `fn` (None
-        clears)."""
-        with self._lock:
-            self._sinks = () if fn is None else (fn,)
-
-    def clear_sink(self, fn) -> None:
-        """Remove the sink only if `fn` is an installed one — a
-        stopping node must not strip a successor's sink."""
-        self.remove_sink(fn)
 
     def add(self, name: str, start: float, end: float, **attrs) -> Span:
         span = Span(name, start, end, attrs)
@@ -125,6 +158,11 @@ class Tracer:
             raise
         finally:
             self.add(name, t0, time.time(), **_snapshot_attrs(attrs))
+
+    def stage(self, name: str, sink=None) -> Stage:
+        """A `Stage` stopwatch (see there). Records no span: the caller
+        sums stages into the one span it records per unit of work."""
+        return Stage(name, sink)
 
     def recent(self, n: int | None = None, prefix: str = "") -> list[dict]:
         with self._lock:

@@ -1,0 +1,239 @@
+"""Fast-sync's stage clock: the
+`tendermint_fastsync_stage_seconds{stage}` histogram with its two
+counters, and the one `fastsync.window` span a window. (The stopwatch
+itself, `TRACER.stage`, is tested with the tracer in test_telemetry.py.)
+
+All on the CPU with four validators and the host library passed as the
+verifier: no kernel compiles, and a window's verify still goes through
+the reactor's dispatch queue, so it leaves a launch record.
+"""
+
+from __future__ import annotations
+
+import time
+
+import pytest
+
+from tendermint_tpu.abci.apps import KVStoreApp, PersistentKVStoreApp
+from tendermint_tpu.abci.client import local_client_creator
+from tendermint_tpu.blockchain import BlockchainReactor, BlockStore
+from tendermint_tpu.blockchain.reactor import VERIFY_WINDOW
+from tendermint_tpu.db.kv import MemDB
+from tendermint_tpu.p2p import NodeInfo, Switch, connect_switches
+from tendermint_tpu.services.verifier import HostBatchVerifier
+from tendermint_tpu.state import make_genesis_state
+from tendermint_tpu.telemetry import REGISTRY, TRACER
+from tendermint_tpu.telemetry.launchlog import LAUNCHLOG
+from tendermint_tpu.telemetry.metrics import (
+    FASTSYNC_CUTS,
+    FASTSYNC_STAGES,
+    SPAN_CATALOG,
+)
+
+from tests.helpers import CHAIN_ID, ChainSim
+from tests.test_fastsync import _pipelined_reactor, _serving_node, wait_until
+
+STAGE_SECONDS = "tendermint_fastsync_stage_seconds"
+APPLY_STAGES = ("validate", "exec", "state_save")
+
+
+class Readings:
+    """The fast-sync series and window spans as they stand, so a test
+    can say how far each rose (the registry and the tracer are the
+    process's: other tests of this worker moved them before)."""
+
+    def __init__(self) -> None:
+        self.t0 = time.time()
+        self.base = self._read()
+
+    @staticmethod
+    def _read() -> dict:
+        series = REGISTRY.to_dict()
+        out = {
+            f"count.{s['labels']['stage']}": s["count"]
+            for s in series[STAGE_SECONDS]["series"]
+        }
+        out.update(
+            (f"sum.{s['labels']['stage']}", s["sum"])
+            for s in series[STAGE_SECONDS]["series"]
+        )
+        out.update(
+            (f"cut.{s['labels']['cut']}", s["value"])
+            for s in series["tendermint_fastsync_windows_total"]["series"]
+        )
+        out["blocks"] = REGISTRY.counter_value(
+            "tendermint_fastsync_blocks_applied_total"
+        )
+        return out
+
+    def rise(self) -> dict:
+        now = self._read()
+        return {k: now[k] - self.base[k] for k in now}
+
+    def windows(self) -> list[dict]:
+        return [
+            s["attrs"]
+            for s in TRACER.recent(prefix="fastsync.window")
+            if s["start"] >= self.t0
+        ]
+
+
+def synced(sim: ChainSim, app=None):
+    """`sim`'s chain through a fresh reactor's pipeline, no network."""
+    reactor, _state, store = _pipelined_reactor(
+        sim, depth=2, verifier=HostBatchVerifier(), app=app
+    )
+    reactor._try_sync()
+    return reactor, store
+
+
+def chain(n_blocks: int, app=None) -> ChainSim:
+    sim = ChainSim(n_vals=4, app=app)
+    for _ in range(n_blocks):
+        sim.advance()
+    return sim
+
+
+class TestCatalog:
+    def test_every_stage_and_cut_is_on_metrics_before_any_sync(self):
+        text = REGISTRY.prometheus_text()
+        for stage in FASTSYNC_STAGES:
+            assert f'{STAGE_SECONDS}_count{{stage="{stage}"}}' in text
+        for cut in FASTSYNC_CUTS:
+            assert f'tendermint_fastsync_windows_total{{cut="{cut}"}}' in text
+        assert "tendermint_fastsync_blocks_applied_total" in text
+        assert "fastsync.window" in SPAN_CATALOG
+
+
+class TestWindows:
+    def test_a_window_is_one_span_one_count_and_its_launchs_heights(self):
+        sim = chain(48)
+        before = Readings()
+        reactor, store = synced(sim)
+        assert store.height == 47
+        rise, spans = before.rise(), before.windows()
+        assert rise["blocks"] == 47 == reactor.blocks_synced
+        # 1-16 and 17-32 are whole windows; the third ends where the pool does
+        assert [(w["height_lo"], w["height_hi"], w["cut"]) for w in spans] == [
+            (1, 16, "full"), (17, 32, "full"), (33, 47, "pool_gap")
+        ]
+        assert (rise["cut.full"], rise["cut.pool_gap"], rise["cut.boundary"]) == (2, 1, 0)
+        assert VERIFY_WINDOW == 16
+        # the launch record of a window carries the span's height_lo
+        launched = {
+            (r.get("height_lo"), r.get("height_hi"))
+            for r in LAUNCHLOG.recent()
+            if r["t"] >= before.t0
+        }
+        assert {(w["height_lo"], w["height_hi"]) for w in spans} <= launched
+        # a span's seconds per stage are the histogram's
+        for stage in ("part_set", "verify_submit", "verify_wait", "store", *APPLY_STAGES):
+            assert sum(w[stage + "_s"] for w in spans) == pytest.approx(
+                rise["sum." + stage], abs=1e-4
+            )
+        assert rise["count.store"] == rise["count.validate"] == 47
+        # exec and state_save are two stretches a block each
+        assert rise["count.exec"] == rise["count.state_save"] == 2 * 47
+        assert rise["count.verify_submit"] == rise["count.verify_wait"] == 3
+
+    def test_a_validator_set_change_cuts_the_window_at_the_boundary(self):
+        sim = chain(20, app=PersistentKVStoreApp())
+        pub = sim.state.validators.validators[0].pub_key.data.hex()
+        sim.advance(txs=[f"val:{pub}/25".encode()])  # height 21 changes a power
+        for _ in range(9):
+            sim.advance()
+        before = Readings()
+        _reactor, store = synced(sim, app=PersistentKVStoreApp())
+        assert store.height == 29
+        rise, spans = before.rise(), before.windows()
+        assert rise["blocks"] == 29
+        assert sum(w["height_hi"] - w["height_lo"] + 1 for w in spans) == 29
+        assert rise["cut.boundary"] >= 1
+        assert sum(rise["cut." + c] for c in FASTSYNC_CUTS) == len(spans)
+        assert [w["height_lo"] for w in spans] == sorted(w["height_lo"] for w in spans)
+
+    def test_a_window_refused_at_its_join_is_counted_and_applies_nothing(self):
+        sim = chain(40)
+        # height 39's commit rides in the last block, which nothing links
+        # past: its signatures are well formed and wrong, so the window
+        # is refused by the verdict and not at prep
+        commit, other = sim.blocks[39].last_commit, sim.blocks[10].last_commit
+        for i in range(3):
+            commit.precommits[i] = commit.precommits[i].with_signature(
+                other.precommits[i].signature
+            )
+        before = Readings()
+        _reactor, store = synced(sim)
+        assert store.height == 32
+        rise, spans = before.rise(), before.windows()
+        assert rise["blocks"] == 32
+        assert [(w["height_lo"], w["cut"], "store_s" in w) for w in spans] == [
+            (1, "full", True), (17, "full", True), (33, "pool_gap", False)
+        ]
+        assert sum(rise["cut." + c] for c in FASTSYNC_CUTS) == 3
+
+
+class TestApplyBlock:
+    def test_without_a_stage_argument_nothing_is_recorded(self):
+        before = Readings()
+        chain(2)  # ChainSim applies as consensus does: no stage passed
+        rise = before.rise()
+        assert all(rise["count." + s] == 0 for s in FASTSYNC_STAGES)
+        assert rise["blocks"] == 0 and before.windows() == []
+
+    def test_the_stages_bracket_the_apply_in_order(self):
+        from contextlib import contextmanager
+
+        from tendermint_tpu.state import apply_block
+
+        sim = chain(1)
+        block, parts = sim.make_next_block()
+        seen = []
+
+        @contextmanager
+        def stage(name):
+            seen.append(name)
+            yield
+
+        apply_block(sim.state, block, parts.header, sim.conns.consensus, stage=stage)
+        assert seen == ["validate", "exec", "state_save", "exec", "state_save"]
+        assert sim.state.last_block_height == 2
+
+
+class TestOverTheWire:
+    def test_every_stage_is_observed_while_a_fresh_node_syncs(self):
+        sim = ChainSim(n_vals=4)
+        store = BlockStore(MemDB())
+        for _ in range(24):
+            block = sim.advance()
+            store.save_block(block, block.make_part_set(), sim.commits[-1])
+        before = Readings()
+        server = _serving_node(sim, store)
+        fresh_state = make_genesis_state(MemDB(), sim.genesis)
+        fresh_state.save()
+        fresh_store = BlockStore(MemDB())
+        reactor = BlockchainReactor(
+            state=fresh_state,
+            store=fresh_store,
+            app_conn=local_client_creator(KVStoreApp())().consensus,
+            fast_sync=True,
+            verifier=HostBatchVerifier(),
+        )
+        client = Switch(NodeInfo(node_id="fresh", moniker="fresh", chain_id=CHAIN_ID))
+        client.add_reactor("blockchain", reactor)
+        client.start()
+        try:
+            connect_switches(server, client)
+            wait_until(lambda: fresh_store.height >= 23, timeout=30, msg="fresh node synced")
+            wait_until(lambda: not reactor.fast_sync, timeout=10, msg="caught up")
+        finally:
+            server.stop()
+            client.stop()
+        rise, spans = before.rise(), before.windows()
+        for stage in FASTSYNC_STAGES:
+            assert rise["count." + stage] > 0, stage
+            assert rise["sum." + stage] > 0, stage
+        assert rise["count.decode"] == 24  # one a block_response
+        assert rise["blocks"] == fresh_store.height == reactor.blocks_synced
+        assert sum(w["height_hi"] - w["height_lo"] + 1 for w in spans) == fresh_store.height
+        assert sum(rise["cut." + c] for c in FASTSYNC_CUTS) == len(spans) >= 2

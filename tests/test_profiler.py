@@ -339,6 +339,38 @@ class TestProcessTelemetry:
         )
 
 
+    def test_a_collection_inside_a_scrape_of_its_own_series_does_not_wait(self):
+        """A scrape allocates under the family lock, so a collection can
+        start right there, on that thread: the hook must not wait for
+        the lock (it waited for itself, and `/metrics` was dead from
+        then on). What it cannot count it counts at the next collection."""
+        from tendermint_tpu.telemetry import metrics, process
+
+        process.install_gc_telemetry()
+        pauses = metrics.PROCESS_GC_PAUSE
+        counts = metrics.PROCESS_GC_COLLECTIONS
+        before = _hist_count("tendermint_process_gc_pause_seconds")
+        gen0 = REGISTRY.counter_value("tendermint_process_gc_collections_total", gen="0")
+        done = []
+
+        def collection_inside_samples():
+            with pauses._lock, counts._lock:  # as samples() holds them, in turn
+                process._gc_callback("start", {"generation": 0})
+                process._gc_callback("stop", {"generation": 0})
+            done.append(True)
+
+        worker = threading.Thread(target=collection_inside_samples, daemon=True)
+        worker.start()
+        worker.join(5)
+        assert done, "the hook waited for a lock its own thread holds"
+        process._gc_callback("start", {"generation": 0})
+        process._gc_callback("stop", {"generation": 0})
+        assert _hist_count("tendermint_process_gc_pause_seconds") >= before + 2
+        assert REGISTRY.counter_value(
+            "tendermint_process_gc_collections_total", gen="0"
+        ) >= gen0 + 2
+
+
 class TestQueueWaitView:
     def test_unified_queue_table(self):
         """The queue-wait unification: waits the subsystems already

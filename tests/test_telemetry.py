@@ -11,7 +11,9 @@ from __future__ import annotations
 
 import json
 import math
+import sys
 import threading
+import time
 import urllib.request
 
 import pytest
@@ -237,6 +239,52 @@ class TestTracer:
         assert tr.recent(n=2)[-1]["attrs"]["i"] == 9
 
 
+class TestStage:
+    """`TRACER.stage`: a stopwatch that shows in a profiler trace and
+    writes no span (fast-sync's per-block stages)."""
+
+    def test_the_duration_is_the_monotonic_clocks_and_goes_to_the_sink(self):
+        tracer = Tracer(capacity=4)
+        got = []
+        with tracer.stage("fastsync.store", got.append) as st:
+            time.sleep(0.01)
+        assert 0.009 < st.seconds < 0.5
+        assert got == [st.seconds] and st.name == "fastsync.store"
+        # a stage is no span: the ring stays as it was
+        assert len(tracer) == 0
+
+    def test_the_sink_hears_of_a_stage_that_raised(self):
+        got = []
+        with pytest.raises(KeyError):
+            with TRACER.stage("s", got.append):
+                raise KeyError("boom")
+        assert len(got) == 1
+
+    def test_without_jax_loaded_the_stage_is_timed_without_an_annotation(self, monkeypatch):
+        monkeypatch.delitem(sys.modules, "jax", raising=False)
+        with TRACER.stage("s") as st:
+            assert st._annotation is None
+        assert st.seconds > 0
+
+    def test_with_jax_loaded_the_stage_holds_a_profiler_annotation(self):
+        import jax  # no backend starts: the annotation needs none
+
+        with TRACER.stage("s") as st:
+            assert isinstance(st._annotation, jax.profiler.TraceAnnotation)
+
+    def test_a_stage_costs_microseconds_with_no_profiler_session(self):
+        import jax  # noqa: F401 - the annotation's path, as a node runs it
+
+        def batch(n=2000) -> float:
+            t = time.perf_counter()
+            for _ in range(n):
+                with TRACER.stage("s"):
+                    pass
+            return (time.perf_counter() - t) / n
+
+        assert min(batch() for _ in range(5)) < 5e-6
+
+
 class TestCatalog:
     def test_global_catalog_registered(self):
         # the catalog module must have registered every advertised family
@@ -358,10 +406,10 @@ class TestSpanPersistence:
 
         tr = Tracer(capacity=16)
         log = SpanLog(str(tmp_path / "spans.jsonl"), capacity=16)
-        tr.set_sink(log.append)
+        tr.add_sink(log.append)
         tr.add("consensus.propose", 1.0, 2.0, height=7)
         tr.add("verify.batch", 2.0, 2.5, n=64)
-        tr.clear_sink(log.append)
+        tr.remove_sink(log.append)
         log.close()
         loaded = SpanLog(str(tmp_path / "spans.jsonl"), capacity=16).load()
         assert [d["name"] for d in loaded] == [
@@ -376,7 +424,7 @@ class TestSpanPersistence:
         path = str(tmp_path / "spans.jsonl")
         log = SpanLog(path, capacity=8)
         tr = Tracer(capacity=64)
-        tr.set_sink(log.append)
+        tr.add_sink(log.append)
         for i in range(40):
             tr.add("s", float(i), float(i) + 0.5, i=i)
         log.close()
@@ -391,7 +439,7 @@ class TestSpanPersistence:
         path = str(tmp_path / "spans.jsonl")
         first = SpanLog(path, capacity=32)
         tr0 = Tracer(capacity=32)
-        tr0.set_sink(first.append)
+        tr0.add_sink(first.append)
         tr0.add("consensus.commit", 10.0, 11.0, height=42)
         first.close()
 
@@ -404,7 +452,7 @@ class TestSpanPersistence:
         assert restored[0]["attrs"]["restored"] is True
         assert restored[0]["attrs"]["height"] == 42
         tr1.add("consensus.propose", 11.0, 12.0, height=43)
-        tr1.clear_sink(log.append)
+        tr1.remove_sink(log.append)
         log.close()
         names = [d["name"] for d in SpanLog(path, capacity=32).load()]
         # the replayed span is NOT re-appended; the new one is
@@ -420,12 +468,12 @@ class TestSpanPersistence:
         loaded = SpanLog(str(path), capacity=8).load()
         assert [d["name"] for d in loaded] == ["ok"]
 
-    def test_clear_sink_only_removes_own_sink(self):
+    def test_remove_sink_only_removes_own_sink(self):
         tr = Tracer(capacity=4)
         mine, theirs = [], []
-        tr.set_sink(mine.append)
-        tr.set_sink(theirs.append)  # a successor took over
-        tr.clear_sink(mine.append)  # stopping node must not strip it
+        tr.add_sink(mine.append)
+        tr.add_sink(theirs.append)  # a successor joined
+        tr.remove_sink(mine.append)  # stopping node must not strip it
         tr.add("s", 0.0, 1.0)
         assert len(theirs) == 1 and not mine
 
