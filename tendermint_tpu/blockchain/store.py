@@ -6,6 +6,17 @@ the locally-seen commit (+2/3 precommits this node saw — may differ from
 the canonical one and is needed to reconstruct the consensus LastCommit
 on restart, `consensus/state.go:392-411`). A JSON height watermark marks
 the contiguous store head.
+
+What is durable when: the store has one way to write. `save_block`,
+`bootstrap` and `prune` each put their rows (or deletes) and the new
+watermark into one write batch and `write_sync` it: one transaction, one
+WAL fsync, atomic per block. The watermark is the store's acknowledged
+point, the first of a block's three (then the ABCI responses, then the
+state: `db/kv.py`): when `save_block` returns, the block and the
+watermark that names it are on disk together; after a crash there is
+never a row above the watermark nor a height under it that loads in
+part. `height` (what `/status` answers) moves after that transaction
+has committed, so a reader never hears of a height it cannot load.
 """
 
 from __future__ import annotations
@@ -14,7 +25,7 @@ import json
 from dataclasses import dataclass
 
 from tendermint_tpu.codec import Reader, Writer
-from tendermint_tpu.db.kv import DB
+from tendermint_tpu.db.kv import DB, Batch
 from tendermint_tpu.types.block import Block, Commit, Header
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
@@ -45,6 +56,7 @@ class BlockStore:
         self._db = db
         self._height = 0
         self._base = 0
+        self._encoded_commit: tuple[Commit, bytes] | None = None
         raw = db.get(b"blockStore")
         if raw is not None:
             doc = json.loads(raw.decode())
@@ -63,12 +75,6 @@ class BlockStore:
         pruned stores start above 1; `load_block*` below the base
         answers None, never a decode error."""
         return self._base
-
-    def _save_watermark(self) -> None:
-        self._db.set_sync(
-            b"blockStore",
-            json.dumps({"height": self._height, "base": self._base}).encode(),
-        )
 
     # -- keys ----------------------------------------------------------------
 
@@ -90,6 +96,40 @@ class BlockStore:
 
     # -- save ----------------------------------------------------------------
 
+    def _put_block(
+        self, batch: Batch, block: Block, part_set: PartSet, seen_commit: Commit
+    ) -> None:
+        height = block.header.height
+        meta = BlockMeta(
+            block_id=BlockID(block.hash(), part_set.header), header=block.header
+        )
+        batch.set(self._meta_key(height), meta.encode())
+        for i in range(part_set.total):
+            batch.set(self._part_key(height, i), part_set.get_part(i).encode())
+        # commit of block H-1 (carried inside block H): asked for first,
+        # it is what the block before left in `_encode`'s memo
+        batch.set(self._commit_key(height - 1), self._encode(block.last_commit))
+        # commit that made THIS block (what we saw locally)
+        batch.set(self._seen_commit_key(height), self._encode(seen_commit))
+
+    def _encode(self, commit: Commit) -> bytes:
+        """`commit.encode()`, once a commit: fast-sync hands every commit
+        over twice, as the seen commit of block H and then, the same
+        object, as `last_commit` of block H+1 (a hundred votes each)."""
+        memo = self._encoded_commit
+        if memo is None or memo[0] is not commit:
+            memo = self._encoded_commit = (commit, commit.encode())
+        return memo[1]
+
+    def _write(self, batch: Batch, height: int, base: int) -> None:
+        """The batch and the watermark that covers it, one durable
+        transaction; only then does the store answer the new height."""
+        batch.set(
+            b"blockStore", json.dumps({"height": height, "base": base}).encode()
+        )
+        batch.write_sync()
+        self._height, self._base = height, base
+
     def save_block(self, block: Block, part_set: PartSet, seen_commit: Commit) -> None:
         """Reference `SaveBlock :148`: must be called with height ==
         store height + 1 (contiguous chain)."""
@@ -100,21 +140,9 @@ class BlockStore:
             )
         if not part_set.is_complete():
             raise ValidationError("BlockStore can only save complete part sets")
-        meta = BlockMeta(
-            block_id=BlockID(block.hash(), part_set.header), header=block.header
-        )
-        self._db.set(self._meta_key(height), meta.encode())
-        for i in range(part_set.total):
-            part = part_set.get_part(i)
-            self._db.set(self._part_key(height, i), part.encode())
-        # commit of block H-1 (carried inside block H)
-        self._db.set(self._commit_key(height - 1), block.last_commit.encode())
-        # commit that made THIS block (what we saw locally)
-        self._db.set(self._seen_commit_key(height), seen_commit.encode())
-        self._height = height
-        if self._base == 0:
-            self._base = height
-        self._save_watermark()
+        batch = self._db.batch()
+        self._put_block(batch, block, part_set, seen_commit)
+        self._write(batch, height, self._base or height)
 
     def bootstrap(self, tail: list) -> None:
         """Seed an EMPTY store from a snapshot's block tail
@@ -128,6 +156,7 @@ class BlockStore:
             )
         if not tail:
             return
+        batch = self._db.batch()
         prev = None
         for block, seen_commit in tail:
             height = block.header.height
@@ -136,18 +165,8 @@ class BlockStore:
                     f"snapshot tail is not consecutive: {prev} -> {height}"
                 )
             prev = height
-            part_set = block.make_part_set()
-            meta = BlockMeta(
-                block_id=BlockID(block.hash(), part_set.header), header=block.header
-            )
-            self._db.set(self._meta_key(height), meta.encode())
-            for i in range(part_set.total):
-                self._db.set(self._part_key(height, i), part_set.get_part(i).encode())
-            self._db.set(self._commit_key(height - 1), block.last_commit.encode())
-            self._db.set(self._seen_commit_key(height), seen_commit.encode())
-        self._base = tail[0][0].header.height
-        self._height = tail[-1][0].header.height
-        self._save_watermark()
+            self._put_block(batch, block, block.make_part_set(), seen_commit)
+        self._write(batch, prev, tail[0][0].header.height)
 
     def prune(self, retain_height: int) -> int:
         """Delete blocks below `retain_height` (exclusive), bounding the
@@ -158,20 +177,19 @@ class BlockStore:
             retain_height = self._height
         if self._base == 0 or retain_height <= self._base:
             return 0
-        pruned = 0
+        batch = self._db.batch()
         for h in range(self._base, retain_height):
             meta = self.load_block_meta(h)
             if meta is not None:
                 for i in range(meta.block_id.parts_header.total):
-                    self._db.delete(self._part_key(h, i))
-            self._db.delete(self._meta_key(h))
-            self._db.delete(self._seen_commit_key(h))
+                    batch.delete(self._part_key(h, i))
+            batch.delete(self._meta_key(h))
+            batch.delete(self._seen_commit_key(h))
             # C:h-1 rides with block h, so this keeps the canonical
             # commit for retain_height-1 (written by block retain_height)
-            self._db.delete(self._commit_key(h - 1))
-            pruned += 1
-        self._base = retain_height
-        self._save_watermark()
+            batch.delete(self._commit_key(h - 1))
+        pruned = retain_height - self._base
+        self._write(batch, self._height, retain_height)
         return pruned
 
     # -- load ----------------------------------------------------------------

@@ -5,6 +5,6 @@ The reference uses goleveldb for blockstore/state/txindex/addrbook
 single-file) and MemDB backs tests/replay.
 """
 
-from tendermint_tpu.db.kv import DB, MemDB, SQLiteDB, db_provider
+from tendermint_tpu.db.kv import DB, Batch, MemDB, SQLiteDB, db_provider
 
-__all__ = ["DB", "MemDB", "SQLiteDB", "db_provider"]
+__all__ = ["DB", "Batch", "MemDB", "SQLiteDB", "db_provider"]

@@ -157,8 +157,12 @@ class State:
     def save(self) -> None:
         if self.db is None:
             raise ValidationError("state has no db to save to")
-        self.save_validators_info()
-        self.db.set_sync(_STATE_KEY, self.to_json())
+        # the validators pointer and the state document that implies it
+        # land together: one transaction, the block's last durable point
+        batch = self.db.batch()
+        batch.set(*self._validators_info_row())
+        batch.set(_STATE_KEY, self.to_json())
+        batch.write_sync()
 
     def copy(self) -> "State":
         return State(
@@ -183,25 +187,23 @@ class State:
     def _validators_key(height: int) -> bytes:
         return b"validatorsKey:%d" % height
 
-    def save_validators_info(self) -> None:
-        """Store validators-for-height(H+1) with change-height compression:
+    def _validators_info_row(self) -> tuple[bytes, bytes]:
+        """Validators-for-height(H+1) with change-height compression:
         full set only when it changed, else a pointer to the last change
         (reference `state/state.go:174-224`)."""
-        if self.db is None:
-            return
         next_height = self.last_block_height + 1
         changed = self.last_height_validators_changed
         if next_height == changed:
             doc = {"last_changed": changed, "validators": _valset_to_dict(self.validators)}
         else:
             doc = {"last_changed": changed}
-        self.db.set(self._validators_key(next_height), json.dumps(doc, sort_keys=True).encode())
+        return self._validators_key(next_height), json.dumps(doc, sort_keys=True).encode()
 
     def save_validators_full(self) -> None:
         """Write the FULL current validator set at its change height.
 
         Snapshot restore seeds a fresh state DB with this so the
-        change-height pointers `save_validators_info` writes afterwards
+        change-height pointers `save` writes afterwards
         resolve (`load_validators` would otherwise chase a pointer into
         pre-snapshot history this node never stored)."""
         if self.db is None:

@@ -71,6 +71,7 @@ class KVTxIndexer(TxIndexer):
         self._db = db
 
     def add_batch(self, block, abci_responses) -> None:
+        batch = self._db.batch()
         for i, tx in enumerate(block.data.txs):
             tr = TxResult(
                 height=block.header.height,
@@ -78,7 +79,8 @@ class KVTxIndexer(TxIndexer):
                 tx=bytes(tx),
                 result=abci_responses.deliver_tx[i],
             )
-            self._db.set(b"tx:" + tx_hash(bytes(tx)), tr.to_json())
+            batch.set(b"tx:" + tx_hash(bytes(tx)), tr.to_json())
+        batch.write()
 
     def get(self, tx_hash: bytes) -> TxResult | None:
         raw = self._db.get(b"tx:" + tx_hash)

@@ -543,6 +543,17 @@ for _stage in FASTSYNC_STAGES:
 for _cut in FASTSYNC_CUTS:
     FASTSYNC_WINDOWS.labels(cut=_cut).inc(0)
 
+# -- databases (db/kv.py) -----------------------------------------------------
+
+DB_COMMITS = Counter(
+    "tendermint_db_commits_total",
+    "SQLite write transactions (one WAL fsync each) by database file: a "
+    "set, a set_sync, a delete or a whole write batch is one. Over "
+    "tendermint_fastsync_blocks_applied_total a block reads 4: blockstore "
+    "1, state 2 (ABCI responses, state), txindex 1",
+    labelnames=("db",),
+)
+
 # -- state sync ---------------------------------------------------------------
 
 STATESYNC_CHUNKS = Counter(
