@@ -130,12 +130,102 @@ class HostBatchVerifier(BatchVerifier):
         return out
 
 
-# Below this many lanes the host library answers: every device launch
-# has a fixed cost while a host verify is ~60 us — single votes and
-# small commits must never wait on a kernel launch (the consensus hot
-# path verifies one gossiped vote at a time). The value is not measured
-# on v5e (ROADMAP Queue 1 item 2(d) owns the retune).
+# Below this many REAL lanes the host library answers: every device
+# launch has a fixed cost while a host verify is ~60 us, so single votes
+# and small commits must never wait on a kernel launch (the consensus hot
+# path verifies one gossiped vote at a time). For a commit window the
+# count is k * n of the commits and validators handed in, never of the
+# padded launch shape (`commit_launch_shape`): at 100 validators windows
+# of K <= 5 stay on the host library, 42% of fast-sync's launches. The
+# value is not measured on v5e (ROADMAP Queue 1 item 2(d) owns the retune).
 DEVICE_MIN_BATCH = int(os.environ.get("TENDERMINT_TPU_MIN_DEVICE_BATCH", "512"))
+
+# What a pad column of a commit window's launch carries, and what stands
+# in for a malformed key: the identity point's encoding. It decompresses
+# cleanly, so the packed table build stays sound; a pad column is absent
+# in every commit and a malformed key's lanes are masked, so neither
+# ever reports True.
+PLACEHOLDER_KEY = b"\x01" + b"\x00" * 31
+
+
+def commit_launch_shape(k: int, n: int) -> tuple[int, list[tuple[int, int]]]:
+    """The one shape rule of a commit window on the fused path: `k`
+    commits over `n` validators (per chip: the sharded verifier passes
+    n // ndev) -> (n_launch, [(real, k_launch), ...]), one pair per
+    kernel launch, `real` of the window's commits in a stack of
+    `k_launch`.
+
+    N rounds up to the fused kernel's 128-validator tile. K in 2..16
+    rounds up to 16 (fast-sync's `VERIFY_WINDOW`), above that to 32,
+    then 64 (`MAX_FUSED_STACK`), then chunks of 64 whose last is rounded
+    the same way. So a validator set costs one executable for every
+    fast-sync window size and three for a walk of any length, where
+    every K off the tile was an executable of its own (12-18 s to load,
+    50-60 s to compile, met as a stall). K = 1 is apart and keeps its
+    stack of one on the materialized chain: it is consensus' own
+    `verify_commit`, latency-bound, and a 16-stack of 1,024 validators
+    is 16,384 lanes where it needs 1,024 (13.7 ms of fused kernel
+    against a 7.0 ms round trip: PERF.md section 6). It shares the
+    padded table with the stacks.
+    """
+    from tendermint_tpu.ops.ed25519_tables import MAX_FUSED_STACK, V_TILE
+
+    n_launch = -(-n // V_TILE) * V_TILE
+    if k == 1:
+        return n_launch, [(1, 1)]
+    chunks = []
+    for lo in range(0, k, MAX_FUSED_STACK):
+        real = min(MAX_FUSED_STACK, k - lo)
+        stack = 16
+        while stack < real:
+            stack *= 2
+        chunks.append((real, stack))
+    return n_launch, chunks
+
+
+def _window_shape(k: int, n: int, fused: bool, ndev: int = 1):
+    """(n_launch, chunks) of a window as a verifier launches it: the
+    window's own shape off the fused path, else `commit_launch_shape`
+    asked about one chip's `n // ndev` validators, its tile times the
+    chips."""
+    if not fused:
+        return n, [(k, k)]
+    shard_launch, chunks = commit_launch_shape(k, n // ndev)
+    return shard_launch * ndev, chunks
+
+
+def _pad_lanes(a: np.ndarray, real: int, n: int, k_launch: int, n_launch: int):
+    """Commit-major lanes of `real` commits over `n` validators, set into
+    zeros at the launch shape: pad commits and pad columns are written
+    as one zero array, never walked lane by lane."""
+    if (real, n) == (k_launch, n_launch):
+        return a
+    out = np.zeros((k_launch, n_launch) + a.shape[1:], dtype=a.dtype)
+    out[:real, :n] = a.reshape((real, n) + a.shape[1:])
+    return out.reshape((k_launch * n_launch,) + a.shape[1:])
+
+
+def _window_chunks(pubkeys, commits, n_launch: int, chunks):
+    """Host prep of each launch of a window: yields (real, k_launch,
+    s, h, r, precheck), the lane arrays at the launch shape and the
+    precheck as the (real, n) grid of the real lanes. The launch
+    ledger's `rows_padded` and `k_launch` are counted when the caller
+    comes back for the next chunk, so a launch that raised (a shard
+    fault retried onto survivors) is not counted twice."""
+    from tendermint_tpu.ops.ed25519_tables import prepare_commit_lanes
+
+    n = len(pubkeys)
+    lo = 0
+    for real, k_launch in chunks:
+        s, h, r, precheck = prepare_commit_lanes(pubkeys, commits[lo : lo + real])
+        lo += real
+        s, h, r = (_pad_lanes(a, real, n, k_launch, n_launch) for a in (s, h, r))
+        yield real, k_launch, s, h, r, precheck.reshape(real, n)
+        _launchlog.annotate(
+            _additive=True,
+            rows_padded=k_launch * n_launch - real * n,
+            k_launch=k_launch,
+        )
 
 
 class DeviceBatchVerifier(BatchVerifier):
@@ -399,16 +489,53 @@ class TableBatchVerifier(DeviceBatchVerifier):
     def prebuild(self, pubkeys) -> None:
         """Warm the table cache for a validator set in the background —
         called when a valset rotation is decided (EndBlock diffs) so the
-        first verify against the NEXT set doesn't stall on a build."""
+        first verify against the NEXT set doesn't stall on a build. The
+        set is padded as a launch pads it, so this builds the table the
+        next launch looks up."""
         import threading
 
-        pubs = tuple(bytes(pk) for pk in pubkeys)
+        pubs, _ = self._launch_keys(
+            pubkeys, self._fused(None), self._launch_chips(len(pubkeys))
+        )
         if self._cache_key(pubs) in self._tables:
             return
 
         threading.Thread(
             target=lambda: self._tables_for(pubs), daemon=True
         ).start()
+
+    @staticmethod
+    def _fused(force_fused: bool | None) -> bool:
+        """Whether commit windows are shaped for the fused kernel: on
+        the TPU, where `verify_tables_kernel` picks it for a shape that
+        tiles. Off it the kernel never does, and padding would be pure
+        wasted lanes; `force_fused` lets CPU tests gate the pad logic."""
+        if force_fused is not None:
+            return force_fused
+        import jax
+
+        return jax.default_backend() == "tpu"
+
+    def _launch_chips(self, n: int) -> int:
+        """Chips a window over `n` validators is split across."""
+        return 1
+
+    @staticmethod
+    def _launch_keys(pubkeys, fused: bool, ndev: int = 1):
+        """The key sequence a launch's table is built and cached under,
+        and which of the `pubkeys` are well-formed: malformed keys
+        degrade to a False verdict (matching every other backend)
+        instead of corrupting the packed table build, and on the fused
+        path the set is padded to its launch width, so a set has ONE
+        cache key whatever window sizes arrive."""
+        n = len(pubkeys)
+        length_ok = np.array([len(pk) == 32 for pk in pubkeys], dtype=bool)
+        keys = [
+            bytes(pk) if ok else PLACEHOLDER_KEY
+            for pk, ok in zip(pubkeys, length_ok)
+        ]
+        keys.extend([PLACEHOLDER_KEY] * (_window_shape(1, n, fused, ndev)[0] - n))
+        return tuple(keys), length_ok
 
     def verify_commits(
         self,
@@ -440,11 +567,21 @@ class TableBatchVerifier(DeviceBatchVerifier):
         per chunk, device outputs left un-materialized (the fast-sync
         pipeline preps/applies other windows while these fly). Paths
         with no device half (small commits, table-build degradation)
-        compute on the spot and tag themselves "host"."""
-        from tendermint_tpu.ops.ed25519_tables import (
-            prepare_commit_lanes,
-            verify_tables_kernel,
-        )
+        compute on the spot and tag themselves "host".
+
+        The shape rule (`commit_launch_shape`): on the TPU every window
+        is launched at one padded shape, N rounded up to the 128 tile
+        and K in 2..16 up to 16 (32, 64, then chunks of 64 above), so
+        the executables a validator set needs do not depend on which
+        window sizes arrive. Pad columns carry `PLACEHOLDER_KEY` and
+        pad commits no votes: both verify False, hold no power and are
+        sliced off in `finalize_verify_commits`. What stays on the host
+        library: windows of k * n < `DEVICE_MIN_BATCH` REAL lanes,
+        where a launch costs more than the loop. K = 1 is apart:
+        consensus' own commit keeps its stack of one (latency), over
+        the same padded table. Off the TPU nothing is padded.
+        """
+        from tendermint_tpu.ops.ed25519_tables import verify_tables_kernel
 
         n = len(pubkeys)
         k = len(commits)
@@ -453,67 +590,36 @@ class TableBatchVerifier(DeviceBatchVerifier):
         if k * n < self._min_batch:
             # small commits: host loop beats a device launch
             return ("host", self._host_commit_loop(pubkeys, commits))
-        # malformed pubkeys degrade to a False verdict (matching every
-        # other backend) instead of corrupting the packed table build
-        length_ok = np.array([len(pk) == 32 for pk in pubkeys], dtype=bool)
-        if not length_ok.all():
-            placeholder = b"\x01" + b"\x00" * 31  # identity point encoding
-            pubkeys = [
-                pk if ok else placeholder for pk, ok in zip(pubkeys, length_ok)
-            ]
+        fused = self._fused(force_fused)
+        keys, length_ok = self._launch_keys(pubkeys, fused)
         try:
-            tables, key_ok = self._tables_for(tuple(pubkeys))
+            tables, key_ok = self._tables_for(keys)
         except TableBuildError:
             # table construction is down and the set is too big to
             # host-build: answer this call with host crypto (slow but
             # correct) instead of raising out of the consensus path
             return ("host", self._host_commit_loop(pubkeys, commits))
-        key_ok = key_ok & length_ok
-        # The fused pallas path wants K in multiples of 8 (lane planes
-        # are (8, 16K)) up to MAX_FUSED_STACK; pad with absent-vote
-        # commits (verify False, masked by precheck) and chunk larger
-        # windows so every launch takes the fast path. Only shape for it
-        # when it actually wins: off-TPU the kernel never selects fused
-        # (padding would be pure wasted lanes), and below K=8 the
-        # materialized K-small path serves — single-commit latency is
-        # the consensus loop's. The K=8 crossover is not measured on
-        # v5e (ROADMAP Queue 3 item 3).
-        import jax
-
-        from tendermint_tpu.ops.ed25519_tables import MAX_FUSED_STACK
-
-        fusable = (
-            (n % 128 == 0 and k >= 8 and jax.default_backend() == "tpu")
-            if force_fused is None
-            else force_fused
-        )
-        launches = []  # (device_out, precheck, real, part_len) per chunk
-        chunk = MAX_FUSED_STACK if fusable else len(commits)
+        key_ok = key_ok[:n] & length_ok
+        n_launch, chunks = _window_shape(k, n, fused)
+        launches = []  # (device_out, lane_ok, k_launch) per chunk
         t0 = time.perf_counter()
-        for lo in range(0, k, chunk):
-            part = list(commits[lo : lo + chunk])
-            real = len(part)
-            if fusable and real % 8 != 0:
-                absent = ([None] * n, [None] * n)
-                part.extend([absent] * (8 - real % 8))
-            s, h, r, precheck = prepare_commit_lanes(pubkeys, part)
+        for _real, k_launch, s, h, r, precheck in _window_chunks(
+            pubkeys, commits, n_launch, chunks
+        ):
             dev = verify_tables_kernel(tables, s, h, r)
-            launches.append((dev, precheck, real, len(part)))
-            _launchlog.annotate(
-                _additive=True, rows_padded=(len(part) - real) * n
-            )
+            launches.append((dev, precheck & key_ok, k_launch))
             _launchlog.add_transfer(s.nbytes + h.nbytes + r.nbytes)
-        return ("device", launches, key_ok, n, k, t0)
+        _launchlog.annotate(n_launch=n_launch)
+        return ("device", launches, n, k, n_launch, t0)
 
     def finalize_verify_commits(self, launched) -> np.ndarray:
         if launched[0] == "host":
             return launched[1]
-        _tag, launches, key_ok, n, k, t0 = launched
+        _tag, launches, n, k, n_launch, t0 = launched
         out_rows = []
-        for dev, precheck, real, part_len in launches:
-            out = np.asarray(dev)
-            out = (out & precheck & np.tile(key_ok, part_len)).reshape(-1, n)
-            out_rows.append(out[:real])
+        for dev, lane_ok, k_launch in launches:
+            out = np.asarray(dev).reshape(k_launch, n_launch)
+            out_rows.append(out[: len(lane_ok), :n] & lane_ok)
         _observe_verify("tables", k * n, time.perf_counter() - t0, kind="tables")
         return np.concatenate(out_rows, axis=0)
 
@@ -821,7 +927,7 @@ class ShardedTableBatchVerifier(_MeshFlatMixin, TableBatchVerifier):
                 raise MeshExhaustedError(
                     f"all {m.n_total} mesh devices faulted"
                 )
-            if n % m.n_active != 0:
+            if self._launch_chips(n) != m.n_active:
                 # uneven split: per-call fallback to the single-device
                 # table path (still breaker-guarded upstream)
                 return super().launch_verify_commits(
@@ -838,65 +944,55 @@ class ShardedTableBatchVerifier(_MeshFlatMixin, TableBatchVerifier):
                         f"all {m.n_total} mesh devices faulted"
                     ) from e
 
+    def _launch_chips(self, n: int) -> int:
+        """The active chips when the tables program serves the window:
+        a device mesh whose chips split `n` evenly; else 1, the
+        single-device table path."""
+        m = self.mesh
+        if m.n_total <= 1 or m.executor != "device":
+            return 1
+        ndev = m.n_active
+        return ndev if ndev and n % ndev == 0 else 1
+
     def _launch_mesh_tables(self, pubkeys, commits, force_fused=None):
-        from tendermint_tpu.ops.ed25519_tables import prepare_commit_lanes
         from tendermint_tpu.parallel.mesh import shard_lanes_validator_major
 
         n, k = len(pubkeys), len(commits)
         m = self.mesh
         ndev = m.n_active
-        length_ok = np.array([len(pk) == 32 for pk in pubkeys], dtype=bool)
-        if not length_ok.all():
-            placeholder = b"\x01" + b"\x00" * 31
-            pubkeys = [
-                pk if ok else placeholder for pk, ok in zip(pubkeys, length_ok)
-            ]
+        fused = self._fused(force_fused)
+        keys, length_ok = self._launch_keys(pubkeys, fused, ndev)
         try:
-            tables, key_ok = self._tables_for_mesh(tuple(pubkeys), m.mesh())
+            tables, key_ok = self._tables_for_mesh(keys, m.mesh())
         except TableBuildError:
             return ("host", self._host_commit_loop(pubkeys, commits))
-        key_ok = key_ok & length_ok
+        key_ok = key_ok[:n] & length_ok
         # Stack/chunk geometry from the PER-CHIP lane count: the fused
-        # plane shape inside verify_tables_kernel sees n/ndev columns
-        # per shard under shard_map, so fusability and the K padding
-        # rule use shard_n, not the global validator count (absent-vote
-        # pad commits verify False via precheck, sliced off at finalize)
-        import jax as _jax
-
-        from tendermint_tpu.ops.ed25519_tables import MAX_FUSED_STACK
-
-        shard_n = n // ndev
-        fusable = (
-            (shard_n % 128 == 0 and k >= 8 and _jax.default_backend() == "tpu")
-            if force_fused is None
-            else force_fused
-        )
+        # plane shape inside verify_tables_kernel sees n_launch/ndev
+        # columns per shard under shard_map, so the one shape rule is
+        # asked about n // ndev validators. Real validators fill the
+        # leading columns; the pad columns land on the last chips.
+        n_launch, chunks = _window_shape(k, n, fused, ndev)
         step = m.tables_step()
-        chunk = MAX_FUSED_STACK if fusable else k
-        launches = []  # (device_ok, real, part_len) per chunk
+        launches = []  # (device_ok, real, k_launch) per chunk
         t0 = time.perf_counter()
-        for lo in range(0, k, chunk):
-            part = list(commits[lo : lo + chunk])
-            real = len(part)
-            if fusable and real % 8 != 0:
-                absent = ([None] * n, [None] * n)
-                part.extend([absent] * (8 - real % 8))
-            s, h, r, precheck = prepare_commit_lanes(pubkeys, part)
-            lane_ok = precheck & np.tile(key_ok, len(part))
-            powers = np.ones(len(part) * n, dtype=np.int32)
+        for real, k_launch, s, h, r, precheck in _window_chunks(
+            pubkeys, commits, n_launch, chunks
+        ):
+            lane_ok = _pad_lanes(
+                (precheck & key_ok).reshape(-1), real, n, k_launch, n_launch
+            )
+            powers = np.ones(k_launch * n_launch, dtype=np.int32)
             s, h, r, lane_ok_s, powers = shard_lanes_validator_major(
-                [s, h, r, lane_ok, powers], n, ndev
+                [s, h, r, lane_ok, powers], n_launch, ndev
             )
             ok, _total = step(tables, s, h, r, lane_ok_s, powers)
-            launches.append((ok, real, len(part)))
-            _launchlog.annotate(
-                _additive=True, rows_padded=(len(part) - real) * n
-            )
+            launches.append((ok, real, k_launch))
             _launchlog.add_transfer(
                 s.nbytes + h.nbytes + r.nbytes + lane_ok_s.nbytes + powers.nbytes
             )
-        _launchlog.annotate(mesh_width=ndev)
-        return ("mesh_tables", launches, ndev, k, n, t0)
+        _launchlog.annotate(mesh_width=ndev, n_launch=n_launch)
+        return ("mesh_tables", launches, ndev, k, n, n_launch, t0)
 
     def finalize_verify_commits(self, launched) -> np.ndarray:
         if launched[0] in ("host_grid", "mesh_grid"):
@@ -905,11 +1001,11 @@ class ShardedTableBatchVerifier(_MeshFlatMixin, TableBatchVerifier):
             return super().finalize_verify_commits(launched)
         from tendermint_tpu.parallel.mesh import unshard_lanes_validator_major
 
-        _tag, launches, ndev, k, n, t0 = launched
+        _tag, launches, ndev, k, n, n_launch, t0 = launched
         rows = []
-        for ok, real, part_len in launches:
-            lanes = unshard_lanes_validator_major(np.asarray(ok), n, ndev)
-            rows.append(lanes.reshape(part_len, n)[:real])
+        for ok, real, k_launch in launches:
+            lanes = unshard_lanes_validator_major(np.asarray(ok), n_launch, ndev)
+            rows.append(lanes.reshape(k_launch, n_launch)[:real, :n])
         _observe_verify("mesh", k * n, time.perf_counter() - t0, kind="tables")
         return np.concatenate(rows, axis=0)
 

@@ -356,15 +356,24 @@ class TestTablesMeshGeometry:
         assert calls[-1]["lanes"] == 3 * 16
 
     def test_k_padding_from_per_shard_geometry(self, monkeypatch):
-        """force_fused pads the K stack to multiples of 8 with absent
-        commits (sliced off at finalize) — per-chip lane counts, the
-        single-device assumption removed."""
+        """force_fused pads the K stack to 16 with absent commits and
+        each chip's 2 validators to the 128 tile with placeholder
+        columns (both sliced off at finalize) — the one shape rule
+        asked about per-chip lane counts, the single-device assumption
+        removed."""
         privs, pubs, v, mgr, calls = self._verifier(16, monkeypatch)
+        monkeypatch.setattr(
+            v,
+            "_tables_for_mesh",
+            lambda pk, m: (None, np.ones(len(pk), dtype=bool)),
+        )
         commits = self._commits(privs, 3)
         grid = v.verify_commits(list(pubs), commits, force_fused=True)
         assert grid.shape == (3, 16)
         assert grid.all()
-        assert calls[-1]["lanes"] == 8 * 16  # K 3 -> padded stack of 8
+        # K 3 -> 16, N 16 -> 8 chips x 128
+        assert calls[-1]["lanes"] == 16 * 8 * 128
+        assert int(calls[-1]["lane_ok"].sum()) == 3 * 16  # pads carry no lane
 
     def test_uneven_valset_falls_back_to_single_device(self, monkeypatch):
         """N=10 does not split over 8 chips: the call degrades to the

@@ -319,33 +319,39 @@ class TestFusedPathShaping:
             commits.append((msgs, sigs))
         return commits, expected
 
-    def test_pad_and_chunk_boundaries(self, monkeypatch):
+    def test_pad_and_chunk_boundaries(self):
+        """The real kernel (XLA scan on the CPU) at the padded launch
+        shape: 8 validators in a 128-column table whose pad columns the
+        verifier's own incremental build made, 13 commits in a stack of
+        16 (`commit_launch_shape`; chunking past 64 commits is gated
+        without a compile in tests/test_launch_shape.py)."""
         import tendermint_tpu.ops.ed25519_tables as tbl_mod
 
-        # shrink the VMEM stack bound so chunking triggers at tiny K
-        monkeypatch.setattr(tbl_mod, "MAX_FUSED_STACK", 8)
         seen = []
-        real_prep = tbl_mod.prepare_commit_lanes
-        monkeypatch.setattr(
-            tbl_mod,
-            "prepare_commit_lanes",
-            lambda pubs, part: (seen.append(len(part)), real_prep(pubs, part))[1],
-        )
+        real_kernel = tbl_mod.verify_tables_kernel
+
+        def spy(tables, s, h, r):
+            seen.append((tables.shape[3], s.shape[0]))
+            return real_kernel(tables, s, h, r)
 
         privs, pubs, v = self._verifier_with_tables(8)
-        # K=13: chunk [8] + [5 -> padded to 8]; bad sigs at the chunk
-        # boundary (ci=7) and in the LAST REAL commit right against the
-        # padded tail (ci=12); absent votes sprinkled in both chunks
+        # bad sigs in the first commit, mid-stack, and in the LAST REAL
+        # commit right against the padded tail (ci=12), in the last real
+        # column right against the pad columns (vi=7); absent votes too
         commits, expected = self._commits(
             privs,
             13,
             corrupt={(0, 0), (7, 7), (12, 3)},
             absent={(2, 5), (12, 7)},
         )
-        got = v.verify_commits(pubs, commits, force_fused=True)
+        tbl_mod.verify_tables_kernel = spy
+        try:
+            got = v.verify_commits(pubs, commits, force_fused=True)
+        finally:
+            tbl_mod.verify_tables_kernel = real_kernel
         assert got.shape == (13, 8)
         assert (got == expected).all()
-        assert seen == [8, 8]  # second chunk padded 5 -> 8
+        assert seen == [(128, 16 * 128)]  # N 8 -> 128, K 13 -> 16
 
     def _spy_prep_fake_kernel(self, monkeypatch):
         """Record prepare_commit_lanes part sizes and replace the device
