@@ -56,7 +56,6 @@ class BlockStore:
         self._db = db
         self._height = 0
         self._base = 0
-        self._encoded_commit: tuple[Commit, bytes] | None = None
         raw = db.get(b"blockStore")
         if raw is not None:
             doc = json.loads(raw.decode())
@@ -106,20 +105,12 @@ class BlockStore:
         batch.set(self._meta_key(height), meta.encode())
         for i in range(part_set.total):
             batch.set(self._part_key(height, i), part_set.get_part(i).encode())
-        # commit of block H-1 (carried inside block H): asked for first,
-        # it is what the block before left in `_encode`'s memo
-        batch.set(self._commit_key(height - 1), self._encode(block.last_commit))
-        # commit that made THIS block (what we saw locally)
-        batch.set(self._seen_commit_key(height), self._encode(seen_commit))
-
-    def _encode(self, commit: Commit) -> bytes:
-        """`commit.encode()`, once a commit: fast-sync hands every commit
-        over twice, as the seen commit of block H and then, the same
-        object, as `last_commit` of block H+1 (a hundred votes each)."""
-        memo = self._encoded_commit
-        if memo is None or memo[0] is not commit:
-            memo = self._encoded_commit = (commit, commit.encode())
-        return memo[1]
+        # commit of block H-1 (carried inside block H)
+        batch.set(self._commit_key(height - 1), block.last_commit.encode())
+        # commit that made THIS block (what we saw locally): fast-sync
+        # hands it over again as `last_commit` of block H+1, and each of
+        # its votes keeps its own encoding (`Vote.encode`)
+        batch.set(self._seen_commit_key(height), seen_commit.encode())
 
     def _write(self, batch: Batch, height: int, base: int) -> None:
         """The batch and the watermark that covers it, one durable

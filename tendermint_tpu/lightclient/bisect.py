@@ -343,6 +343,7 @@ class BisectingCertifier:
         round_ = commit.round()
         prep = _SkipPrep(fc=fc)
         seen_old: set[bytes] = set()
+        msgs = commit.vote_sign_bytes(self.chain_id)
         for idx, precommit in enumerate(commit.precommits):
             if precommit is None:
                 continue
@@ -355,11 +356,7 @@ class BisectingCertifier:
             new_val = new.validators[idx]
             _, old_val = old.get_by_address(new_val.address)
             prep.triples.append(
-                (
-                    new_val.pub_key.data,
-                    precommit.sign_bytes(self.chain_id),
-                    precommit.signature,
-                )
+                (new_val.pub_key.data, msgs[idx], precommit.signature)
             )
             prep.new_powers.append(new_val.voting_power)
             old_credit = 0

@@ -543,6 +543,30 @@ for _stage in FASTSYNC_STAGES:
 for _cut in FASTSYNC_CUTS:
     FASTSYNC_WINDOWS.labels(cut=_cut).inc(0)
 
+# -- a vote's bytes (types/vote.py, types/block.py) ---------------------------
+#
+# Both are built once: over tendermint_fastsync_blocks_applied_total a
+# catching-up node reads one wire encoding a vote (not one each for the
+# part set, the commit's hash and the store) and, where every validator
+# signed the same block at the same time, one sign-bytes encoding a commit.
+
+VOTE_ENCODES = Counter(
+    "tendermint_vote_encodes_total",
+    "Vote wire encodings computed (Vote.encode keeps its bytes on the "
+    "frozen vote, so every later caller reuses them): the validator "
+    "count a fast-synced block",
+)
+COMMIT_SIGNBYTES = Counter(
+    "tendermint_commit_signbytes_total",
+    "Sign-bytes handed to a commit verifier, added once a commit walked "
+    "(Commit.vote_sign_bytes): encoded (one canonical-JSON encoding a "
+    "distinct signed content: block_id, height, round, timestamp, type) "
+    "or shared (another vote of the commit had the same content)",
+    labelnames=("source",),
+)
+for _source in ("encoded", "shared"):
+    COMMIT_SIGNBYTES.labels(source=_source).inc(0)
+
 # -- databases (db/kv.py) -----------------------------------------------------
 
 DB_COMMITS = Counter(

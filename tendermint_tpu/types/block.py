@@ -11,12 +11,17 @@ from dataclasses import dataclass, field
 
 from tendermint_tpu.codec import Reader, Writer, encode_string, encode_uvarint
 from tendermint_tpu.merkle import simple_hash_from_byte_slices, simple_hash_from_map
+from tendermint_tpu.telemetry.metrics import COMMIT_SIGNBYTES
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
 from tendermint_tpu.types.part_set import DEFAULT_PART_SIZE, PartSet
 from tendermint_tpu.types.tx import Txs
 from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT, Vote
 from tendermint_tpu.utils.bit_array import BitArray
+
+
+_SIGNBYTES_ENCODED = COMMIT_SIGNBYTES.labels(source="encoded")
+_SIGNBYTES_SHARED = COMMIT_SIGNBYTES.labels(source="shared")
 
 
 @dataclass
@@ -145,6 +150,29 @@ class Commit:
                 raise ValidationError(f"commit vote {i} is not a precommit")
             if v.height != h or v.round != r:
                 raise ValidationError(f"commit vote {i} has wrong height/round")
+
+    def vote_sign_bytes(self, chain_id: str) -> list[bytes | None]:
+        """`Vote.sign_bytes(chain_id)` of every precommit, None where a
+        validator is absent: what a commit verifier checks each signature
+        against. Sign-bytes carry no validator identity, so votes whose
+        signed fields are equal get the one encoding of the first of them
+        (the same object); a nil vote, a vote for another block or at
+        another time gets its own."""
+        by_content: dict[tuple, bytes] = {}
+        out: list[bytes | None] = []
+        for v in self.precommits:
+            if v is None:
+                out.append(None)
+                continue
+            content = (v.timestamp, v.block_id, v.height, v.round, v.type)
+            msg = by_content.get(content)
+            if msg is None:
+                msg = by_content[content] = v.sign_bytes(chain_id)
+            out.append(msg)
+        encoded = len(by_content)
+        _SIGNBYTES_ENCODED.inc(encoded)
+        _SIGNBYTES_SHARED.inc(len(out) - out.count(None) - encoded)
+        return out
 
     def encode(self) -> bytes:
         w = Writer().raw(self.block_id.encode()).uvarint(len(self.precommits))

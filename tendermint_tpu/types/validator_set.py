@@ -180,6 +180,7 @@ class ValidatorSet:
         round_ = commit.round()
         triples: list[tuple[bytes, bytes, bytes]] = []
         indices: list[int] = []
+        msgs = commit.vote_sign_bytes(chain_id)
         for idx, precommit in enumerate(commit.precommits):
             if precommit is None:
                 continue
@@ -190,9 +191,7 @@ class ValidatorSet:
             if precommit.type != VOTE_TYPE_PRECOMMIT:
                 raise ValidationError("commit vote is not a precommit")
             val = self.validators[idx]
-            triples.append(
-                (val.pub_key.data, precommit.sign_bytes(chain_id), precommit.signature)
-            )
+            triples.append((val.pub_key.data, msgs[idx], precommit.signature))
             indices.append(idx)
         return triples, indices
 
@@ -404,6 +403,7 @@ class ValidatorSet:
         old_powers: list[int] = []
         new_powers: list[int] = []
         seen: set[bytes] = set()
+        msgs = commit.vote_sign_bytes(chain_id)
         for idx, precommit in enumerate(commit.precommits):
             if precommit is None:
                 continue
@@ -420,9 +420,7 @@ class ValidatorSet:
             if old_val is None or old_val.address in seen:
                 continue
             seen.add(old_val.address)
-            triples.append(
-                (old_val.pub_key.data, precommit.sign_bytes(chain_id), precommit.signature)
-            )
+            triples.append((old_val.pub_key.data, msgs[idx], precommit.signature))
             old_powers.append(old_val.voting_power)
             new_powers.append(new_val.voting_power)
         ok_mask = _verify_triples(triples, verifier, consumer=consumer)

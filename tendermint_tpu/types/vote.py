@@ -10,8 +10,10 @@ signature key, which is what makes commit signatures batchable as
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from typing import ClassVar
 
 from tendermint_tpu.codec import Reader, Writer, canonical_dumps
+from tendermint_tpu.telemetry.metrics import VOTE_ENCODES
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
 
@@ -33,6 +35,11 @@ class Vote:
     type: int
     block_id: BlockID
     signature: bytes = b""
+
+    # `encode()`'s result, kept on the object once computed. No dataclass
+    # field: `__eq__`, `__hash__`, `repr` and `replace` never see it, and a
+    # vote made by `replace` / `with_signature` starts without one.
+    _encoded: ClassVar[bytes | None] = None
 
     def sign_bytes(self, chain_id: str) -> bytes:
         return canonical_dumps(
@@ -62,18 +69,27 @@ class Vote:
             raise ValidationError("negative validator index")
 
     def encode(self) -> bytes:
-        return (
-            Writer()
-            .bytes(self.validator_address)
-            .uvarint(self.validator_index)
-            .uvarint(self.height)
-            .uvarint(self.round)
-            .svarint(self.timestamp)
-            .uvarint(self.type)
-            .raw(self.block_id.encode())
-            .bytes(self.signature)
-            .build()
-        )
+        """The canonical wire encoding, computed once a vote: the vote is
+        frozen, and a commit's hash, its block's part set, the store and
+        the WAL all ask for the same bytes. (Never the bytes a peer sent:
+        `decode` accepts non-minimal varints.)"""
+        encoded = self._encoded
+        if encoded is None:
+            encoded = (
+                Writer()
+                .bytes(self.validator_address)
+                .uvarint(self.validator_index)
+                .uvarint(self.height)
+                .uvarint(self.round)
+                .svarint(self.timestamp)
+                .uvarint(self.type)
+                .raw(self.block_id.encode())
+                .bytes(self.signature)
+                .build()
+            )
+            object.__setattr__(self, "_encoded", encoded)
+            VOTE_ENCODES.inc()
+        return encoded
 
     @classmethod
     def decode_from(cls, r: Reader) -> "Vote":

@@ -253,31 +253,38 @@ class TestTheStoreHasOneWayToWrite:
             sim.advance(txs=[b"k%d=v" % h])
         return sim
 
-    def test_each_commit_is_encoded_once_and_the_rows_are_its_encoding(self, monkeypatch):
+    def test_each_commit_is_encoded_once_and_the_rows_are_its_encoding(self):
         """Fast-sync's order: the seen commit of block H is `last_commit`
-        of block H+1, the same object, and is not encoded again."""
+        of block H+1, the same object, and none of its votes is encoded
+        again (`Vote.encode` keeps its bytes on the vote)."""
         from tendermint_tpu.db.kv import MemDB
-        from tendermint_tpu.types.block import Commit
+        from tendermint_tpu.telemetry import REGISTRY
+        from tendermint_tpu.types.block import Block, Commit
+
+        def encodes() -> float:
+            return REGISTRY.counter_value("tendermint_vote_encodes_total")
 
         sim = self._chain(8)
-        part_sets = [b.make_part_set() for b in sim.blocks]  # encodes the block
-        calls = []
-        plain = Commit.encode
-        monkeypatch.setattr(Commit, "encode", lambda self: calls.append(id(self)) or plain(self))
+        # as a peer sends them: votes that have not been encoded yet
+        blocks = [Block.decode(b.encode()) for b in sim.blocks]
+        before = encodes()
+        part_sets = [b.make_part_set() for b in blocks]  # encodes the block
+        # block 1's last commit is empty; four votes a block after it
+        assert encodes() - before == 7 * 4
         db = MemDB()
         store = BlockStore(db)
         for i in range(7):
-            store.save_block(sim.blocks[i], part_sets[i], sim.blocks[i + 1].last_commit)
-        # block 1's empty last commit, then one new commit a block
-        assert len(calls) == 8 == len(set(calls))
+            store.save_block(blocks[i], part_sets[i], blocks[i + 1].last_commit)
+        assert encodes() - before == 7 * 4
         for h in range(1, 8):
-            assert db.get(b"SC:%d" % h) == plain(sim.blocks[h].last_commit)
+            assert db.get(b"SC:%d" % h) == sim.blocks[h].last_commit.encode()
             # the canonical commit of h comes with block h + 1
-            assert db.get(b"C:%d" % (h - 1)) == plain(sim.blocks[h - 1].last_commit)
+            assert db.get(b"C:%d" % (h - 1)) == sim.blocks[h - 1].last_commit.encode()
         # a commit that only looks the same is encoded for itself
-        other = Commit.decode_from(Reader(plain(sim.commits[7])))
-        store.save_block(sim.blocks[7], part_sets[7], other)
-        assert len(calls) == 9 and db.get(b"SC:8") == plain(sim.commits[7])
+        other = Commit.decode_from(Reader(sim.commits[7].encode()))
+        at = encodes()
+        store.save_block(blocks[7], part_sets[7], other)
+        assert encodes() - at == 4 and db.get(b"SC:8") == sim.commits[7].encode()
         assert db.get(b"C:7") == db.get(b"SC:7")
 
     def test_bootstrap_and_prune_are_one_transaction_each(self, tmp_path):
