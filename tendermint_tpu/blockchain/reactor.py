@@ -17,7 +17,6 @@ it applies.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from functools import partial
@@ -57,9 +56,9 @@ VERIFY_WINDOW = 16  # commits batched per device call
 # Verify windows kept in flight on device at once (docs/PERFORMANCE.md).
 # 2 is the classic software pipeline: window K's verdict flies while the
 # host preps K+1's part sets/lanes and applies K-1's blocks via ABCI.
-# 1 degenerates to the synchronous verify->apply loop (the bench
-# baseline); >2 only helps when launches are slower than applies.
-PIPELINE_DEPTH = int(os.environ.get("TENDERMINT_TPU_PIPELINE_DEPTH", "2"))
+# 1 degenerates to the synchronous verify->apply loop; >2 only helps
+# when launches are slower than applies.
+PIPELINE_DEPTH = 2
 
 _STAGE_SECONDS = {
     s: FASTSYNC_STAGE_SECONDS.labels(stage=s) for s in FASTSYNC_STAGES
@@ -79,28 +78,6 @@ def _stage(name: str, window: dict | None = None):
             window[name] = window.get(name, 0.0) + seconds
 
     return TRACER.stage("fastsync." + name, sink)
-
-
-def adaptive_pipeline_depth() -> int:
-    """Default pipeline depth from the measured launch:apply ratio.
-
-    The env knob always wins. Without it, the dispatch telemetry's
-    overlap histogram (what the fastsync queue already exports) gives
-    launch:apply ≈ (1-o)/o; `1 + round(ratio)` windows keep the device
-    busy while one window applies — balanced pipelines land on the
-    classic depth 2, launch-dominated ones deepen, apply-dominated ones
-    collapse to the synchronous loop. Clamped to [1, 4]: beyond 4 the
-    extra in-flight windows only add redo-drain latency.
-    """
-    env = os.environ.get("TENDERMINT_TPU_PIPELINE_DEPTH")
-    if env:
-        return max(1, int(env))
-    from tendermint_tpu.services.dispatch import measured_launch_apply_ratio
-
-    ratio = measured_launch_apply_ratio("fastsync")
-    if ratio is None:
-        return 2
-    return max(1, min(4, 1 + int(round(ratio))))
 
 
 def _enc(tag: int, *fields) -> bytes:
@@ -170,8 +147,7 @@ class BlockchainReactor(Reactor):
         self.deferred = deferred
         self.pool = BlockPool(start_height=store.height + 1)
         self.pipeline_depth = max(
-            1,
-            adaptive_pipeline_depth() if pipeline_depth is None else pipeline_depth,
+            1, PIPELINE_DEPTH if pipeline_depth is None else pipeline_depth
         )
         self._dispatch_queue = None  # lazy: only fast-syncing nodes need it
         self._running = False

@@ -338,22 +338,19 @@ class ConsensusState:
     # A backlog of this many same-(height, round, type) votes switches the
     # loop to one batched device verify instead of per-vote singles
     # (SURVEY §7 hard part 3: a 10k-validator vote storm must not verify
-    # 10k sigs one at a time on host while the TPU idles). Env-tunable
-    # so small validator sets can opt into batched preverifies too.
+    # 10k sigs one at a time on host while the TPU idles).
     # While a pipelined apply is in flight the gate drops to ANY run —
     # votes preverify through the coalescer instead of their tally
     # queuing behind the apply join (measured: the extra thread hops of
     # universal async singles COST latency on an idle loop, so the
     # always-async variant was reverted).
-    VOTE_DRAIN_MIN = int(os.environ.get("TENDERMINT_TPU_VOTE_DRAIN_MIN", "8"))
+    VOTE_DRAIN_MIN = 8
     VOTE_DRAIN_MAX = 4096
     # Vote-batch preverifies kept in flight: while batch K's signatures
     # fly on device, the loop keeps pulling the queue and drains batch
     # K+1 — verdicts join in drain order before ANY state mutation, so
     # consensus input order is exactly the synchronous loop's.
-    VOTE_PIPELINE_DEPTH = int(
-        os.environ.get("TENDERMINT_TPU_VOTE_PIPELINE_DEPTH", "2")
-    )
+    VOTE_PIPELINE_DEPTH = 2
 
     def _receive_loop(self) -> None:
         from collections import deque
@@ -1364,7 +1361,6 @@ class ConsensusState:
         self.commit_time = time_mod.time()
         self.step = RoundStepType.COMMIT
         self._observe_phase("commit")
-        self._new_step()
 
         block_id = self.votes.precommits(commit_round).two_thirds_majority()
         if block_id is None or block_id.is_zero():
@@ -1379,7 +1375,13 @@ class ConsensusState:
                 # we don't have the committed block: fetch via gossip
                 self.proposal_block = None
                 self.proposal_block_parts = PartSet.from_header(block_id.parts_header)
-                return
+        # announced only now, as the reference defers newStep to
+        # enterCommit's end: the reactor's CommitStep broadcast reads the
+        # parts header inside this event, and it must be the committed
+        # block's. A stale proposal's header with its bits set tells
+        # every peer this node holds a block nobody committed, and none
+        # sends it the real one.
+        self._new_step()
         self._try_finalize_commit(height)
 
     def _try_finalize_commit(self, height: int) -> None:

@@ -33,17 +33,14 @@ out (`TENDERMINT_TPU_SIGNED_TXS=0`, config `[mempool] signed_txs`, or
 `Mempool(signed_txs=False)`), which restores unconditional
 pass-through; all nodes of a chain must agree on the setting.
 
-Env knobs (mirroring the TENDERMINT_TPU_COALESCE discipline):
+Env knobs:
   TENDERMINT_TPU_INGRESS_BATCH=0      legacy synchronous admission
-  TENDERMINT_TPU_INGRESS_WINDOW_MS    flush window (default 2 ms)
-  TENDERMINT_TPU_INGRESS_MAX_BATCH    txs per window (default 1024)
   TENDERMINT_TPU_MEMPOOL_LANES        pool lanes (mempool.py)
   TENDERMINT_TPU_SIGNED_TXS=0         disable envelope recognition
 """
 
 from __future__ import annotations
 
-import os
 import queue as queue_mod
 import threading
 import time
@@ -60,6 +57,9 @@ SIGNED_TX_MAGIC = b"\xed\x01"
 _PK_LEN = 32
 _SIG_LEN = 64
 _HEADER_LEN = len(SIGNED_TX_MAGIC) + _PK_LEN + _SIG_LEN
+
+INGRESS_WINDOW_S = 0.002  # flush window age
+INGRESS_MAX_BATCH = 1024  # txs per window
 
 _STOP = object()
 
@@ -143,15 +143,10 @@ class IngressBatcher:
         self._verifier = verifier
         self._signed_txs = signed_txs
         if window_s is None:
-            window_s = (
-                float(os.environ.get("TENDERMINT_TPU_INGRESS_WINDOW_MS", "2.0"))
-                / 1e3
-            )
+            window_s = INGRESS_WINDOW_S
         self._window_s = max(0.0, window_s)
         if max_batch is None:
-            max_batch = int(
-                os.environ.get("TENDERMINT_TPU_INGRESS_MAX_BATCH", "1024")
-            )
+            max_batch = INGRESS_MAX_BATCH
         self._max_batch = max(1, max_batch)
         # Non-reentrant by construction (every `with self._cond:` block
         # is self-contained); ranked BELOW the lane locks — the joiner

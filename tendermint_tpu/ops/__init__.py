@@ -8,14 +8,12 @@ seams.
 """
 
 from tendermint_tpu.ops.sha256_kernel import sha256_batch_jax, sha256_digest_bytes
-from tendermint_tpu.ops.sha512_kernel import sha512_batch_jax
 from tendermint_tpu.ops.ripemd160_kernel import ripemd160_batch_jax
 from tendermint_tpu.ops.merkle_kernel import merkle_root_device, merkle_root_from_leaf_words
 
 __all__ = [
     "sha256_batch_jax",
     "sha256_digest_bytes",
-    "sha512_batch_jax",
     "ripemd160_batch_jax",
     "merkle_root_device",
     "merkle_root_from_leaf_words",

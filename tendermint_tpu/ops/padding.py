@@ -11,7 +11,6 @@ from __future__ import annotations
 import numpy as np
 
 SHA256_BLOCK_BYTES = 64
-SHA512_BLOCK_BYTES = 128
 
 
 def bucket_blocks(n: int, buckets: tuple[int, ...] = (1, 2, 4, 8, 16, 32)) -> int:
@@ -94,19 +93,6 @@ def pad_sha256_prefixed(
         buf.view(">u4").astype(np.uint32).reshape(n, mb, 16)
     )
     return blocks, counts
-
-
-def pad_sha512(msgs: list[bytes], max_blocks: int | None = None) -> tuple[np.ndarray, np.ndarray]:
-    """-> (blocks[B, max_blocks, 32] u32: words 2i=hi, 2i+1=lo of 64-bit BE words,
-    n_blocks[B] i32)."""
-    padded = [_md_pad(m, 128, 16, length_le=False) for m in msgs]
-    counts = np.array([len(p) // 128 for p in padded], dtype=np.int32)
-    mb = max_blocks if max_blocks is not None else bucket_blocks(int(counts.max(initial=1)))
-    out = np.zeros((len(msgs), mb, 32), dtype=np.uint32)
-    for i, p in enumerate(padded):
-        words = np.frombuffer(p, dtype=">u4").astype(np.uint32)  # already hi,lo pairs
-        out[i, : counts[i]] = words.reshape(-1, 32)
-    return out, counts
 
 
 def pad_ripemd160(msgs: list[bytes], max_blocks: int | None = None) -> tuple[np.ndarray, np.ndarray]:

@@ -24,10 +24,11 @@ assemble a jax.Array from per-host shards (each host supplies only the
 lanes of its own validators — the same shard-major layout
 `shard_lanes_validator_major` produces).
 
-There is no multi-host hardware in the bench environment, so this seam
-is exercised degenerately (1 process) by tests; the mesh/step code it
-feeds is the same code the 8-device virtual mesh and the driver's
-multichip dryrun run.
+There is no multi-host hardware here: tier-1 drives this seam in one
+process (`tests/test_services.py`), the slow suite in two CPU processes
+over `jax.distributed` (`tests/test_multiprocess.py`); it has never run
+across hosts or on a chip. The mesh/step code it feeds is the same code
+the 8-device virtual mesh and the driver's multichip dryrun run.
 """
 
 from __future__ import annotations

@@ -36,18 +36,11 @@ Two layers remove both costs:
 wrapped around the resilient device stack by `default_verifier()` —
 device faults keep degrading through `ResilientVerifier.call_async`
 inside the merged handles, invisible to the sub-handle consumers.
-
-Env knobs (all optional):
-  TENDERMINT_TPU_VERIFY_CACHE_SIZE   proven triples kept (65536; 0 off)
-  TENDERMINT_TPU_COALESCE_WINDOW_MS  fixed flush window (adaptive)
-  TENDERMINT_TPU_COALESCE_MAX_BATCH  triples per merged launch (4096)
-  TENDERMINT_TPU_COALESCE=0          default_verifier() skips the wrap
 """
 
 from __future__ import annotations
 
 import hashlib
-import os
 import queue as queue_mod
 import threading
 import time
@@ -64,10 +57,8 @@ from tendermint_tpu.telemetry import tracectx as _trace
 from tendermint_tpu.telemetry.flightrec import FLIGHT
 from tendermint_tpu.utils.lockrank import ranked_lock
 
-CACHE_SIZE = int(os.environ.get("TENDERMINT_TPU_VERIFY_CACHE_SIZE", "65536"))
-MAX_COALESCED_BATCH = int(
-    os.environ.get("TENDERMINT_TPU_COALESCE_MAX_BATCH", "4096")
-)
+CACHE_SIZE = 65536  # proven triples kept (LRU over 8 shards)
+MAX_COALESCED_BATCH = 4096  # triples per merged launch (the `size` flush)
 
 # Window bounds: never stall a request longer than one small fraction of
 # the launch cost it amortizes, never spin under 0.2 ms (scheduler
@@ -250,10 +241,7 @@ def _adaptive_window_s() -> float:
     """Flush window derived from what the telemetry already measured:
     a small fraction of the mean device launch cost, scaled up when the
     dispatch overlap histogram says launches dominate applies (more
-    coalescing amortizes more of the bottleneck). Env override wins."""
-    env = os.environ.get("TENDERMINT_TPU_COALESCE_WINDOW_MS")
-    if env:
-        return max(0.0, float(env) / 1e3)
+    coalescing amortizes more of the bottleneck)."""
     from tendermint_tpu.services.dispatch import measured_launch_apply_ratio
     from tendermint_tpu.telemetry import REGISTRY
 

@@ -28,7 +28,7 @@ joined), `tendermint_dispatch_queue_wait_seconds` (submit -> launch
 start), and `tendermint_dispatch_overlap_ratio` — the fraction of a
 handle's submit->join wall time the consumer spent doing OTHER work
 rather than blocked inside `result()`. Overlap > 0 is the direct proof
-the pipeline engaged (tools/bench_hotpath.py `fastsync_pipeline`).
+the pipeline engaged (tests/test_dispatch.py, tests/test_fastsync.py).
 """
 
 from __future__ import annotations
@@ -48,8 +48,8 @@ from tendermint_tpu.utils.lockrank import ranked_lock
 
 # In-flight launches per queue (submitted, not yet joined). 2 is the
 # classic double-buffer: one launch on device, one window of host prep.
-# Not measured on v5e (ROADMAP Queue 3 item 4(b)).
-DISPATCH_DEPTH = int(os.environ.get("TENDERMINT_TPU_DISPATCH_DEPTH", "2"))
+# Not measured on v5e.
+DISPATCH_DEPTH = 2
 
 # A submit() that cannot get a slot within this window means the
 # consumer abandoned its handles — fail loudly instead of wedging the
@@ -396,11 +396,10 @@ def measured_launch_apply_ratio(queue: str | None = None) -> float | None:
     blocked:overlapped = (1-o):o estimates device-launch time vs host
     apply time. None until any handle has been joined.
 
-    Consumers of the estimate: the fast-sync pipeline sizes its depth
-    (≈ 1 + ratio windows keeps the device busy while one applies) and
-    the verify coalescer scales its flush window (launch-dominated
-    pipelines amortize more per merged launch). `queue` narrows to one
-    pipeline's series; None aggregates all of them.
+    The verify coalescer scales its flush window by the estimate
+    (launch-dominated pipelines amortize more per merged launch).
+    `queue` narrows to one pipeline's series; None aggregates all of
+    them.
     """
     from tendermint_tpu.telemetry import REGISTRY
 

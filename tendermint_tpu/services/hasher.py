@@ -14,7 +14,6 @@ already-hashed-leaf aggregation).
 
 from __future__ import annotations
 
-import os
 import time
 
 import numpy as np
@@ -35,8 +34,8 @@ def _observe_hash(
 
 # Below this leaf count host hashlib answers; the device tree is for big
 # blocks (BASELINE config 4 is 65k leaves). The value is not measured on
-# v5e (ROADMAP Queue 1 item 2(d) owns the retune).
-DEVICE_MIN_LEAVES = int(os.environ.get("TENDERMINT_TPU_MIN_DEVICE_LEAVES", "8192"))
+# v5e (ROADMAP Queue 1 item 5).
+DEVICE_MIN_LEAVES = 8192
 
 
 class TreeHasher:

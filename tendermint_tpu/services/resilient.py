@@ -28,7 +28,6 @@ Env knobs (all optional):
   TENDERMINT_TPU_BREAKER_RESET_S     OPEN -> probe window seconds (5)
   TENDERMINT_TPU_DEVICE_RETRIES      in-call retries before failing (1)
   TENDERMINT_TPU_DEVICE_TIMEOUT_S    per-dispatch timeout (0 = none)
-  TENDERMINT_TPU_RESILIENT=1         wrap even on host-only backends
   TENDERMINT_TPU_DEVICE_FAIL         fault injection spec (utils/fail.py)
 """
 

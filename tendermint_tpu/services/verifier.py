@@ -1048,14 +1048,14 @@ def default_verifier() -> BatchVerifier:
     Device backends are wrapped in `ResilientVerifier` so a device
     fault degrades verification to host instead of killing consensus
     (`services/resilient.py`); host-only runs get the wrapper too when
-    fault injection / TENDERMINT_TPU_RESILIENT is armed, so chaos tests
-    exercise the same dispatch path CI-side.
+    fault injection (TENDERMINT_TPU_DEVICE_FAIL) is armed, so chaos
+    tests exercise the same dispatch path CI-side.
 
     Whatever the backend stack, the outermost layer is the
     `CoalescingVerifier` (`services/batcher.py`): a verified-signature
-    dedup cache plus the cross-consumer launch coalescer. Disable with
-    TENDERMINT_TPU_COALESCE=0 (the wrap is verdict-transparent — only
-    positives are cached, failures always re-verify).
+    dedup cache plus the cross-consumer launch coalescer (the wrap is
+    verdict-transparent — only positives are cached, failures always
+    re-verify).
     """
     global _DEFAULT
     if _DEFAULT is None:
@@ -1093,12 +1093,9 @@ def default_verifier() -> BatchVerifier:
                 )
             else:
                 inner = ResilientVerifier(TableBatchVerifier())
-        if os.environ.get("TENDERMINT_TPU_COALESCE", "1") != "0":
-            from tendermint_tpu.services.batcher import CoalescingVerifier
+        from tendermint_tpu.services.batcher import CoalescingVerifier
 
-            _DEFAULT = CoalescingVerifier(inner)
-        else:
-            _DEFAULT = inner
+        _DEFAULT = CoalescingVerifier(inner)
     return _DEFAULT
 
 

@@ -2,8 +2,7 @@
 
 The verify/hash spine's metrics say how many signatures a backend
 verified and how long the calls took; the height ledger says which
-consensus phase dominated a height; neither answers the question every
-"reseed on real silicon" caveat in BENCH_hotpath.json leaves open:
+consensus phase dominated a height; neither answers the question
 **for one device launch, where did the time and the capacity go?** The
 `LaunchLedger` answers it with ONE structured record per launch,
 assembled at the seams that already exist — no new plumbing through

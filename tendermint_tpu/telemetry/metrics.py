@@ -301,7 +301,7 @@ DISPATCH_QUEUE_WAIT = Histogram(
 # Per-handle share of submit->join wall time the consumer spent doing
 # other work (host prep, ABCI applies) instead of blocked in result().
 # 0 = fully synchronous behavior; anything > 0 proves the overlap
-# pipeline engaged (tools/bench_hotpath.py fastsync_pipeline section).
+# pipeline engaged.
 DISPATCH_OVERLAP = Histogram(
     "tendermint_dispatch_overlap_ratio",
     "Fraction of a dispatch handle's lifetime overlapped with host work",

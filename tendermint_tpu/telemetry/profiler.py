@@ -40,10 +40,6 @@ waits and holds then flow into ``tendermint_lock_wait_seconds{lock}``
 ``dump_telemetry?profile=1`` serves ``snapshot()`` + the lock view +
 the unified queue waits; ``tools/contention_report.py`` turns them
 into the per-subsystem on-CPU/blocked waterfall.
-
-Overhead is bench-guarded: `tools/bench_hotpath.py` ``profiler_overhead``
-holds the dedup replay within 3% at the default 29 Hz with lock timing
-armed (floor in tools/bench_floors.json).
 """
 
 from __future__ import annotations

@@ -158,6 +158,9 @@ def wait_evidence_committed(
                     break
         if len(found) == len(targets):
             return found
+        # poll like Nemesis.wait_height: a spin here holds the
+        # interpreter lock against the very net it waits for
+        time.sleep(0.05)
     raise TimeoutError(
         f"evidence for {address.hex()[:12]} not committed on nodes "
         f"{sorted(set(targets) - set(found))} within {timeout}s "

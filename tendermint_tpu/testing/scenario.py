@@ -23,9 +23,7 @@ by expectations, not assumed.
 `SCENARIO_LIBRARY` ships the standing suite: flash crowd, regional
 outage, slow-WAN validator, churn storm, partition-during-churn, plus
 tier-1-affordable variants (`slow_wan_validator`, `churn_small`).
-Heavy entries carry `"slow": True` — tests mark them accordingly and
-`tools/bench_hotpath.py --section scenario_finality` runs them with
-committed floors.
+Heavy entries carry `"slow": True` — tests mark them accordingly.
 """
 
 from __future__ import annotations

@@ -92,9 +92,7 @@ def clear_device_faults() -> None:
 def device_faults_armed() -> bool:
     """Any fault injection configured (env or runtime)? The service
     layer uses this to wrap resilient dispatch even on host-only runs."""
-    return bool(_load_device_faults()) or bool(
-        os.environ.get("TENDERMINT_TPU_RESILIENT")
-    )
+    return bool(_load_device_faults())
 
 
 def device_fail_point(kind: str) -> None:

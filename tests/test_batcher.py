@@ -557,27 +557,6 @@ class TestDedupConcurrency:
 
 
 class TestAdaptiveKnobs:
-    def test_pipeline_depth_env_wins(self, monkeypatch):
-        from tendermint_tpu.blockchain.reactor import adaptive_pipeline_depth
-
-        monkeypatch.setenv("TENDERMINT_TPU_PIPELINE_DEPTH", "3")
-        assert adaptive_pipeline_depth() == 3
-
-    def test_pipeline_depth_from_ratio_clamped(self, monkeypatch):
-        from tendermint_tpu.blockchain.reactor import adaptive_pipeline_depth
-        from tendermint_tpu.services import dispatch as dispatch_mod
-
-        monkeypatch.delenv("TENDERMINT_TPU_PIPELINE_DEPTH", raising=False)
-        # depth = clamp(1 + round(launch:apply), 1, 4); None (no samples
-        # yet) keeps the classic double-buffer default
-        for ratio, want in ((None, 2), (0.2, 1), (1.0, 2), (2.6, 4), (50.0, 4)):
-            monkeypatch.setattr(
-                dispatch_mod,
-                "measured_launch_apply_ratio",
-                lambda queue=None, r=ratio: r,
-            )
-            assert adaptive_pipeline_depth() == want
-
     def test_launch_apply_ratio_from_overlap_histogram(self):
         from tendermint_tpu.services.dispatch import (
             measured_launch_apply_ratio,

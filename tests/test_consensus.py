@@ -250,6 +250,37 @@ class TestQuorumProgress:
         finally:
             f.stop()
 
+    def test_commit_step_is_announced_with_the_committed_blocks_parts(self):
+        """+2/3 precommit a block we do not have while we still hold the
+        complete parts of our own proposal: the new-step event of the
+        commit step (where the reactor reads the parts header for its
+        CommitStep broadcast) must already see the COMMITTED block's
+        empty part set. Announced with the stale proposal's header and
+        every bit set, no peer ever sends the block and the node stays
+        in commit for good."""
+        f = Fixture(n_vals=4)
+        seen = []
+
+        def parts_at_commit(data):  # runs on the consensus thread
+            if data.step == "Commit":
+                parts = f.cs.proposal_block_parts
+                seen.append((parts.header, parts.is_complete()))
+
+        f.cs.event_switch.add_listener(
+            "parts-at-commit", ev.EVENT_NEW_ROUND_STEP, parts_at_commit
+        )
+        try:
+            f.cs.start()
+            own = f.proposal_block_id()
+            other = BlockID(b"\x42" * 20, PartSetHeader(1, b"\x43" * 20))
+            assert other.parts_header != own.parts_header
+            f.inject_votes(VOTE_TYPE_PRECOMMIT, other, [1, 2, 3])
+            f.wait_step("Commit")
+            assert seen == [(other.parts_header, False)]
+            assert f.cs.get_round_state().proposal_block is None
+        finally:
+            f.stop()
+
     def test_nil_precommits_go_to_next_round(self):
         f = Fixture(n_vals=4, real_ticker=True)
         try:

@@ -151,17 +151,49 @@ class TestFastSyncPipeline:
     / valset boundary must drain the in-flight suffix WITHOUT applying
     stale blocks (ISSUE 4 acceptance)."""
 
-    def test_pipelined_sync_applies_full_chain(self):
+    @pytest.mark.parametrize("depth", [1, 2, 3, None])
+    def test_pipelined_sync_applies_full_chain(self, depth):
+        """Any depth applies the same chain; no depth given means
+        `PIPELINE_DEPTH` = 2. Counts: every window launched is one handle
+        on the `fastsync` queue, joined once (one overlap observation a
+        window), and never more than `depth` of them are unjoined."""
+        from tendermint_tpu.services.verifier import HostBatchVerifier
+        from tendermint_tpu.telemetry import REGISTRY
+
+        windows_fam = REGISTRY.get("tendermint_fastsync_windows_total")
+        joins = REGISTRY.get("tendermint_dispatch_overlap_ratio").labels(
+            queue="fastsync"
+        )
+
         sim = ChainSim(n_vals=4)
         for _ in range(48):
             sim.advance()
-        for depth in (1, 2, 3):
-            reactor, state, store = _pipelined_reactor(sim, depth=depth)
-            reactor._try_sync()
-            assert store.height == 47, f"depth {depth}"
-            assert state.last_block_height == 47
-            for h in (1, 20, 47):
-                assert store.load_block(h).hash() == sim.blocks[h - 1].hash()
+        # a verifier of its own: through `default_verifier()` the dedup
+        # cache, which proved these votes when the chain was made,
+        # answers every window without a launch
+        reactor, state, store = _pipelined_reactor(
+            sim, depth=depth, verifier=HostBatchVerifier()
+        )
+        assert reactor.pipeline_depth == (2 if depth is None else depth)
+        peak = [0]
+        submit = reactor._queue().submit
+
+        def counting_submit(*a, **kw):
+            handle = submit(*a, **kw)
+            peak[0] = max(peak[0], reactor._queue().inflight())
+            return handle
+
+        reactor._queue().submit = counting_submit
+        windows0, joins0 = windows_fam.sum_total(), joins.value["count"]
+        reactor._try_sync()
+        assert store.height == 47, f"depth {depth}"
+        assert state.last_block_height == 47
+        for h in (1, 20, 47):
+            assert store.load_block(h).hash() == sim.blocks[h - 1].hash()
+        windows = windows_fam.sum_total() - windows0
+        assert windows == 3  # 47 commits in windows of 16
+        assert joins.value["count"] - joins0 == windows
+        assert peak[0] == min(reactor.pipeline_depth, windows)
 
     def test_linkage_break_mid_pipeline_applies_intact_prefix_only(self):
         """Window 2's commit linkage breaks while window 1 is in

@@ -11,14 +11,12 @@ from tendermint_tpu.ops import (
     ripemd160_batch_jax,
     sha256_batch_jax,
     sha256_digest_bytes,
-    sha512_batch_jax,
 )
 from tendermint_tpu.ops.padding import (
     digests_to_bytes_be,
     digests_to_bytes_le,
     pad_ripemd160,
     pad_sha256,
-    pad_sha512,
 )
 
 # Device-kernel compiles dominate runtime (~minutes per bucket shape);
@@ -48,15 +46,6 @@ def test_sha256_matches_hashlib():
 def test_sha256_convenience_api():
     msgs = [b"", b"abc", b"x" * 1000]
     assert sha256_digest_bytes(msgs) == [hashlib.sha256(m).digest() for m in msgs]
-
-
-def test_sha512_matches_hashlib():
-    msgs = msgs_of_lengths()
-    blocks, counts = pad_sha512(msgs)
-    out = np.asarray(sha512_batch_jax(blocks, counts))  # (B, 16) u32
-    got = digests_to_bytes_be(out)
-    want = [hashlib.sha512(m).digest() for m in msgs]
-    assert got == want
 
 
 def test_ripemd160_matches_hashlib():

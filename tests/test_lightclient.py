@@ -161,29 +161,32 @@ class TestBisectionMath:
         # intermediate hops became trusted (the memoization)
         assert trusted.get_by_height(3).height() >= 2
 
-    def test_dense_rotation_long_chain(self):
-        """64 heights rotating one of 8 validators every 4 heights:
-        bisection must converge in far fewer verifies than the
+    @pytest.mark.parametrize(
+        "tip,every,max_rounds", [(64, 4, 8), (256, 8, 12)]
+    )
+    def test_dense_rotation_long_chain(self, tip, every, max_rounds):
+        """`tip` heights rotating one of 8 validators every `every`
+        heights: bisection must converge in far fewer verifies than the
         sequential walk's one-commit-per-height."""
         base = list(range(1, 9))
 
         def privs_for(h):
-            rotated = (h - 1) // 4  # rotations accumulated by height h
+            rotated = (h - 1) // every  # rotations accumulated by height h
             ids = base[rotated % 8:] + [100 + i for i in range(rotated)]
             return _privs(sorted(ids[-8:]))
 
-        heights = list(range(1, 65))
+        heights = list(range(1, tip + 1))
         src, fcs = _chain_source(heights, privs_for)
         cert = BisectingCertifier(
             CHAIN, seed=fcs[1], trusted=MemProvider(), source=src
         )
-        cert.verify_to_height(64)
-        assert cert.last_height == 64
-        sequential_verifies = 64 * 8
+        cert.verify_to_height(tip)
+        assert cert.last_height == tip
+        sequential_verifies = tip * 8
         assert cert.last_walk_verifies < sequential_verifies / 2
         # the on-device cost term is LAUNCHES (rounds), not rows: the
         # sequential walk pays one per height, bisection a handful total
-        assert cert.last_walk_rounds <= 8
+        assert cert.last_walk_rounds <= max_rounds
 
     def test_unbridgeable_gap_raises_too_much_change(self):
         sets = {

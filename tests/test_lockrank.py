@@ -312,10 +312,15 @@ class TestMempoolAcceptance:
         t = threading.Thread(target=traffic, name="admission-traffic")
         t.start()
         time.sleep(0.05)
-        # the inversion: counter (52) held while taking a lane (40)
+        # the inversion: counter (52) held while taking a lane (40). The
+        # sanitizer judges the order at the attempt, before it waits, so
+        # the attempt is bounded: when the admission thread holds that
+        # lane and wants the counter this is a real ABBA, and an
+        # unbounded wait here wedged the whole worker
+        lane_lock = mp._lanes[0].lock
         with mp._counter_lock:
-            with mp._lanes[0].lock:
-                pass
+            if lane_lock.acquire(timeout=0.2):
+                lane_lock.release()
         stop.set()
         t.join(10)
         assert not t.is_alive(), "admission thread wedged"

@@ -8,8 +8,7 @@ speculated-round rebuild (`pipeline_stats["valset_rebuilds"]`) and
 PR 15's bisection bridging from the genesis valset across every
 epoch boundary — with the Nemesis no-fork/commit-agreement invariants
 green throughout. The heavy library entries (flash crowd, regional
-outage, churn storm, partition-during-churn) run slow-marked and in
-`tools/bench_hotpath.py --section scenario_finality`.
+outage, churn storm, partition-during-churn) run slow-marked.
 """
 
 from __future__ import annotations

@@ -356,46 +356,6 @@ class TestMetricsRoute:
             srv.stop()
 
 
-class TestBenchHotpath:
-    def test_emits_bench_json_from_registry(self, tmp_path):
-        import importlib.util
-        import os
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_hotpath",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                "tools",
-                "bench_hotpath.py",
-            ),
-        )
-        bh = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bh)
-
-        out = tmp_path / "BENCH_hotpath.json"
-        rc = bh.main(
-            [
-                "--out",
-                str(out),
-                "--reps",
-                "1",
-                "--sizes",
-                "8,16",
-                "--wal-records",
-                "16",
-                "--no-device",
-            ]
-        )
-        assert rc == 0
-        data = json.loads(out.read_text())
-        assert data["unit"] == "verifies/s"
-        assert data["value"] > 0
-        host = data["detail"]["verify"]["host"]
-        assert host["signatures"] >= 8 + 16  # this run's (registry may hold more)
-        assert data["detail"]["wal_fsync"]["count"] >= 16
-        assert data["detail"]["hash"]["host"]["leaves_per_s"] > 0
-
-
 class TestSpanPersistence:
     """Span timelines survive restarts: bounded JSONL ring under the
     data dir, replayed into the tracer on boot (ROADMAP observability

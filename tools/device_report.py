@@ -201,10 +201,9 @@ def build_report(records: list[dict]) -> dict:
             "cost_s": waste[top],
             "fix_first_on_silicon": _FIXES[top],
             "reseed_note": (
-                "reseed BENCH_hotpath.json device sections from this "
-                "report on the real pod (ROADMAP real-silicon reseed "
-                "bullet); the per-kind costs here are the launch cost "
-                "model for ROADMAP items 2 and 5"
+                "re-run this report against a node on the chip before "
+                "acting on it: off the chip the per-kind costs are host "
+                "times (PERF.md holds what was measured)"
             ),
         }
     return {

@@ -10,8 +10,7 @@ times the restore:
     python tools/statesync_demo.py --nodes 4 --interval 5 --chunk-size 4096
 
 Prints discovery/restore/parity timings plus the exported
-`tendermint_statesync_*` telemetry the run produced — the same series
-`tools/bench_hotpath.py --statesync` folds into BENCH_hotpath.json.
+`tendermint_statesync_*` telemetry the run produced.
 """
 
 from __future__ import annotations
