@@ -567,6 +567,15 @@ COMMIT_SIGNBYTES = Counter(
 for _source in ("encoded", "shared"):
     COMMIT_SIGNBYTES.labels(source=_source).inc(0)
 
+# -- a validator set's root (types/validator_set.py) --------------------------
+
+VALSET_HASHES = Counter(
+    "tendermint_valset_hashes_total",
+    "Validator-set Merkle roots actually computed (ValidatorSet.hash keeps "
+    "its root and copy() hands it on; only a membership or power change "
+    "drops it): about one a process on a static set, not one a block",
+)
+
 # -- databases (db/kv.py) -----------------------------------------------------
 
 DB_COMMITS = Counter(

@@ -14,6 +14,7 @@ from dataclasses import dataclass, replace
 from tendermint_tpu.codec import Writer
 from tendermint_tpu.crypto import PubKey
 from tendermint_tpu.merkle import simple_hash_from_byte_slices
+from tendermint_tpu.telemetry.metrics import VALSET_HASHES
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ErrTooMuchChange, ValidationError
 from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT
@@ -44,6 +45,10 @@ class Validator:
 
 class ValidatorSet:
     def __init__(self, validators: list[Validator]):
+        """A set from outside data (genesis, JSON, the wire): checked and
+        sorted here. `copy()` does not come back through these checks, so
+        whatever else changes membership or a power (`apply_changes`)
+        keeps them itself."""
         seen: set[bytes] = set()
         for v in validators:
             if v.address in seen:
@@ -52,10 +57,17 @@ class ValidatorSet:
                 raise ValidationError("negative voting power")
             seen.add(v.address)
         self.validators: list[Validator] = sorted(validators, key=lambda v: v.address)
+        # What follows depends on membership, keys and powers alone, never
+        # on accum, and a copy SHARES it with its source. So none of it is
+        # ever mutated in place: each is built once and rebound (to a new
+        # object or None) by whoever changes what it was built from, which
+        # is apply_changes and nothing else.
         self._total = sum(v.voting_power for v in self.validators)
-        self._proposer: Validator | None = None
         self._addr_index: dict[bytes, int] | None = None
         self._hash: bytes | None = None
+        self._json_static: tuple[str, ...] | None = None
+        # rebound by every rotation as well
+        self._proposer: Validator | None = None
 
     # -- basic accessors ---------------------------------------------------
 
@@ -92,7 +104,16 @@ class ValidatorSet:
         return self.get_by_address(address)[0] >= 0
 
     def copy(self) -> "ValidatorSet":
-        vs = ValidatorSet(list(self.validators))
+        """A set that rotates and changes apart from this one. Its list is
+        its own; the (frozen) validators and everything derived from
+        membership are this set's, not checked, sorted, summed or hashed
+        again: a block rotates the set, it does not rebuild it."""
+        vs = ValidatorSet.__new__(ValidatorSet)
+        vs.validators = list(self.validators)
+        vs._total = self._total
+        vs._addr_index = self._addr_index
+        vs._hash = self._hash
+        vs._json_static = self._json_static
         vs._proposer = self._proposer
         return vs
 
@@ -101,17 +122,26 @@ class ValidatorSet:
     def increment_accum(self, times: int = 1) -> None:
         """Weighted round-robin (reference `IncrementAccum
         types/validator_set.go:52-69`): each step adds voting power to every
-        accumulator, picks the max as proposer, subtracts total power from it."""
+        accumulator, picks the max as proposer, subtracts total power from it.
+        Higher accum wins and ties break to the lower address (reference
+        `Validator.CompareAccum`): the list is sorted by address, so that is
+        the first maximum. Only the accumulators move."""
+        if times <= 0:
+            return
+        if not self.validators:
+            raise ValidationError("empty validator set has no proposer")
+        powers = [v.voting_power for v in self.validators]
+        accums = [v.accum for v in self.validators]
         for _ in range(times):
-            self.validators = [
-                replace(v, accum=v.accum + v.voting_power) for v in self.validators
-            ]
-            proposer = self.validators[0]
-            for v in self.validators[1:]:
-                proposer = proposer.compare_proposer_priority(v)
-            idx, _ = self.get_by_address(proposer.address)
-            self.validators[idx] = replace(proposer, accum=proposer.accum - self._total)
-            self._proposer = self.validators[idx]
+            accums = [a + p for a, p in zip(accums, powers)]
+            top = max(accums)
+            idx = accums.index(top)
+            accums[idx] = top - self._total
+        self.validators = [
+            Validator(v.address, v.pub_key, v.voting_power, a)
+            for v, a in zip(self.validators, accums)
+        ]
+        self._proposer = self.validators[idx]
 
     @property
     def proposer(self) -> Validator:
@@ -128,21 +158,61 @@ class ValidatorSet:
 
     def hash(self) -> bytes:
         """Merkle root of the validator encodings (reference `Hash :145`).
-        Cached: the encoding covers address/pubkey/power only, so accum
-        rotation (increment_accum) does not change it; membership/power
-        changes invalidate in apply_changes."""
+        Kept, and handed on by copy(): the encoding covers
+        address/pubkey/power only, so accum rotation (increment_accum)
+        does not change it; membership/power changes drop it in
+        apply_changes."""
         if self._hash is None:
+            VALSET_HASHES.inc()
             self._hash = simple_hash_from_byte_slices(
                 [v.encode() for v in self.validators]
             )
         return self._hash
 
+    def to_json(self) -> str:
+        """The set as the state document and the per-height validators
+        rows carry it (`state/state.py`): byte for byte
+        `json.dumps({"validators": [{"accum", "address", "pub_key",
+        "voting_power"}, ...]}, sort_keys=True)`; `from_dict` reads it.
+        A block moves only the accums, so the hex of an address and a key
+        and the power are formatted once a membership (kept beside the
+        root, by the root's rule) and only `accum` once a call."""
+        static = self._json_static
+        if static is None:
+            static = self._json_static = tuple(
+                ', "address": "%s", "pub_key": "%s", "voting_power": %d}'
+                % (v.address.hex(), v.pub_key.data.hex(), v.voting_power)
+                for v in self.validators
+            )
+        rows = ['{"accum": %d%s' % (v.accum, s) for v, s in zip(self.validators, static)]
+        return '{"validators": [' + ", ".join(rows) + "]}"
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "ValidatorSet":
+        """The set `to_json` wrote, parsed: outside data, so checked."""
+        return cls(
+            [
+                Validator(
+                    address=bytes.fromhex(v["address"]),
+                    pub_key=PubKey(bytes.fromhex(v["pub_key"])),
+                    voting_power=v["voting_power"],
+                    accum=v["accum"],
+                )
+                for v in d["validators"]
+            ]
+        )
+
     # -- membership changes (EndBlock diffs) --------------------------------
 
     def apply_changes(self, changes: list[Validator]) -> None:
         """Apply app-driven diffs: power 0 removes, new address adds, else
-        updates (reference `updateValidators state/execution.go:120-159`)."""
+        updates (reference `updateValidators state/execution.go:120-159`).
+        The one place that changes membership or a power, so the one place
+        that drops what copies share: by rebinding, never in place (the
+        source of this copy still holds the old index, root and total)."""
         for c in changes:
+            if c.voting_power < 0:
+                raise ValidationError("negative voting power")
             idx, existing = self.get_by_address(c.address)
             if c.voting_power == 0:
                 if existing is None:
@@ -163,6 +233,7 @@ class ValidatorSet:
         self._proposer = None
         self._addr_index = None
         self._hash = None
+        self._json_static = None
 
     # -- commit verification (the hot loop) ---------------------------------
 

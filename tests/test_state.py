@@ -215,3 +215,213 @@ class TestFailPoints:
             [sys.executable, str(script)], env=env, capture_output=True, text=True
         )
         assert p.returncode == 0 and "SURVIVED" in p.stdout, p.stderr
+
+
+def _plain_valset(vs) -> dict:
+    return {
+        "validators": [
+            {
+                "address": v.address.hex(),
+                "pub_key": v.pub_key.data.hex(),
+                "voting_power": v.voting_power,
+                "accum": v.accum,
+            }
+            for v in vs.validators
+        ]
+    }
+
+
+def _plain_state_document(state) -> bytes:
+    """The state document as `json.dumps` writes it from plain dicts: the
+    form on disk in every node's state DB and inside every snapshot."""
+    import json
+
+    return json.dumps(
+        {
+            "chain_id": state.chain_id,
+            "consensus_params": state.consensus_params.to_dict(),
+            "last_block_height": state.last_block_height,
+            "last_block_id": {
+                "hash": state.last_block_id.hash.hex(),
+                "parts": {
+                    "total": state.last_block_id.parts_header.total,
+                    "hash": state.last_block_id.parts_header.hash.hex(),
+                },
+            },
+            "last_block_time": state.last_block_time,
+            "validators": _plain_valset(state.validators),
+            "last_validators": _plain_valset(state.last_validators),
+            "last_height_validators_changed": state.last_height_validators_changed,
+            "app_hash": state.app_hash.hex(),
+        },
+        sort_keys=True,
+    ).encode()
+
+
+class TestStateDocumentBytes:
+    """`State.to_json` formats what a block cannot change once a
+    membership; the bytes must stay `json.dumps`'s, whatever the sets
+    have been through since the static half was kept."""
+
+    SHAPES = ("genesis", "rotated", "extreme_accums", "after_apply_changes")
+
+    @staticmethod
+    def _state(n: int, shape: str):
+        from tendermint_tpu.state.state import ABCIResponses
+        from tendermint_tpu.abci.types import Validator as ABCIValidator
+        from tendermint_tpu.types.block import Header
+        from tendermint_tpu.types.part_set import PartSetHeader
+        from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+        from tests.helpers import det_priv_keys
+
+        genesis, _ = make_genesis(n, chain_id="bytes-é-chain")
+        state = make_genesis_state(MemDB(), genesis)
+        if shape == "genesis":
+            return state
+        state.to_json()  # the static half is kept from here on
+
+        def advance(changes=()):
+            height = state.last_block_height + 1
+            header = Header(
+                chain_id=state.chain_id, height=height, time=height * 10**9, num_txs=0,
+                last_block_id=state.last_block_id, validators_hash=state.validators.hash(),
+            )
+            state.set_block_and_validators(
+                header,
+                PartSetHeader(1, b"\x07" * 20),
+                ABCIResponses(height=height, end_block_changes=list(changes)),
+            )
+            state.app_hash = bytes([height]) * 20
+
+        for _ in range(3):
+            advance()
+        if shape == "extreme_accums":
+            accums = [-(2**63) - 1, 2**53 + 1, 2**64 + 3, -1, 0]
+            state.validators = ValidatorSet(
+                [
+                    Validator(v.address, v.pub_key, v.voting_power, accums[i % 5] + i)
+                    for i, v in enumerate(state.validators.validators)
+                ]
+            )
+            state.to_json()
+            advance()
+        elif shape == "after_apply_changes":
+            members = state.validators.validators
+            changes = [
+                ABCIValidator(det_priv_keys(n + 1)[n].pub_key.data, 7),  # added
+                ABCIValidator(members[0].pub_key.data, 33),  # re-powered
+            ]
+            if n > 1:
+                changes.append(ABCIValidator(members[-1].pub_key.data, 0))  # removed
+            advance(changes)
+            assert state.validators.size() == n + 1 - (n > 1)
+            assert state.last_validators.size() == n
+        return state
+
+    @pytest.mark.parametrize("shape", SHAPES)
+    @pytest.mark.parametrize("n", [1, 4, 100])
+    def test_the_bytes_are_json_dumps_own(self, n, shape):
+        from tendermint_tpu.state.state import State
+
+        state = self._state(n, shape)
+        for _ in range(2):  # built, then from what was kept
+            assert state.to_json() == _plain_state_document(state)
+        again = State.from_json(state.to_json(), db=state.db)
+        assert again.to_json() == state.to_json() and again.equals(state)
+        assert again.validators.hash() == state.validators.hash()
+        # a copy writes the same bytes and goes on rotating apart
+        copy = state.copy()
+        copy.validators.increment_accum(1)
+        assert copy.to_json() == _plain_state_document(copy)
+        # (a lone validator's accum stays where it is)
+        assert (copy.to_json() != state.to_json()) == (state.validators.size() > 1)
+        assert state.to_json() == _plain_state_document(state)
+
+    @pytest.mark.parametrize("n", [1, 4, 100])
+    def test_the_validators_rows_are_json_dumps_own(self, n):
+        import json
+
+        state = self._state(n, "after_apply_changes")
+        changed = state.last_height_validators_changed
+        assert changed == state.last_block_height + 1
+        full = json.dumps(
+            {"last_changed": changed, "validators": _plain_valset(state.validators)},
+            sort_keys=True,
+        ).encode()
+        assert state._validators_info_row() == (b"validatorsKey:%d" % changed, full)
+        state.save()
+        state.save_validators_full()
+        assert state.db.get(b"validatorsKey:%d" % changed) == full
+        state.last_block_height += 1
+        pointer = json.dumps({"last_changed": changed}, sort_keys=True).encode()
+        assert state._validators_info_row() == (b"validatorsKey:%d" % (changed + 1), pointer)
+        state.save()
+        assert state.load_validators(changed + 1).hash() == state.validators.hash()
+
+
+class TestValsetRootKept:
+    """`tendermint_valset_hashes_total` counts roots computed. A node that
+    applies a chain computes one a validator set, not one a block."""
+
+    N_VALS, N_BLOCKS = 16, 20
+    NAME = "tendermint_valset_hashes_total"
+
+    def _apply_chain(self, change_at: int | None):
+        """Build a chain on one node, apply it on a fresh one through
+        `apply_block`; the counter's rise after each block."""
+        from tendermint_tpu.crypto.keys import gen_priv_key
+        from tendermint_tpu.services.verifier import HostBatchVerifier
+        from tendermint_tpu.types import PrivValidator
+        from tendermint_tpu.state import apply_block
+        from tendermint_tpu.telemetry import REGISTRY
+
+        source = ChainSim(n_vals=self.N_VALS, app=PersistentKVStoreApp(MemDB()))
+        key = gen_priv_key(b"\x42" * 32)
+        newcomer = key.pub_key
+        source.privs.append(PrivValidator(key))  # signs once it is in the set
+        for height in range(1, self.N_BLOCKS + 1):
+            txs = [b"val:" + newcomer.data.hex().encode() + b"/9"] if height == change_at else []
+            source.advance(txs=txs)
+
+        state = make_genesis_state(MemDB(), source.genesis)
+        conns = local_client_creator(PersistentKVStoreApp(MemDB()))()
+        start = REGISTRY.counter_value(self.NAME)
+        rises = []
+        for block in source.blocks:
+            apply_block(
+                state, block, block.make_part_set().header, conns.consensus,
+                verifier=HostBatchVerifier(),
+            )
+            rises.append(int(REGISTRY.counter_value(self.NAME) - start))
+        assert state.equals(source.state)
+        return rises, state
+
+    def test_a_static_chain_costs_one_root(self):
+        from tendermint_tpu.telemetry import REGISTRY
+
+        rises, state = self._apply_chain(change_at=None)
+        assert len(rises) == self.N_BLOCKS
+        assert rises[0] <= 1 and rises[-1] == rises[0]
+        before = REGISTRY.counter_value(self.NAME)
+        assert state.last_validators.hash() == state.validators.copy().hash()
+        assert REGISTRY.counter_value(self.NAME) == before  # a kept root is free
+
+    def test_a_membership_change_costs_one_more_and_the_next_header_still_checks(self):
+        change_at = 10
+        rises, state = self._apply_chain(change_at=change_at)
+        # block `change_at` changes the set for height change_at + 1, whose
+        # header carries the new root: validate_block checked it, once
+        assert rises[change_at - 1] == rises[0] <= 1
+        assert rises[change_at] == rises[0] + 1 == rises[-1]
+        assert state.validators.size() == self.N_VALS + 1
+        assert state.last_height_validators_changed == change_at + 1
+
+    def test_the_counter_is_cataloged_and_documented(self):
+        import pathlib
+
+        from tendermint_tpu.telemetry import REGISTRY
+
+        docs = pathlib.Path(__file__).resolve().parents[1] / "docs" / "OBSERVABILITY.md"
+        assert self.NAME in REGISTRY.prometheus_text()
+        assert self.NAME in docs.read_text()

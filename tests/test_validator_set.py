@@ -144,3 +144,139 @@ def test_verify_commit_any_requires_new_set_quorum():
     commit = Commit(block_id=bid, precommits=precommits)
     with pytest.raises(ValidationError, match="new voting power"):
         vs.verify_commit_any(new_vs, CHAIN_ID, bid, 9, commit)
+
+
+# -- a block rotates the set, it does not rebuild it --------------------------
+#
+# copy() hands on what membership alone decides (root, address index, total
+# power, the static half of the JSON) and increment_accum touches only the
+# accumulators. Nothing a caller can observe may change with that.
+
+ROTATION_POWERS = {
+    "linear": [20 + 10 * i for i in range(100)],  # the benchmark's ToValidators(20, 10)
+    "uniform": [10] * 7,  # ties at every step
+    "one_whale": [1, 1, 1, 1, 1000],
+}
+
+
+def _set_with_powers(powers) -> ValidatorSet:
+    base, _ = make_validators(len(powers))
+    return ValidatorSet(
+        [Validator(v.address, v.pub_key, p) for v, p in zip(base.validators, powers)]
+    )
+
+
+def _reference_rotation(rows: list[list], total: int) -> bytes:
+    """One `IncrementAccum` step written plainly over [address, power,
+    accum] rows (sorted by address): returns the proposer's address."""
+    for row in rows:
+        row[2] += row[1]
+    best = rows[0]
+    for row in rows[1:]:
+        if row[2] > best[2] or (row[2] == best[2] and row[0] < best[0]):
+            best = row
+    best[2] -= total
+    return best[0]
+
+
+@pytest.mark.parametrize("powers", sorted(ROTATION_POWERS))
+def test_a_thousand_rotations_match_a_plain_reference(powers):
+    vs = _set_with_powers(ROTATION_POWERS[powers])
+    rows = [[v.address, v.voting_power, v.accum] for v in vs.validators]
+    total = sum(ROTATION_POWERS[powers])
+    for step in range(1000):
+        vs = vs.copy()  # as a block does: rotate a copy of the last set
+        vs.increment_accum(1)
+        want = _reference_rotation(rows, total)
+        assert vs.proposer.address == want, step
+        assert [v.accum for v in vs.validators] == [r[2] for r in rows], step
+        _, held = vs.get_by_address(want)
+        assert held is vs.proposer
+    assert sum(v.accum for v in vs.validators) == 0
+
+
+@pytest.mark.parametrize("powers", sorted(ROTATION_POWERS))
+def test_rotating_k_times_at_once_is_k_rotations(powers):
+    at_once = _set_with_powers(ROTATION_POWERS[powers])
+    stepwise = at_once.copy()
+    for k in (0, 1, 3, 17):
+        at_once.increment_accum(k)
+        for _ in range(k):
+            stepwise.increment_accum(1)
+        assert at_once.validators == stepwise.validators
+        if k:
+            assert at_once.proposer == stepwise.proposer
+
+
+def _observed(vs: ValidatorSet) -> tuple:
+    return (
+        vs.hash(),
+        vs.total_voting_power,
+        vs.proposer,
+        list(vs.validators),
+        [vs.get_by_address(v.address) for v in vs.validators],
+        vs.to_json(),
+    )
+
+
+@pytest.mark.parametrize("changed", ["the_copy", "the_source"])
+def test_a_copy_and_its_source_change_apart(changed):
+    from tests.helpers import det_priv_keys
+
+    source, _ = make_validators(6)
+    source.increment_accum(2)
+    _observed(source)  # everything a copy shares is built before the copy
+    copy = source.copy()
+    assert _observed(copy) == _observed(source)
+    mover, still = (copy, source) if changed == "the_copy" else (source, copy)
+    before = _observed(still)
+
+    mover.increment_accum(3)
+    assert _observed(still) == before
+    newcomer = det_priv_keys(7)[6].pub_key
+    gone, repowered = mover.validators[0], mover.validators[1]
+    mover.apply_changes(
+        [
+            Validator(newcomer.address, newcomer, 5),
+            Validator(gone.address, gone.pub_key, 0),
+            Validator(repowered.address, repowered.pub_key, 77),
+        ]
+    )
+    mover.increment_accum(1)
+    assert _observed(still) == before
+    assert still.has_address(gone.address) and not still.has_address(newcomer.address)
+
+    # and the set that moved reads what a set built from its members reads
+    rebuilt = ValidatorSet(list(mover.validators))
+    assert mover.hash() == rebuilt.hash() != before[0]
+    assert mover.total_voting_power == rebuilt.total_voting_power == 60 - 10 - 10 + 5 + 77
+    assert mover.to_json() == rebuilt.to_json()
+    assert not mover.has_address(gone.address)
+    for i, v in enumerate(mover.validators):
+        assert mover.get_by_address(v.address) == (i, v)
+
+
+@pytest.mark.parametrize("fault", ["duplicate_address", "negative_power", "negative_change"])
+def test_outside_data_is_still_checked(fault):
+    vs, _ = make_validators(3)
+    v = vs.validators[1]
+    with pytest.raises(ValidationError):
+        if fault == "duplicate_address":
+            ValidatorSet(list(vs.copy().validators) + [v])
+        elif fault == "negative_power":
+            ValidatorSet([Validator(v.address, v.pub_key, -1)])
+        else:
+            # copy() no longer walks the set, so the one place a power
+            # changes refuses what the constructor refuses
+            vs.copy().apply_changes([Validator(v.address, v.pub_key, -1)])
+
+
+def test_an_unsorted_list_is_sorted_and_an_empty_set_has_no_proposer():
+    vs, _ = make_validators(5)
+    shuffled = ValidatorSet(list(reversed(vs.validators)))
+    assert shuffled.validators == vs.validators and shuffled.hash() == vs.hash()
+    empty = ValidatorSet([]).copy()
+    assert empty.size() == 0 and empty.total_voting_power == 0
+    assert empty.to_json() == '{"validators": []}'
+    with pytest.raises(ValidationError):
+        empty.increment_accum(1)
