@@ -134,10 +134,10 @@ class HostBatchVerifier(BatchVerifier):
 # launch has a fixed cost while a host verify is ~60 us, so single votes
 # and small commits must never wait on a kernel launch (the consensus hot
 # path verifies one gossiped vote at a time). For a commit window the
-# count is k * n of the commits and validators handed in, never of the
-# padded launch shape (`commit_launch_shape`): at 100 validators windows
-# of K <= 5 stay on the host library, 42% of fast-sync's launches. The
-# value is not measured on v5e (ROADMAP Queue 1 item 2(d) owns the retune).
+# count is k * n as handed in, never of the padded launch shape
+# (`commit_launch_shape`): at 100 validators windows of K <= 5 stay on the
+# host library; at 1,000 every window, K = 1 too, is a device launch (the cell
+# `fastsync-1k.sparse`). Not measured on v5e (ROADMAP Queue 1 item 2(d)).
 DEVICE_MIN_BATCH = int(os.environ.get("TENDERMINT_TPU_MIN_DEVICE_BATCH", "512"))
 
 # What a pad column of a commit window's launch carries, and what stands
@@ -164,9 +164,9 @@ def commit_launch_shape(k: int, n: int) -> tuple[int, list[tuple[int, int]]]:
     50-60 s to compile, met as a stall). K = 1 is apart and keeps its
     stack of one on the materialized chain: it is consensus' own
     `verify_commit`, latency-bound, and a 16-stack of 1,024 validators
-    is 16,384 lanes where it needs 1,024 (13.7 ms of fused kernel
-    against a 7.0 ms round trip: PERF.md section 6). It shares the
-    padded table with the stacks.
+    is 16,384 lanes where it needs 1,024 (PERF.md section 6). It shares
+    the padded table with the stacks: 1,000 validators launch both at 1,024
+    columns (the cell `fastsync-1k.sparse`: `verify.single_commit_launch_share`).
     """
     from tendermint_tpu.ops.ed25519_tables import MAX_FUSED_STACK, V_TILE
 

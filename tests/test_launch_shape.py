@@ -181,14 +181,23 @@ def _non_canonical(sig):
 FAULTS = ["absent", "forged", "non_canonical_s", "malformed_key"]
 
 
+def _stack(k):
+    """The stack a window of up to 16 commits is launched at."""
+    return 1 if k == 1 else 16
+
+
 class TestPaddedPath:
     @pytest.mark.parametrize("fault", FAULTS)
+    @pytest.mark.parametrize("k", [1, 2, 6, 16])
     @pytest.mark.parametrize("n", [100, 130])
-    def test_grid_equals_the_host_loop(self, monkeypatch, n, fault):
+    def test_grid_equals_the_host_loop(self, monkeypatch, n, k, fault):
+        """K = 1 is the materialized stack of one (reached here through
+        `min_device_batch`, at 1,000 validators by every window of one
+        commit), K = 2 and 16 the ends of the padded stack of 16."""
         privs, pubs = _set(n)
-        k = 6
         commits = _window(privs, k)
-        at = [(0, 0), (3, n - 1), (k - 1, n // 2)]  # first, last column, last commit
+        # first lane, last column, last commit
+        at = sorted({(0, 0), (min(3, k - 1), n - 1), (k - 1, n // 2)})
         for c, i in at:
             msgs, sigs = commits[c]
             if fault == "absent":
@@ -204,11 +213,32 @@ class TestPaddedPath:
         want = v._host_commit_loop(pubs, commits)
         rec = _recorded(monkeypatch, v, commits)
         got = v.verify_commits(pubs, commits, force_fused=True)
-        n_launch = 128 if n == 100 else 256
-        assert rec.shapes == [(n_launch, 16 * n_launch)]
+        assert rec.shapes == [(N_LAUNCH[n], _stack(k) * N_LAUNCH[n])]
         assert got.shape == (k, n) and got.dtype == bool
         assert (got == want).all()
         assert int((~got).sum()) == (2 * k if fault == "malformed_key" else len(at))
+
+    @pytest.mark.parametrize("k", [1, 2, 16])
+    def test_no_pad_column_or_pad_commit_ever_reports_true(self, monkeypatch, k):
+        """What the kernel says of a lane whose rows are zero is garbage.
+        Here it says True of every lane it is handed: the verdicts are
+        the real lanes' alone, and an absent vote among them stays False."""
+        n = 130
+        privs, pubs = _set(n)
+        commits = _window(privs, k)
+        commits[k - 1][0][n - 1] = commits[k - 1][1][n - 1] = None
+        v = TableBatchVerifier(min_device_batch=1)
+        _recorded(monkeypatch, v, commits)
+        lanes = []
+        monkeypatch.setattr(
+            tbl_mod,
+            "verify_tables_kernel",
+            lambda tables, s, h, r: (lanes.append(s.shape[0]), np.ones(s.shape[0], dtype=bool))[1],
+        )
+        got = v.verify_commits(pubs, commits, force_fused=True)
+        assert lanes == [_stack(k) * 256]
+        assert got.shape == (k, n)
+        assert int(got.sum()) == k * n - 1 and not got[k - 1, n - 1]
 
     def test_pad_lanes_are_not_walked_by_the_prep_loop(self, monkeypatch):
         privs, pubs = _set(100)
@@ -284,44 +314,34 @@ class TestPaddedPath:
             assert v.verify_commits(pubs, commits).all()
         assert len(built) == 1 and len(v._tables) == 1
 
-    def test_ledger_record_carries_the_launch_shape(self, monkeypatch):
-        privs, pubs = _set(100)
-        commits = _window(privs, 6)
+    @pytest.mark.parametrize(
+        "n,k", [(100, 6), (100, 1), (100, 2), (100, 16), (130, 1), (130, 2), (130, 16)]
+    )
+    def test_ledger_record_carries_the_launch_shape(self, monkeypatch, n, k):
+        privs, pubs = _set(n)
+        commits = _window(privs, k)
         v = TableBatchVerifier(min_device_batch=1)
         _recorded(monkeypatch, v, commits)
         v.verify_commits(pubs, commits, force_fused=True)
         rec = LAUNCHLOG.recent(kind="tables")[-1]
-        assert (rec["k_launch"], rec["n_launch"]) == (16, 128)
-        assert rec["rows"] == 600 and rec["rows_padded"] == 16 * 128 - 600
+        assert (rec["k_launch"], rec["n_launch"]) == (_stack(k), N_LAUNCH[n])
+        assert rec["rows"] == k * n
+        # at the stack of one as at the stack of 16, what was launched
+        # is what was asked, what the cache kept back and the padding
+        assert (
+            rec["rows"] + rec.get("rows_cached", 0) + rec["rows_padded"]
+            == rec["k_launch"] * rec["n_launch"]
+        )
         # off the fused path the shape is the window's own
         v.verify_commits(pubs, commits)
         rec = LAUNCHLOG.recent(kind="tables")[-1]
-        assert (rec["k_launch"], rec["n_launch"], rec["rows_padded"]) == (6, 100, 0)
+        assert (rec["k_launch"], rec["n_launch"], rec["rows_padded"]) == (k, n, 0)
 
     def test_a_forged_commit_is_refused_by_validator_entry_and_height(self, monkeypatch):
-        from tendermint_tpu.testing.nemesis import make_genesis
-        from tendermint_tpu.types import BlockID, Commit
+        from tendermint_tpu.types import Commit
         from tendermint_tpu.types.errors import ValidationError
-        from tendermint_tpu.types.part_set import PartSetHeader
-        from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT, Vote
 
-        chain = "launch-shape"
-        genesis, privs = make_genesis(100, chain_id=chain)
-        valset = genesis.validator_set()
-        entries = []
-        for height in range(5, 11):
-            bid = BlockID(bytes([height]) * 20, PartSetHeader(total=1, hash=b"\x22" * 20))
-            votes = [
-                p.sign_vote(
-                    chain,
-                    Vote(
-                        validator_address=p.address, validator_index=i, height=height,
-                        round=0, timestamp=1, type=VOTE_TYPE_PRECOMMIT, block_id=bid,
-                    ),
-                )
-                for i, p in enumerate(privs)
-            ]
-            entries.append((bid, height, Commit(block_id=bid, precommits=votes)))
+        valset, entries = _signed_window(100, range(5, 11))
         bid, height, commit = entries[4]
         votes = list(commit.precommits)
         sig = votes[99].signature
@@ -330,31 +350,96 @@ class TestPaddedPath:
 
         def refusal(verifier, window):
             with pytest.raises(ValidationError) as e:
-                valset.verify_commit_batched(chain, window, verifier)
+                valset.verify_commit_batched(CHAIN, window, verifier)
             return str(e.value)
 
-        class Padded(TableBatchVerifier):
-            accepts_consumer = False
-
-            def verify_commits(self, pubkeys, commits, force_fused=None):
-                return super().verify_commits(pubkeys, commits, force_fused=True)
-
-        v = Padded(min_device_batch=1)
-        by_sig = {}
-        rec = _Recorder(by_sig)
-        for _b, _h, c in forged:
-            for vote in c.precommits:
-                by_sig[vote.signature] = vote.sign_bytes(chain)
-        monkeypatch.setattr(tbl_mod, "verify_tables_kernel", rec)
-        monkeypatch.setattr(
-            v, "_build_tables", lambda keys: (_Tables(keys), np.ones(len(keys), dtype=bool))
-        )
+        v, rec = _padded_verifier(monkeypatch, forged)
         host = vmod.HostBatchVerifier()
         got = refusal(v, forged)
         assert rec.shapes == [(128, 2048)]
         assert got == refusal(host, forged)
         assert "validator 99 (batch entry 4, height 9)" in got
-        valset.verify_commit_batched(chain, entries, v)  # the clean window passes
+        valset.verify_commit_batched(CHAIN, entries, v)  # the clean window passes
+
+    @pytest.mark.parametrize("k", [1, 2, 16])
+    def test_the_quorum_edge_of_uniform_power_is_a_count(self, monkeypatch, k):
+        """3m validators of one power: more than 2/3 of the power is
+        2m + 1 precommits. A window whose last commit holds 2m valid
+        ones is refused and one with 2m + 1 accepted, through the padded
+        table path as by `verify_commit` on the host; the 29 pad columns
+        and the pad commits weigh nothing on either side of the edge."""
+        from tendermint_tpu.types import Commit
+        from tendermint_tpu.types.errors import ValidationError
+
+        m = 33
+        valset, entries = _signed_window(3 * m, range(5, 5 + k))
+        assert {val.voting_power for val in valset.validators} == {10}
+        bid, height, commit = entries[-1]
+
+        def holding(signed):
+            votes = [vote if i < signed else None for i, vote in enumerate(commit.precommits)]
+            return entries[:-1] + [(bid, height, Commit(block_id=bid, precommits=votes))]
+
+        v, rec = _padded_verifier(monkeypatch, entries)
+        host = vmod.HostBatchVerifier()
+        short, enough = holding(2 * m), holding(2 * m + 1)
+        for verifier in (v, host):
+            with pytest.raises(ValidationError, match=f"insufficient voting power: {20 * m} of {30 * m}"):
+                valset.verify_commit_batched(CHAIN, short, verifier)
+            valset.verify_commit_batched(CHAIN, enough, verifier)
+        with pytest.raises(ValidationError, match="insufficient"):
+            valset.verify_commit(CHAIN, bid, height, short[-1][2], host)
+        valset.verify_commit(CHAIN, bid, height, enough[-1][2], host)
+        assert rec.shapes == [(128, _stack(k) * 128)] * 2
+
+
+CHAIN = "launch-shape"
+
+
+def _signed_window(n, heights):
+    """A genesis set of `n` validators of power 10 and one fully signed
+    commit per height: (valset, [(block_id, height, commit), ...])."""
+    from tendermint_tpu.testing.nemesis import make_genesis
+    from tendermint_tpu.types import BlockID, Commit
+    from tendermint_tpu.types.part_set import PartSetHeader
+    from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT, Vote
+
+    genesis, privs = make_genesis(n, chain_id=CHAIN)
+    entries = []
+    for height in heights:
+        bid = BlockID(bytes([height]) * 20, PartSetHeader(total=1, hash=b"\x22" * 20))
+        votes = [
+            p.sign_vote(
+                CHAIN,
+                Vote(
+                    validator_address=p.address, validator_index=i, height=height,
+                    round=0, timestamp=1, type=VOTE_TYPE_PRECOMMIT, block_id=bid,
+                ),
+            )
+            for i, p in enumerate(privs)
+        ]
+        entries.append((bid, height, Commit(block_id=bid, precommits=votes)))
+    return genesis.validator_set(), entries
+
+
+def _padded_verifier(monkeypatch, entries):
+    """A table verifier that launches every window at the padded shape,
+    its kernel the recorder over the votes of `entries`."""
+
+    class Padded(TableBatchVerifier):
+        accepts_consumer = False
+
+        def verify_commits(self, pubkeys, commits, force_fused=None):
+            return super().verify_commits(pubkeys, commits, force_fused=True)
+
+    v = Padded(min_device_batch=1)
+    votes = [[vote for vote in c.precommits if vote is not None] for _b, _h, c in entries]
+    rec = _recorded(
+        monkeypatch,
+        v,
+        [([vote.sign_bytes(CHAIN) for vote in vs], [vote.signature for vote in vs]) for vs in votes],
+    )
+    return v, rec
 
 
 class TestShardedPaddedPath:
