@@ -109,7 +109,8 @@ class BlockStore:
         batch.set(self._commit_key(height - 1), block.last_commit.encode())
         # commit that made THIS block (what we saw locally): fast-sync
         # hands it over again as `last_commit` of block H+1, and each of
-        # its votes keeps its own encoding (`Vote.encode`)
+        # its votes has its encoding already: the canonical bytes it was
+        # decoded from, or `Vote.encode`'s own where a varint was padded
         batch.set(self._seen_commit_key(height), seen_commit.encode())
 
     def _write(self, batch: Batch, height: int, base: int) -> None:

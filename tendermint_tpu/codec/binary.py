@@ -13,6 +13,15 @@ and every p2p message). Encoding rules:
 Every encoder is a pure function of the value — no maps with nondeterministic
 iteration order, no floats. All integers are arbitrary-precision Python ints;
 heights/rounds fit int64 by validation at the type layer.
+
+Decoders accept more than encoders emit. An encoder writes every varint
+minimal (no `0x80 ... 0x00` padding); a decoder also takes a padded one and
+gives the same value. Bytes are *canonical* when every varint in them is
+minimal: for a struct of fixed field order with no optional fields that is
+exactly when encoding the decoded value gives those bytes back. A varint is
+padded exactly when it is longer than one byte and its last byte is `0x00`;
+`Reader.padded` says whether the reader has passed one, so a type whose
+encoding is wanted again (`Vote`) can keep canonical bytes and no others.
 """
 
 from __future__ import annotations
@@ -115,25 +124,42 @@ class Writer:
 
 
 class Reader:
-    """Sequential decoder with bounds checking."""
+    """Sequential decoder with bounds checking. `padded` turns True, and
+    stays so, once a varint read (a value or a length prefix) was not
+    minimal: the bytes read so far are then not what an encoder writes."""
 
-    __slots__ = ("data", "offset")
+    __slots__ = ("data", "offset", "padded")
 
     def __init__(self, data: bytes, offset: int = 0) -> None:
         self.data = data
         self.offset = offset
+        self.padded = False
 
     def uvarint(self) -> int:
-        n, self.offset = decode_uvarint(self.data, self.offset)
+        data = self.data
+        start = self.offset
+        # one byte is always minimal, and is most varints: no call, no check
+        if start < len(data) and data[start] < 0x80:
+            self.offset = start + 1
+            return data[start]
+        n, end = decode_uvarint(data, start)
+        if not data[end - 1]:
+            self.padded = True
+        self.offset = end
         return n
 
     def svarint(self) -> int:
-        n, self.offset = decode_svarint(self.data, self.offset)
-        return n
+        u = self.uvarint()
+        return (u >> 1) ^ -(u & 1)
 
     def bytes(self) -> bytes:
-        b, self.offset = decode_bytes(self.data, self.offset)
-        return b
+        n = self.uvarint()
+        start = self.offset
+        end = start + n
+        if end > len(self.data):
+            raise ValueError("truncated bytes")
+        self.offset = end
+        return bytes(self.data[start:end])
 
     def raw(self, n: int) -> bytes:
         if self.offset + n > len(self.data):
@@ -143,8 +169,7 @@ class Reader:
         return bytes(b)
 
     def string(self) -> str:
-        s, self.offset = decode_string(self.data, self.offset)
-        return s
+        return self.bytes().decode("utf-8")
 
     def bool(self) -> bool:
         b = self.raw(1)

@@ -545,16 +545,19 @@ for _cut in FASTSYNC_CUTS:
 
 # -- a vote's bytes (types/vote.py, types/block.py) ---------------------------
 #
-# Both are built once: over tendermint_fastsync_blocks_applied_total a
-# catching-up node reads one wire encoding a vote (not one each for the
-# part set, the commit's hash and the store) and, where every validator
+# Both are built at most once: over tendermint_fastsync_blocks_applied_total
+# a catching-up node reads no wire encoding a vote where the peer's bytes
+# were canonical (they are kept: tendermint_vote_wire_kept_total reads the
+# validator count a block) and one where they were not, never one each for
+# the part set, the commit's hash and the store; and, where every validator
 # signed the same block at the same time, one sign-bytes encoding a commit.
 
 VOTE_ENCODES = Counter(
     "tendermint_vote_encodes_total",
     "Vote wire encodings computed (Vote.encode keeps its bytes on the "
-    "frozen vote, so every later caller reuses them): the validator "
-    "count a fast-synced block",
+    "frozen vote, so every later caller reuses them): about none a "
+    "fast-synced block from a peer that sends canonical bytes, the "
+    "validator count from one that pads a varint in every vote",
 )
 COMMIT_SIGNBYTES = Counter(
     "tendermint_commit_signbytes_total",
@@ -566,6 +569,13 @@ COMMIT_SIGNBYTES = Counter(
 )
 for _source in ("encoded", "shared"):
     COMMIT_SIGNBYTES.labels(source=_source).inc(0)
+VOTE_WIRE_KEPT = Counter(
+    "tendermint_vote_wire_kept_total",
+    "Votes whose decoded bytes were kept as their encoding (Vote.decode: "
+    "every varint in them minimal, so Vote.encode would build the same "
+    "bytes): the validator count a fast-synced block, 0 for a vote with "
+    "a padded varint, which is encoded for itself on first use",
+)
 
 # -- a validator set's root (types/validator_set.py) --------------------------
 

@@ -13,7 +13,7 @@ from dataclasses import dataclass, replace
 from typing import ClassVar
 
 from tendermint_tpu.codec import Reader, Writer, canonical_dumps
-from tendermint_tpu.telemetry.metrics import VOTE_ENCODES
+from tendermint_tpu.telemetry.metrics import VOTE_ENCODES, VOTE_WIRE_KEPT
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
 
@@ -36,7 +36,8 @@ class Vote:
     block_id: BlockID
     signature: bytes = b""
 
-    # `encode()`'s result, kept on the object once computed. No dataclass
+    # `encode()`'s result, kept on the object once computed, or the bytes
+    # `decode` read the vote from where they are that result. No dataclass
     # field: `__eq__`, `__hash__`, `repr` and `replace` never see it, and a
     # vote made by `replace` / `with_signature` starts without one.
     _encoded: ClassVar[bytes | None] = None
@@ -69,10 +70,10 @@ class Vote:
             raise ValidationError("negative validator index")
 
     def encode(self) -> bytes:
-        """The canonical wire encoding, computed once a vote: the vote is
-        frozen, and a commit's hash, its block's part set, the store and
-        the WAL all ask for the same bytes. (Never the bytes a peer sent:
-        `decode` accepts non-minimal varints.)"""
+        """The canonical wire encoding, computed at most once a vote: the
+        vote is frozen, and a commit's hash, its block's part set, the
+        store and the WAL all ask for the same bytes. A vote that `decode`
+        read from canonical bytes has them already and is never encoded."""
         encoded = self._encoded
         if encoded is None:
             encoded = (
@@ -106,9 +107,19 @@ class Vote:
 
     @classmethod
     def decode(cls, data: bytes) -> "Vote":
+        """The vote in `data`, and `data` kept as its encoding when, and
+        only when, `encode()` would build those same bytes: the field
+        order is fixed and nothing is optional, so that is when no varint
+        in them was padded (a peer's only freedom; `Reader.padded`) and
+        nothing trails. A vote from padded bytes is encoded for itself."""
         r = Reader(data)
         v = cls.decode_from(r)
         r.expect_done()
+        if not r.padded:
+            # immutable bytes are kept as they are; anything else is copied
+            encoded = data if type(data) is bytes else bytes(data)
+            object.__setattr__(v, "_encoded", encoded)
+            VOTE_WIRE_KEPT.inc()
         return v
 
     def __str__(self) -> str:

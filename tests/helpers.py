@@ -41,6 +41,16 @@ def make_validators(n: int, power: int = 10) -> tuple[ValidatorSet, list[PrivVal
     return vs, ordered
 
 
+def pad_varint(wire: bytes, span: tuple[int, int], pad: int) -> bytes:
+    """`wire` with the varint at `span` (start, end) made `pad` bytes
+    longer and worth the same: the continue bit set on its last byte,
+    then `0x80` fillers and a closing `0x00`. What a decoder accepts and
+    no encoder writes."""
+    _start, end = span
+    filler = bytes([wire[end - 1] | 0x80]) + b"\x80" * (pad - 1) + b"\x00"
+    return wire[: end - 1] + filler + wire[end:]
+
+
 def make_block_id(seed: bytes = b"blk") -> BlockID:
     import hashlib
 

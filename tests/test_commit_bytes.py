@@ -1,10 +1,12 @@
-"""A vote's bytes are built once.
+"""A vote's bytes are built at most once.
 
 `Commit.vote_sign_bytes` hands every commit verifier one sign-bytes
-encoding per distinct signed content, and `Vote.encode` keeps its bytes
-on the frozen vote. Neither may change a byte: what a verifier is given
-must be what `Vote.sign_bytes` gives for each vote, whatever the commit
-looks like, and a vote's encoding must be what it decodes from.
+encoding per distinct signed content, `Vote.encode` keeps its bytes on
+the frozen vote, and `Vote.decode` keeps the bytes it read as that
+encoding when they are canonical (every varint minimal) and only then.
+None may change a byte: what a verifier is given must be what
+`Vote.sign_bytes` gives for each vote, whatever the commit looks like,
+and a vote's encoding is the canonical one, whatever it was decoded from.
 
 All on the CPU: the verifiers here record what they are handed and
 answer with the host library.
@@ -19,7 +21,7 @@ import random
 import numpy as np
 import pytest
 
-from tendermint_tpu.codec import Reader
+from tendermint_tpu.codec import Reader, Writer, encode_svarint, encode_uvarint
 from tendermint_tpu.crypto.keys import PubKey
 from tendermint_tpu.services.verifier import HostBatchVerifier
 from tendermint_tpu.telemetry import REGISTRY
@@ -31,8 +33,15 @@ from tendermint_tpu.types import (
     ValidatorSet,
     Vote,
 )
+from tendermint_tpu.types.part_set import PartSetHeader
 
-from tests.helpers import CHAIN_ID, det_priv_keys, make_block_id, make_validators
+from tests.helpers import (
+    CHAIN_ID,
+    det_priv_keys,
+    make_block_id,
+    make_validators,
+    pad_varint,
+)
 
 HEIGHT = 7
 STAMP = 1_700_000_000_000_000_000
@@ -220,6 +229,51 @@ def test_a_window_of_commits_shares_within_a_commit_never_across():
     assert len({id(msg) for _, msg, _ in recorder.triples}) == k
 
 
+ENCODES = "tendermint_vote_encodes_total"
+WIRE_KEPT = "tendermint_vote_wire_kept_total"
+
+# the ten varints of a vote's encoding, in wire order
+VARINTS = (
+    "address_length", "index", "height", "round", "timestamp", "type",
+    "hash_length", "parts_total", "parts_hash_length", "signature_length",
+)
+
+
+def vote_counts() -> dict:
+    return {name: REGISTRY.counter_value(name) for name in (ENCODES, WIRE_KEPT)}
+
+
+def rise_since(before: dict) -> dict:
+    return {name: value - before[name] for name, value in vote_counts().items()}
+
+
+def varint_spans(v: Vote) -> dict[str, tuple[int, int]]:
+    """Where each varint of `v.encode()` starts and ends, by walking the
+    fields as the format lays them out (`vote.py`, `block_id.py`,
+    `part_set.py`): a varint's length is its canonical encoding's."""
+    psh = v.block_id.parts_header
+    blobs = (v.validator_address, v.block_id.hash, psh.hash, v.signature)
+    # each varint as written, and the payload bytes that follow it
+    layout = (
+        (encode_uvarint(len(blobs[0])), len(blobs[0])),
+        (encode_uvarint(v.validator_index), 0),
+        (encode_uvarint(v.height), 0),
+        (encode_uvarint(v.round), 0),
+        (encode_svarint(v.timestamp), 0),
+        (encode_uvarint(v.type), 0),
+        (encode_uvarint(len(blobs[1])), len(blobs[1])),
+        (encode_uvarint(psh.total), 0),
+        (encode_uvarint(len(blobs[2])), len(blobs[2])),
+        (encode_uvarint(len(blobs[3])), len(blobs[3])),
+    )
+    spans, at = {}, 0
+    for name, (varint, payload) in zip(VARINTS, layout, strict=True):
+        spans[name] = (at, at + len(varint))
+        at += len(varint) + payload
+    assert at == len(v.encode())
+    return spans
+
+
 class TestVoteEncode:
     def _vote(self, seed: int = 1) -> Vote:
         vals, _bid, commit = make_commit(4, "all_signing", seed=4000 + seed)
@@ -227,7 +281,7 @@ class TestVoteEncode:
 
     @staticmethod
     def _computed() -> float:
-        return REGISTRY.counter_value("tendermint_vote_encodes_total")
+        return REGISTRY.counter_value(ENCODES)
 
     def test_the_encoding_is_computed_once_and_every_call_returns_it(self):
         v = dataclasses.replace(self._vote())  # not encoded yet
@@ -239,22 +293,128 @@ class TestVoteEncode:
 
     def test_it_is_the_canonical_encoding_and_decodes_to_the_vote(self):
         v = self._vote(2)
-        again = Vote.decode(v.encode())
+        wire = v.encode()
+        before = vote_counts()
+        again = Vote.decode(wire)
         assert again == v and again is not v
-        # a decoded vote is encoded for itself, never handed the peer's bytes
-        before = self._computed()
-        assert again.encode() == v.encode()
-        assert self._computed() - before == 1
+        # canonical bytes are the decoded vote's encoding: kept, not rebuilt
+        assert again._encoded is wire
+        assert again.encode() is wire
+        assert rise_since(before) == {ENCODES: 0, WIRE_KEPT: 1}
 
-    def test_a_non_minimal_varint_decodes_but_is_not_kept_as_the_encoding(self):
+    @pytest.mark.parametrize("pad", (1, 2))
+    @pytest.mark.parametrize("where", VARINTS)
+    def test_a_non_minimal_varint_decodes_but_is_not_kept_as_the_encoding(self, where, pad):
         v = self._vote(3)
         wire = v.encode()
-        cut = 1 + len(v.validator_address)  # the index follows the address
-        assert wire[cut] == v.validator_index < 0x80
-        padded = wire[:cut] + bytes([wire[cut] | 0x80, 0x00]) + wire[cut + 1 :]
+        padded = pad_varint(wire, varint_spans(v)[where], pad)
+        assert len(padded) == len(wire) + pad
+        before = vote_counts()
         loose = Vote.decode(padded)
         assert loose == v
+        assert loose._encoded is None  # never handed its wire bytes
+        assert rise_since(before) == {ENCODES: 0, WIRE_KEPT: 0}
         assert loose.encode() == wire != padded
+        assert loose.encode() is loose._encoded
+        assert rise_since(before) == {ENCODES: 1, WIRE_KEPT: 0}
+
+    def test_bytes_that_are_not_immutable_are_copied_not_kept(self):
+        v = self._vote(2)
+        wire = v.encode()
+        for mutable in (bytearray(wire), memoryview(bytearray(wire))):
+            got = Vote.decode(mutable)
+            assert got == v
+            assert type(got._encoded) is bytes and got._encoded == wire
+            mutable[-1] ^= 0xFF  # the caller's buffer is the caller's
+            assert got.encode() == wire
+        assert Vote.decode(memoryview(wire)).encode() == wire
+
+    def test_trailing_bytes_are_refused_as_before(self):
+        wire = self._vote().encode()
+        with pytest.raises(ValueError, match="1 trailing bytes"):
+            Vote.decode(wire + b"\x00")
+        with pytest.raises(ValueError, match="truncated"):
+            Vote.decode(wire[:-1])
+
+    EDGES = (0, 1, 127, 128, 16383, 16384, 2**31, 2**63 - 1)
+    # the decoder takes eleven bytes a varint: nine here, so two pads fit
+    STAMPS = (0, -1, 1, -64, 63, 64, -65, STAMP, -STAMP, 2**61, -(2**61))
+
+    def _random_vote(self, rng: random.Random) -> Vote:
+        """Any vote the codec can carry, valid or not: the rule is about
+        bytes, not about `validate_basic`."""
+        def blob(*sizes: int) -> bytes:
+            return rng.randbytes(rng.choice(sizes))
+
+        block_id = rng.choice((
+            BlockID.zero(),
+            BlockID(blob(0, 20, 32), PartSetHeader(rng.choice(self.EDGES), blob(0, 20, 32))),
+        ))
+        return Vote(
+            validator_address=blob(0, 20, 127, 128),
+            validator_index=rng.choice(self.EDGES),
+            height=rng.choice(self.EDGES),
+            round=rng.choice(self.EDGES),
+            timestamp=rng.choice(self.STAMPS),
+            type=rng.choice((0, 1, 2, 127, 128)),
+            block_id=block_id,
+            signature=blob(0, 64, 64, 200),
+        )
+
+    def _by_hand(self, v: Vote) -> bytes:
+        """The format, written out field by field without `Vote.encode`."""
+        psh = v.block_id.parts_header
+        return (
+            Writer().bytes(v.validator_address).uvarint(v.validator_index)
+            .uvarint(v.height).uvarint(v.round).svarint(v.timestamp).uvarint(v.type)
+            .bytes(v.block_id.hash).uvarint(psh.total).bytes(psh.hash)
+            .bytes(v.signature).build()
+        )
+
+    @pytest.mark.parametrize("seed", range(8))
+    def test_decoded_bytes_are_kept_exactly_when_they_are_the_canonical_ones(self, seed):
+        rng = random.Random(f"vote-bytes-{seed}")
+        for _ in range(150):
+            v = self._random_vote(rng)
+            canonical = self._by_hand(v)
+            assert v.encode() == canonical
+            spans = varint_spans(v)
+            # the canonical bytes, and the same with one to three varints padded
+            wires = [bytes(bytearray(canonical))]  # an equal object, not the memo
+            for k in (1, 2, 3):
+                x = canonical
+                # from the back, so earlier spans stay where they are
+                for where in sorted(rng.sample(VARINTS, k), key=VARINTS.index, reverse=True):
+                    x = pad_varint(x, spans[where], rng.choice((1, 2)))
+                wires.append(x)
+            for x in wires:
+                got = Vote.decode(x)
+                assert got == v
+                assert (got._encoded is x) == (x == canonical)
+                assert got.encode() == canonical
+                assert (got.encode() is x) == (x == canonical)
+
+    def test_a_generated_block_decodes_and_encodes_to_the_bytes_it_came_in(self):
+        from tendermint_tpu.types.block import Block
+
+        from tests.helpers import ChainSim
+
+        sim = ChainSim(n_vals=7)
+        for h in range(4):
+            sim.advance(txs=[b"k%d=v" % h])
+        for block in sim.blocks:
+            wire = block.encode()
+            header = block.make_part_set().header
+            before = vote_counts()
+            again = Block.decode(wire)
+            n = sum(v is not None for v in again.last_commit.precommits)
+            assert again.encode() == wire
+            assert again.make_part_set().header == header
+            assert again.last_commit.hash() == block.last_commit.hash()
+            assert again.hash() == block.hash()
+            assert rise_since(before) == {ENCODES: 0, WIRE_KEPT: n}
+            for kept, v in zip(again.last_commit.precommits, block.last_commit.precommits):
+                assert kept == v and kept.encode() == v.encode()
 
     def test_with_signature_and_replace_start_from_a_fresh_encoding(self):
         v = self._vote()
@@ -262,6 +422,11 @@ class TestVoteEncode:
         resigned = v.with_signature(bytes(64))
         assert resigned.encode() != old and resigned.encode().endswith(bytes(64))
         assert Vote.decode(resigned.encode()) == resigned
+        # and so does a vote made from one that kept its wire bytes
+        kept = Vote.decode(old)
+        assert kept._encoded is old
+        assert kept.with_signature(bytes(64))._encoded is None
+        assert dataclasses.replace(kept)._encoded is None
         later = dataclasses.replace(v, timestamp=v.timestamp + 1)
         assert later.encode() != old and Vote.decode(later.encode()) == later
         assert v.encode() is old
@@ -292,8 +457,9 @@ class TestVoteEncode:
 
 
 class TestFastSyncCounts:
-    """Over a fast-synced chain the two counters read what the mechanism
-    promises: one wire encoding a vote, one sign-bytes encoding a commit."""
+    """Over a fast-synced chain the counters read what the mechanism
+    promises: no wire encoding of a vote that came in canonical bytes
+    (they are kept), one sign-bytes encoding a commit."""
 
     N_VALS, N_BLOCKS = 16, 40
 
@@ -324,15 +490,8 @@ class TestFastSyncCounts:
         sim = OneStampSim(n_vals=self.N_VALS)
         for _ in range(self.N_BLOCKS):
             sim.advance()
-        # as they come off the wire: no vote of the chain is encoded yet
-        sim.blocks = [Block.decode(b.encode()) for b in sim.blocks]
-        reactor, _state, store = _pipelined_reactor(
-            sim, depth=2, verifier=HostBatchVerifier()
-        )
-        names = (
-            "tendermint_vote_encodes_total",
-            "tendermint_fastsync_blocks_applied_total",
-        )
+        wires = [b.encode() for b in sim.blocks]
+        names = (ENCODES, WIRE_KEPT, "tendermint_fastsync_blocks_applied_total")
 
         def read() -> dict:
             return {
@@ -341,6 +500,11 @@ class TestFastSyncCounts:
             }
 
         before = read()
+        # as they come off the wire: the p2p thread's decode is in the count
+        sim.blocks = [Block.decode(w) for w in wires]
+        reactor, _state, store = _pipelined_reactor(
+            sim, depth=2, verifier=HostBatchVerifier()
+        )
         reactor._try_sync()
         assert store.height == self.N_BLOCKS - 1
         return {k: v - before[k] for k, v in read().items()}
@@ -349,8 +513,10 @@ class TestFastSyncCounts:
         rise = self._synced()
         blocks = rise["tendermint_fastsync_blocks_applied_total"]
         assert blocks == self.N_BLOCKS - 1
-        # the part set, the commit's hash and both store rows: one encoding
-        assert rise["tendermint_vote_encodes_total"] == self.N_VALS * blocks
+        # the part set, the commit's hash and both store rows: the bytes the
+        # votes came in, never an encoding (block 1's last commit is empty)
+        assert rise[ENCODES] == 0
+        assert rise[WIRE_KEPT] == self.N_VALS * blocks
         # one commit walked a block, all sixteen votes the same content
         assert rise["encoded"] == blocks
         assert rise["shared"] == (self.N_VALS - 1) * blocks
@@ -364,9 +530,9 @@ class TestFastSyncCounts:
         docs = (
             pathlib.Path(__file__).resolve().parents[1] / "docs" / "OBSERVABILITY.md"
         ).read_text()
-        assert "tendermint_vote_encodes_total" in text
+        assert f"{ENCODES} " in text and f"{WIRE_KEPT} " in text
         for source in ("encoded", "shared"):
             assert f'{SIGNBYTES}{{source="{source}"}}' in text
-        for name in ("tendermint_vote_encodes_total", SIGNBYTES):
-            assert name in docs
+        for name in (ENCODES, WIRE_KEPT, SIGNBYTES):
+            assert f"| `{name}" in docs
         assert metric_offenders() == []

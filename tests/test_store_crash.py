@@ -256,36 +256,46 @@ class TestTheStoreHasOneWayToWrite:
     def test_each_commit_is_encoded_once_and_the_rows_are_its_encoding(self):
         """Fast-sync's order: the seen commit of block H is `last_commit`
         of block H+1, the same object, and none of its votes is encoded
-        again (`Vote.encode` keeps its bytes on the vote)."""
+        at all: `Vote.decode` kept the canonical bytes a peer sent, and
+        the part set and both rows are made of them."""
         from tendermint_tpu.db.kv import MemDB
         from tendermint_tpu.telemetry import REGISTRY
         from tendermint_tpu.types.block import Block, Commit
 
-        def encodes() -> float:
-            return REGISTRY.counter_value("tendermint_vote_encodes_total")
+        def count(what: str) -> float:
+            return REGISTRY.counter_value(f"tendermint_vote_{what}_total")
 
         sim = self._chain(8)
-        # as a peer sends them: votes that have not been encoded yet
-        blocks = [Block.decode(b.encode()) for b in sim.blocks]
-        before = encodes()
+        wires = [b.encode() for b in sim.blocks]
+        last_seen = sim.commits[7].encode()  # the sim's own votes, encoded here
+        encodes, kept = count("encodes"), count("wire_kept")
+        # as a peer sends them; block 1's last commit is empty, four votes
+        # a block after it
+        blocks = [Block.decode(w) for w in wires]
+        assert count("wire_kept") - kept == 7 * 4
         part_sets = [b.make_part_set() for b in blocks]  # encodes the block
-        # block 1's last commit is empty; four votes a block after it
-        assert encodes() - before == 7 * 4
+        assert [ps.header for ps in part_sets] == [
+            b.make_part_set().header for b in sim.blocks
+        ]
         db = MemDB()
         store = BlockStore(db)
         for i in range(7):
             store.save_block(blocks[i], part_sets[i], blocks[i + 1].last_commit)
-        assert encodes() - before == 7 * 4
+        assert count("encodes") - encodes == 0
         for h in range(1, 8):
             assert db.get(b"SC:%d" % h) == sim.blocks[h].last_commit.encode()
             # the canonical commit of h comes with block h + 1
             assert db.get(b"C:%d" % (h - 1)) == sim.blocks[h - 1].last_commit.encode()
-        # a commit that only looks the same is encoded for itself
-        other = Commit.decode_from(Reader(sim.commits[7].encode()))
-        at = encodes()
+        # a commit that only looks the same has its own votes, with their
+        # own kept bytes: decoded, not encoded
+        other = Commit.decode_from(Reader(last_seen))
         store.save_block(blocks[7], part_sets[7], other)
-        assert encodes() - at == 4 and db.get(b"SC:8") == sim.commits[7].encode()
+        assert count("wire_kept") - kept == 8 * 4
+        assert db.get(b"SC:8") == last_seen
         assert db.get(b"C:7") == db.get(b"SC:7")
+        # and what the store hands back is made of kept bytes too
+        assert store.load_seen_commit(8).encode() == last_seen
+        assert count("encodes") - encodes == 0
 
     def test_bootstrap_and_prune_are_one_transaction_each(self, tmp_path):
         sim = self._chain(12)
