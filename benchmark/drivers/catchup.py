@@ -173,6 +173,25 @@ class GcPauses:
             self.pauses.append((int(info["generation"]), time.monotonic() - self._t))
 
 
+def _bytes_written() -> dict:
+    """What this process (the node is in it) has written so far, by the
+    kernel's two counts: `wchar`, the bytes its write calls carried (files,
+    sockets and pipes alike), and `write_bytes`, what of that went down to
+    a block device (0 on a machine whose file system is not one). The
+    driver counts a machine's writes, and a block of 10,000 txs is 10,000
+    rows of the tx index."""
+    out = {}
+    try:
+        with open("/proc/self/io") as f:
+            for row in f:
+                name, _, value = row.partition(":")
+                if name in ("wchar", "write_bytes"):
+                    out[name] = int(value)
+    except OSError:
+        pass
+    return out
+
+
 def _longest_standstill(status: list[dict]) -> float:
     """The longest time, by the client's `/status` answers, over which the
     applied height stayed where it was."""
@@ -286,7 +305,12 @@ def run(ctx: dict) -> dict | None:
         write_config(cfg)
         shutil.copy(os.path.join(peer_home, "genesis.json"), cfg.genesis_path())
         cfg = load_config(node_home)
-        node = Node(cfg)
+        hasher = None  # the node's own choice: the device's trees where a TPU is up
+        if ctx["control"] == "host_trees":
+            from benchmark.lib.controls import host_tree_hasher
+
+            hasher = host_tree_hasher()
+        node = Node(cfg, hasher=hasher)
         shape_seconds, shape_failures = _warm_shapes(record, seed, log)
         t_node = time.monotonic()
         node.start()
@@ -438,6 +462,8 @@ def run(ctx: dict) -> dict | None:
             f"{sum(old):.3f}s (longest {max(old, default=0.0):.3f}s); the height stood still "
             f"for at most {standstill:.3f}s"
         )
+        written = _bytes_written()
+        log(f"storage: this process has written, in bytes since it started: {json.dumps(written)}")
         for name, m in end_to_end.items():
             log(f"end-to-end {name} = {m['value']} {m['unit']}")
 
@@ -451,7 +477,7 @@ def run(ctx: dict) -> dict | None:
             "window_s": t_window, "launch_sizes": sizes,
             "gc": {"collections": len(pauses.pauses), "seconds": sum(s for _g, s in pauses.pauses),
                    "oldest": len(old), "oldest_seconds": sum(old), "oldest_longest_s": max(old, default=0.0)},
-            "longest_standstill_s": standstill,
+            "longest_standstill_s": standstill, "process_write_bytes": written,
         }
         if trace_info is not None:
             from benchmark.lib import trace_reduce
@@ -479,17 +505,21 @@ def run(ctx: dict) -> dict | None:
             "cell": cell, "config": config, "mix": mix, "seed": seed, "seconds": seconds,
             "reads": rd, "launches": launches, "metrics_start": metrics_start,
             "metrics_end": metrics_end, "trace": reduced, "record": record,
-            "device_kind": kind, "window": [wall_start, wall_end],
+            "device_kind": kind, "window": [wall_start, wall_end], "heights": [h_open, h_close],
         }
-        failures, host_fallbacks = checks.run_checks(
+        checked = checks.run_checks(
             port=port, record=record, seed=seed,
             h_close=h_close, launches=launches_all, metrics=metrics_end, health=health,
             devices=devices, log=log,
         )
-        obs["host_fallbacks"] = host_fallbacks
-        failures = shape_failures + failures
+        obs["host_fallbacks"] = checked["host_fallbacks"]
+        obs["hash_host_fallbacks"] = checked["hash_host_fallbacks"]
+        failures = shape_failures + checked["failures"]
+        compared = {"warm_shape_wrong_verdicts": [len(shape_failures), 0], **checked["compared"]}
+        compared["chain_exhausted"] = [int(h_close >= usable), 0]
         if h_close >= usable:
             failures.append(f"chain exhausted: height {h_close} of {n_blocks} inside the window")
+        compared["no_rate_read"] = [int("catchup_blocks_per_s" not in end_to_end), 0]
         if "catchup_blocks_per_s" not in end_to_end:
             failures.append("no two /status reads answered inside the window")
         for f in failures[:8]:
@@ -516,7 +546,8 @@ def run(ctx: dict) -> dict | None:
         obs.update(
             end_to_end=end_to_end, correct=not failures, attempted=heights_fetched + len(rd),
             failed=len(bad_reads) + refused + len(failures), device=device,
-            checks={"failures": failures, "host_fallbacks": host_fallbacks},
+            checks={"failures": failures, "host_fallbacks": checked["host_fallbacks"],
+                    "hash_host_fallbacks": checked["hash_host_fallbacks"], "compared": compared},
             breakdown=breakdown, notes=notes,
         )
         return obs

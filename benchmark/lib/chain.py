@@ -16,7 +16,9 @@ reads. Nothing here starts a JAX backend.
 
 from __future__ import annotations
 
+import functools
 import hashlib
+import importlib.util
 import json
 import os
 import random
@@ -28,6 +30,7 @@ from . import signer
 CHAIN_ID = "benchmark"
 GENESIS_TIME = 1_700_000_000_000_000_000
 FAULT_WINDOW = 16  # commits in the window the planted fault goes into
+TX_RULES = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tx_rules")
 
 
 def powers(rule: dict, n: int) -> list[int]:
@@ -39,13 +42,24 @@ def powers(rule: dict, n: int) -> list[int]:
     raise ValueError(f"unknown power rule {kind!r}")
 
 
+@functools.cache
+def tx_rule(kind: str):
+    """The tx rule `kind`: `tx_rules/<kind>.py`, found by its name as
+    `run.py` finds a layer metric's reader, so a new mix's rule is a new
+    file and no edit here."""
+    path = os.path.join(TX_RULES, kind + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"unknown tx rule {kind!r}: no file {path}")
+    spec = importlib.util.spec_from_file_location("benchmark_tx_rule_" + kind, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.txs
+
+
 def block_txs(mix: dict, height: int) -> list[bytes]:
-    """The mix's tx rule. `fresh_keys` writes keys no other block writes."""
+    """The txs of the block at `height` under the mix's tx rule."""
     rule = mix["txs"]
-    n = int(rule["per_block"])
-    if rule["kind"] == "fresh_keys":
-        return [b"h%07d-%d=%d" % (height, i, height * 7 + i) for i in range(n)]
-    raise ValueError(f"unknown tx rule {rule['kind']!r}")
+    return tx_rule(rule["kind"])(rule, height)
 
 
 @dataclass

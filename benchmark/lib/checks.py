@@ -7,7 +7,9 @@ checks, cut to what a catch-up run can show).
    with `hashlib` and the host ed25519 library alone.
 2. The last applied height's write is read back through `abci_query`.
 3. Every verify launch of k*n >= 512 lanes was answered by a device
-   backend, no breaker moved, no call fell back to the host.
+   backend, no breaker moved, no call fell back to the host; and the
+   same of every Merkle tree of 8,192 leaves or more (a block's 10,000
+   txs): the hash spine answers it on the device.
 4. The node's `/health` names the device JAX gave this process.
 5. The planted fault: the chain's last 16-commit window (heights the node
    never reaches in a run) with one seeded bit of one signature's R
@@ -26,9 +28,10 @@ import re
 
 from . import chain as chainlib
 from . import reference, rpc
-from .ledger import DEVICE_BACKENDS, VERIFY_KINDS
+from .ledger import DEVICE_BACKENDS, HASH_DEVICE_BACKENDS, VERIFY_KINDS
 
 DEVICE_MIN_LANES = 512  # services/verifier.py DEVICE_MIN_BATCH
+DEVICE_MIN_LEAVES = 8192  # services/hasher.py DEVICE_MIN_LEAVES
 REFUSED = re.compile(r"validator (\d+) \(batch entry (\d+), height (\d+)\)")
 
 
@@ -45,6 +48,31 @@ def host_fallbacks(launches: list[dict], metrics: dict) -> int:
             n += 1
     n += int(rpc.metric(metrics, "tendermint_device_fallback_calls_total", kind="verify"))
     n += int(rpc.metric(metrics, "tendermint_device_dispatch_failures_total", kind="verify"))
+    return n
+
+
+def hash_host_fallbacks(launches: list[dict], metrics: dict) -> int:
+    """Merkle trees of `DEVICE_MIN_LEAVES` leaves or more that a host
+    backend answered, plus the hash spine's own fallback and
+    dispatch-failure counters. A host tree outside a launch context closes
+    no record (`telemetry/launchlog.py` `observe`), so the trees are also
+    counted off the histogram of leaves a root, which has a bound at
+    8,192 (a host tree of exactly 8,192 leaves is seen by its record
+    alone; one seen both ways counts twice, and the limit is 0)."""
+    n = 0
+    for r in launches:
+        if r.get("kind") != "hash" or int(r.get("rows", 0)) < DEVICE_MIN_LEAVES:
+            continue
+        if r.get("error") or r.get("backend") not in HASH_DEVICE_BACKENDS:
+            n += 1
+    leaves = "tendermint_hash_batch_leaves"
+    under = sum(
+        v for ls, v in metrics.get(leaves + "_bucket", [])
+        if ls.get("backend") == "host" and ls.get("le") != "+Inf" and float(ls["le"]) == DEVICE_MIN_LEAVES
+    )
+    n += int(rpc.metric(metrics, leaves + "_count", backend="host") - under)
+    n += int(rpc.metric(metrics, "tendermint_device_fallback_calls_total", kind="hash"))
+    n += int(rpc.metric(metrics, "tendermint_device_dispatch_failures_total", kind="hash"))
     return n
 
 
@@ -140,19 +168,31 @@ def check_planted_fault(record, seed: int) -> list[str]:
     return bad
 
 
-def run_checks(*, port, record, seed, h_close, launches, metrics, health, devices, log):
+def run_checks(*, port, record, seed, h_close, launches, metrics, health, devices, log) -> dict:
+    """Every check; returns the failures in words, the two counts of host
+    answers (the readers `verify.host_fallbacks` and `hash.host_fallbacks`
+    report them) and each number compared beside its limit."""
     failures: list[str] = []
+    compared: dict[str, list] = {}
     heights = sample_heights(seed, h_close, 32)
-    bad, compared = check_sample(port, record, heights)
-    log(f"check sample: {len(heights)} heights, {compared} fields and {len(heights)} commits compared, {len(bad)} differ (limit 0)")
+    bad, n_compared = check_sample(port, record, heights)
+    log(f"check sample: {len(heights)} heights, {n_compared} fields and {len(heights)} commits compared, {len(bad)} differ (limit 0)")
+    compared["sample_differ"] = [len(bad), 0]
     failures += bad
     bad = check_last_write(port, record)
     log(f"check last write: {len(bad)} differ (limit 0)")
+    compared["last_write_differ"] = [len(bad), 0]
     failures += bad
     fallbacks = host_fallbacks(launches, metrics)
     log(f"check device answers: {fallbacks} host answers or faults where a device answer was due (limit 0)")
+    compared["verify_host_answers"] = [fallbacks, 0]
     if fallbacks:
         failures.append(f"{fallbacks} verify launches of >= {DEVICE_MIN_LANES} lanes were not answered by the device")
+    hash_fallbacks = hash_host_fallbacks(launches, metrics)
+    log(f"check device trees: {hash_fallbacks} host answers or faults where a device tree was due (limit 0)")
+    compared["tree_host_answers"] = [hash_fallbacks, 0]
+    if hash_fallbacks:
+        failures.append(f"{hash_fallbacks} Merkle trees of >= {DEVICE_MIN_LEAVES} leaves were not answered by the device")
     verify_launches = [r for r in launches if r.get("kind") in VERIFY_KINDS and r.get("backend") in DEVICE_BACKENDS]
     if devices[0].platform != "cpu" and not verify_launches:
         failures.append("the launch ledger holds no device verify launch")
@@ -160,9 +200,14 @@ def run_checks(*, port, record, seed, h_close, launches, metrics, health, device
     got = (dev.get("platform"), dev.get("device_kind"), dev.get("device_count"))
     want = (devices[0].platform, devices[0].device_kind, len(devices))
     log(f"check health.device: {got} against {want}")
+    compared["health_device_differs"] = [int(got != want), 0]
     if got != want:
         failures.append(f"health.device says {got}, JAX gave this process {want}")
     bad = check_planted_fault(record, seed)
     log(f"check planted fault: {len(bad)} wrong verdicts (limit 0)")
+    compared["planted_fault_wrong_verdicts"] = [len(bad), 0]
     failures += bad
-    return failures, fallbacks
+    return {
+        "failures": failures, "host_fallbacks": fallbacks,
+        "hash_host_fallbacks": hash_fallbacks, "compared": compared,
+    }

@@ -6,6 +6,9 @@ runs (`run.py --control <name>`); a benchmark run never installs one.
   a commit passes without 2/3 of the power in *valid* signatures.
 * `host_answers` (in `drivers/catchup.py`): every commit-shaped batch is
   answered by the host library where a device answer is due.
+* `host_trees` (`host_tree_hasher`): the node is built with the host tree
+  hasher, so every Merkle tree, a block's 10,000 txs with them, is
+  answered by the host library where a device tree is due.
 * `apphash_off_by_one` (in `drivers/catchup.py`): the record the node is
   held to has every app hash one height off, as a node that applied the
   wrong state would show.
@@ -29,3 +32,9 @@ def install_accept_all() -> None:
             ).reshape(len(commits), len(pubkeys))
 
     set_default_verifier(AcceptAll())
+
+
+def host_tree_hasher():
+    from tendermint_tpu.services.hasher import TreeHasher
+
+    return TreeHasher(backend="host")

@@ -5,6 +5,10 @@ and those of them inside a traced stretch."""
 from __future__ import annotations
 
 KERNEL = "verify_tables_kernel"
+# `ops/merkle_kernel.py` `_leafhash_and_reduce`: a tree's leaf hashes and
+# levels, one executable a padded shape
+TREE_KERNEL = "leafhash_and_reduce"
+HASH_DEVICE_BACKENDS = ("device", "mesh")
 VERIFY_KINDS = ("verify", "tables")
 DEVICE_BACKENDS = ("tables", "mesh")
 
@@ -43,3 +47,30 @@ def traced_kernel(obs: dict):
     if seconds <= 0 or not recs:
         return None
     return seconds, recs
+
+
+def device_trees(obs: dict) -> list[dict]:
+    """The window's launch records of Merkle roots a device backend built
+    (`services/hasher.py` closes one a tree, kind `hash`, `rows` its
+    leaves)."""
+    return [
+        r for r in obs["launches"]
+        if r.get("kind") == "hash" and not r.get("error") and r.get("backend") in HASH_DEVICE_BACKENDS
+    ]
+
+
+def traced_trees(obs: dict):
+    """(device seconds per chip of the tree executables in the traced
+    stretch, how many times they ran, the real leaves of a tree), or None
+    without a device trace or a device tree. The runs are counted in the
+    trace itself; what a tree holds is read off the window's records,
+    which all carry the mix's one leaf count."""
+    tr = obs.get("trace")
+    if not tr:
+        return None
+    seconds = sum(v for k, v in tr["modules"].items() if TREE_KERNEL in k)
+    runs = sum(v for k, v in tr.get("module_runs", {}).items() if TREE_KERNEL in k)
+    leaves = {int(r.get("rows", 0)) for r in device_trees(obs)}
+    if seconds <= 0 or runs <= 0 or len(leaves) != 1:
+        return None
+    return seconds, runs, leaves.pop()

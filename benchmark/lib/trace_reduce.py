@@ -77,6 +77,7 @@ def reduce(trace: dict, window_s: float) -> dict | None:
     busy_by_plane: list[float] = []
     ops: dict[str, float] = {}
     modules: dict[str, float] = {}
+    module_runs: dict[str, float] = {}
     gaps: list[tuple[float, float]] = []
     for plane in devices:
         spans = [
@@ -95,6 +96,7 @@ def reduce(trace: dict, window_s: float) -> dict | None:
             if line["name"] == MODULES_LINE:
                 for name, _start, dur in line["events"]:
                     modules[name] = modules.get(name, 0.0) + dur / 1e9
+                    module_runs[name] = module_runs.get(name, 0) + 1
         if plane is devices[0]:
             edge = 0.0
             for lo, hi in merged:
@@ -113,6 +115,7 @@ def reduce(trace: dict, window_s: float) -> dict | None:
         # per chip, so that a four-chip trace reads like a one-chip one
         "ops": {k: v / n for k, v in ops.items()},
         "modules": {k: v / n for k, v in modules.items()},
+        "module_runs": {k: v / n for k, v in module_runs.items()},
         "gaps": sorted(gaps, key=lambda g: -g[1]),
     }
 

@@ -140,6 +140,10 @@ def main(argv=None) -> int:
     }
     if args.trace and obs.get("breakdown"):
         line["breakdown"] = obs["breakdown"]
+    # each number `correct` was decided from beside its limit ([number,
+    # limit]; every limit is exact): last in the line, and the last lines
+    # of standard error
+    line["compared"] = obs["checks"]["compared"]
 
     out_dir = os.path.join(HERE, "out")
     os.makedirs(out_dir, exist_ok=True)
@@ -153,6 +157,8 @@ def main(argv=None) -> int:
         json.dump(detail, f, indent=1)
     for name, m in sorted(metrics.items()):
         log(f"metric {name} = {m['value']} {m['unit']}")
+    for name, (number, limit) in line["compared"].items():
+        print(f"compared {name}: {number} (limit {limit})", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0 if line["correct"] else EXIT_INCORRECT
 

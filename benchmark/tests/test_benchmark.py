@@ -27,7 +27,7 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def bench_json() -> dict:
@@ -122,8 +122,10 @@ def test_kernel_metrics_count_only_what_the_device_answered():
     from benchmark.lib import counts, ledger
 
     def launch(t, k, backend):
+        # the device's windows run at the fused shape, 16 commits x 128 columns
+        shape = {"k_launch": 16, "n_launch": 128, "rows_padded": 2048 - 100 * k} if backend == "tables" else {}
         return {"kind": "tables" if backend == "tables" else "verify", "t": t, "backend": backend,
-                "height_lo": 10, "height_hi": 10 + k - 1, "rows": 100 * k}
+                "height_lo": 10, "height_hi": 10 + k - 1, "rows": 100 * k, **shape}
 
     obs = {
         "config": {"validators": 100}, "device_kind": "TPU v5 lite",
@@ -142,8 +144,13 @@ def test_kernel_metrics_count_only_what_the_device_answered():
         return mod.reduce
 
     assert reader("kernel.verify_us_per_sig")(obs) == pytest.approx(1e6 * 0.004 / 2_200)
-    least = (counts.verify_launch_bytes(100, 16) + counts.verify_launch_bytes(100, 6)) / 819e9
+    # the table the kernel reads has the launch's 128 columns, not the set's
+    # 100, and a window of 6 commits runs all 16 of the fused shape
+    least = 2 * counts.verify_launch_bytes(128, 16) / 819e9
     assert reader("kernel.verify_tables_roofline")(obs) == pytest.approx(100 * least / 0.004)
+    # a program whose records carry no launch shape (older than PR 27): nothing to read
+    bare = [{k: v for k, v in r.items() if k not in ("k_launch", "n_launch")} for r in obs["launches"]]
+    assert reader("kernel.verify_tables_roofline")({**obs, "launches": bare}) is None
     # only host launches inside the stretch: nothing to read
     obs["launches"] = obs["launches"][:1]
     assert ledger.traced_kernel(obs) is None
@@ -279,6 +286,10 @@ def test_a_tiny_cell_end_to_end_from_new_files_alone(copy):
     assert set(line["metrics"]) == {"catchup_blocks_per_s", "setup_s"}
     assert line["metrics"]["catchup_blocks_per_s"]["value"] > 0
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    # each number compared beside its limit: last in the line, and the last lines of standard error
+    assert list(line)[-1] == "compared" and len(line["compared"]) >= 7
+    assert all(number == limit == 0 for number, limit in line["compared"].values())
+    assert proc.stderr.strip().splitlines()[-1] == "compared no_rate_read: 0 (limit 0)"
     assert line["device"]["platform"] == "cpu"
     # every line that carries a number names the device
     for row in proc.stdout.splitlines()[:-1]:

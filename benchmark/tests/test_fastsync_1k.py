@@ -73,13 +73,12 @@ def test_the_cell_is_the_mix_sparse_on_one_chip_and_lists_what_it_reports():
     cell, old = load("cells", CELL + ".json"), load("cells", "fastsync-100.sparse.json")
     assert cell["chain_blocks"] == 800 and cell["trace_seconds"] == 6
     assert cell["metrics"] == ["catchup_blocks_per_s", "setup_s"]
-    # everything the present cell reports, then what this deployment makes large
-    assert cell["layer_metrics"][: len(old["layer_metrics"])] == old["layer_metrics"]
-    added = cell["layer_metrics"][len(old["layer_metrics"]):]
-    assert added == SHAPE_READERS + COUNTER_READERS
+    # everything fastsync-100.sparse reports, and what this deployment makes large
+    added = [name for name in cell["layer_metrics"] if name not in old["layer_metrics"]]
+    assert added == SHAPE_READERS + COUNTER_READERS and set(old["layer_metrics"]) < set(cell["layer_metrics"])
     per_layer = {m["name"]: m for m in b["per_layer"]}
     for name in added:
-        assert per_layer[name]["workloads"] == [CELL]
+        assert per_layer[name]["workloads"] == [CELL, "fastsync-1k.full"]
     # the mix is shared: its file names no deployment
     assert load("traffic", "sparse.json")["reads"]["per_s"] == 20
 
@@ -196,8 +195,9 @@ def test_the_cell_rehearsed_from_its_own_files_with_the_validator_count_cut(tmp_
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     got = line["metrics"]
     assert got["verify.host_fallbacks"]["value"] == 0.0
-    # one wire encoding a vote, thirteen votes a block; the set's root is kept
-    assert got["fastsync.vote_encodes_per_block"] == {"value": pytest.approx(13.0, abs=0.5), "unit": "encodes/block"}
+    # the peer sends canonical bytes and a decoded vote keeps them (PR 33): no
+    # vote is encoded again; the set's root is kept
+    assert got["fastsync.vote_encodes_per_block"] == {"value": pytest.approx(0.0, abs=0.5), "unit": "encodes/block"}
     assert got["fastsync.valset_roots_per_block"]["value"] < 0.05
     assert 0 <= got["process.gc_pause_share"]["value"] < 50
     # no device answered on the CPU: the launch-shape and the trace readers find nothing

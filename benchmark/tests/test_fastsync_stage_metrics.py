@@ -4,9 +4,7 @@
 
 Their arithmetic over a hand-made pair of `/metrics` pulls, what they give
 a program that has no such series, and a traced run of a tiny cell that
-lists them. No cell of `BENCHMARK.json` lists them yet: a cell names its
-metrics in `cells/<cell>.json`, a file this PR may not edit (PERF.md
-section 7 has the lines to add).
+lists them. Every cell of `BENCHMARK.json` lists them since PR 35.
 """
 
 from __future__ import annotations
@@ -82,16 +80,23 @@ def test_no_block_applied_is_nothing_to_read():
 
 
 def test_each_reader_has_the_contracts_entry_beside_it():
-    known = {m["name"] for m in json.load(open(os.path.join(ROOT, "BENCHMARK.json")))["per_layer"]}
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    known = {m["name"]: m for m in bench["per_layer"]}
+    cells = {
+        w["name"]: json.load(open(os.path.join(BENCH, "cells", w["name"] + ".json")))["layer_metrics"]
+        for w in bench["workloads"]
+    }
     for name in NEW:
         meta = json.load(open(os.path.join(BENCH, "layer_metrics", name + ".json")))
         assert meta["name"] == name and meta["layer"] == "fast-sync" and meta["what"]
         assert meta["source"] == "program_counter" and meta["moves"] == "catchup_blocks_per_s"
         assert (meta["unit"], meta["better"]) in (("ms", "lower"), ("%", "lower"), ("%", "higher"))
         assert len(name) <= 64
-        # listed by BENCHMARK.json and by the cell together, or by neither
-        cell = json.load(open(os.path.join(BENCH, "cells", "fastsync-100.sparse.json")))
-        assert (name in known) == (name in cell["layer_metrics"])
+        # in BENCHMARK.json, and in every cell that lists it: there, under `workloads`, and nowhere else
+        listing = [cell for cell, names in cells.items() if name in names]
+        assert listing and known[name]["workloads"] == listing
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert known[name][key] == meta[key]
 
 
 def test_a_traced_tiny_cell_reports_every_stage_metric(tmp_path):
