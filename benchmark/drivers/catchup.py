@@ -115,8 +115,8 @@ def _wait(cond, timeout: float, what: str, child: Child | None = None, tick: flo
 
 def _warm_shapes(record, seed: int, log) -> tuple[dict, list[str]]:
     """One call of the process's verifier per window size K = 1..16 at the
-    cell's validator set, on the chain's last commits (heights the node
-    never reaches in a run). Which K a catch-up launches depends on when
+    validator set of the chain's tail, on the chain's last commits (heights
+    the node never reaches in a run). Which K a catch-up launches depends on when
     blocks arrive, and every K the kernel does not pad is an executable of
     its own: one first met inside the window stalls it for the seconds
     this returns per K. Each call's last commit carries one flipped
@@ -310,7 +310,10 @@ def run(ctx: dict) -> dict | None:
             from benchmark.lib.controls import host_tree_hasher
 
             hasher = host_tree_hasher()
-        node = Node(cfg, hasher=hasher)
+        # the app the deployment names (the node's own default is the
+        # kvstore); a persistent app keeps its state in the node's home
+        app = chainlib.make_app(config["app"], cfg.db_path("app"))
+        node = Node(cfg, app=app, hasher=hasher)
         shape_seconds, shape_failures = _warm_shapes(record, seed, log)
         t_node = time.monotonic()
         node.start()
@@ -462,6 +465,11 @@ def run(ctx: dict) -> dict | None:
             f"{sum(old):.3f}s (longest {max(old, default=0.0):.3f}s); the height stood still "
             f"for at most {standstill:.3f}s"
         )
+        table_events = {
+            event: int(rpc.rise(metrics_start, metrics_end, "tendermint_verify_table_cache_total", event=event))
+            for event in ("hit", "miss", "incremental", "host_build")
+        }
+        log(f"table cache: tendermint_verify_table_cache_total rose inside the window by {json.dumps(table_events)}")
         written = _bytes_written()
         log(f"storage: this process has written, in bytes since it started: {json.dumps(written)}")
         for name, m in end_to_end.items():
@@ -478,6 +486,7 @@ def run(ctx: dict) -> dict | None:
             "gc": {"collections": len(pauses.pauses), "seconds": sum(s for _g, s in pauses.pauses),
                    "oldest": len(old), "oldest_seconds": sum(old), "oldest_longest_s": max(old, default=0.0)},
             "longest_standstill_s": standstill, "process_write_bytes": written,
+            "table_cache_events": table_events,
         }
         if trace_info is not None:
             from benchmark.lib import trace_reduce
@@ -508,7 +517,8 @@ def run(ctx: dict) -> dict | None:
             "device_kind": kind, "window": [wall_start, wall_end], "heights": [h_open, h_close],
         }
         checked = checks.run_checks(
-            port=port, record=record, seed=seed,
+            port=port, record=record, config=config, mix=mix, seed=seed,
+            static_reference=ctx["control"] == "static_reference",
             h_close=h_close, launches=launches_all, metrics=metrics_end, health=health,
             devices=devices, log=log,
         )
@@ -550,6 +560,10 @@ def run(ctx: dict) -> dict | None:
                     "hash_host_fallbacks": checked["hash_host_fallbacks"], "compared": compared},
             breakdown=breakdown, notes=notes,
         )
+        if "valset" in mix:
+            # where the set changes, the table cache's four events go into
+            # the result line, before `compared`
+            obs["line_extras"] = {"table_cache_events": table_events}
         return obs
     except RunFailure as e:
         print(f"benchmark: {e}", file=sys.stderr)

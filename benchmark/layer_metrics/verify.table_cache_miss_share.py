@@ -1,0 +1,5 @@
+from benchmark.lib import table_cache
+
+
+def reduce(obs):
+    return table_cache.miss_share(obs)

@@ -2,9 +2,16 @@
 checks, cut to what a catch-up run can show).
 
 1. A seeded sample of applied heights, read back over RPC: block hash,
-   `data_hash`, `app_hash`, `validators_hash` and the commit's block id
-   equal the generator's record; `reference.py` checks the same sample
-   with `hashlib` and the host ed25519 library alone.
+   `data_hash`, `app_hash` and the commit's block id equal the
+   generator's record; `reference.py` checks the same sample with
+   `hashlib` and the host ed25519 library alone, and per height: the
+   header's `validators_hash` is the root of the set `reference.py`
+   derives for that height from the genesis set and the mix's changes,
+   and the commit verifies against that set. Beside its seeded heights
+   the sample holds the four heights around the first and the last change
+   of the set the node applied (h - 1, h, h + 1, h + 2 around the first
+   header that carries a new hash); the generator's record of the sets is
+   compared with the reference's too.
 2. The last applied height's write is read back through `abci_query`.
 3. Every verify launch of k*n >= 512 lanes was answered by a device
    backend, no breaker moved, no call fell back to the host; and the
@@ -27,7 +34,7 @@ import random
 import re
 
 from . import chain as chainlib
-from . import reference, rpc
+from . import reference, rpc, signer
 from .ledger import DEVICE_BACKENDS, HASH_DEVICE_BACKENDS, VERIFY_KINDS
 
 DEVICE_MIN_LANES = 512  # services/verifier.py DEVICE_MIN_BATCH
@@ -83,32 +90,87 @@ def sample_heights(seed: int, top: int, n: int) -> list[int]:
     return sorted({top, *rng.sample(range(1, top), n - 1)})
 
 
-def check_sample(port: int, record, heights: list[int]) -> tuple[list[str], int]:
+def reference_sets(record, config: dict, mix: dict) -> list[dict]:
+    """The per-height reference's validator sets for this chain
+    (`reference.validator_sets`): the genesis keys and powers, the mix's
+    changes with each key rank turned into the seed's key, and the
+    addresses the record gives (a record from before `addresses`, which
+    the chain cache may still hold, gets the program's)."""
+    genesis = [(bytes.fromhex(k), w) for k, w in zip(record.pubkeys, record.powers)]
+    address_of = {bytes.fromhex(k): bytes.fromhex(a) for k, a in record.addresses.items()}
+    if not address_of:
+        from tendermint_tpu.crypto.keys import PubKey
+
+        address_of = {k: PubKey(k).address for k, _w in genesis}
+    keys: dict[int, bytes] = {}
+
+    def key(rank: int) -> bytes:
+        if rank not in keys:
+            keys[rank] = signer.public_bytes(signer.private_key(record.seed, rank))
+        return keys[rank]
+
+    changes = {
+        h: [(key(rank), power) for rank, power in step]
+        for h, step in chainlib.valset_changes(config, mix, record.n_blocks).items()
+    }
+    return reference.validator_sets(genesis, changes, address_of)
+
+
+def record_sets_differ(record, sets: list[dict]) -> int:
+    """Stretches of the generator's record that are not the reference's."""
+    ours = [
+        (s["from_height"], s["validators_hash"], s["pubkeys"], s["powers"])
+        for s in record.valsets or [record.set_at(1)] if s["from_height"] <= record.n_blocks
+    ]
+    theirs = [
+        (s["from_height"], s["validators_hash"].hex(), [k.hex() for k in s["pubkeys"]], s["powers"])
+        for s in sets if s["from_height"] <= record.n_blocks
+    ]
+    return sum(a != b for a, b in zip(ours, theirs)) + abs(len(ours) - len(theirs))
+
+
+def boundary_heights(sets: list[dict], top: int) -> list[int]:
+    """h - 1 .. h + 2 around the first and the last height h <= `top`
+    whose header carries another hash than the header before it."""
+    starts = [s["from_height"] for s in sets[1:] if s["from_height"] <= top]
+    around = {h + d for h in (starts[:1] + starts[-1:]) for d in (-1, 0, 1, 2)}
+    return sorted(h for h in around if 1 <= h <= top)
+
+
+def check_sample(port: int, record, heights: list[int], sets: list[dict]) -> dict:
+    """`sets`: the validator sets the sample is held to, as
+    `reference.validator_sets` gives them. Returns the failures in words,
+    the fields compared, and how many of the sampled headers carry another
+    `validators_hash` than the set of their height and how many of the
+    sampled commits do not verify against it."""
     bad: list[str] = []
-    pubkeys = [bytes.fromhex(p) for p in record.pubkeys]
-    compared = 0
+    compared = hash_differ = commit_differ = 0
     for h in heights:
+        held = reference.set_at(sets, h)
         blk = rpc.call(port, f"block?height={h}")["block"]
         hdr = blk["header"]
         want = {
             "hash": record.block_hash[h - 1],
             "data_hash": record.data_hash[h - 1],
             "app_hash": record.app_hash[h - 2] if h >= 2 else "",
-            "validators_hash": record.validators_hash,
+            "validators_hash": held["validators_hash"].hex(),
         }
         for field, value in want.items():
             compared += 1
             if hdr[field] != value:
                 bad.append(f"block {h}: served {field} {hdr[field][:16]} != source {value[:16]}")
+                hash_differ += field == "validators_hash"
         bad += reference.check_block(blk)
         com = rpc.call(port, f"commit?height={h}")["commit"]
         compared += 1
         if com["block_id"]["hash"] != record.block_hash[h - 1]:
             bad.append(f"commit {h}: block id differs from the source chain")
-        bad += reference.check_commit(
-            record.chain_id, h, record.block_hash[h - 1], com, pubkeys, record.powers
+        wrong = reference.check_commit(
+            record.chain_id, h, record.block_hash[h - 1], com, held["pubkeys"], held["powers"]
         )
-    return bad, compared
+        commit_differ += bool(wrong)
+        bad += wrong
+    return {"bad": bad, "compared": compared, "hash_differ": hash_differ, "commit_differ": commit_differ}
 
 
 def check_last_write(port: int, record) -> list[str]:
@@ -127,13 +189,15 @@ def check_last_write(port: int, record) -> list[str]:
 
 
 def validator_set(record):
-    """The chain's validator set as the program's type, from the record."""
+    """The validator set of the chain's tail (the heights of
+    `record.tail_entries()`) as the program's type, from the record."""
     from tendermint_tpu.crypto.keys import PubKey
     from tendermint_tpu.types import Validator, ValidatorSet
 
-    keys = [PubKey(bytes.fromhex(p)) for p in record.pubkeys]
+    tail = record.set_at(record.n_blocks - chainlib.FAULT_WINDOW)
+    keys = [PubKey(bytes.fromhex(p)) for p in tail["pubkeys"]]
     return ValidatorSet(
-        [Validator(address=k.address, pub_key=k, voting_power=w) for k, w in zip(keys, record.powers)]
+        [Validator(address=k.address, pub_key=k, voting_power=w) for k, w in zip(keys, tail["powers"])]
     )
 
 
@@ -168,16 +232,39 @@ def check_planted_fault(record, seed: int) -> list[str]:
     return bad
 
 
-def run_checks(*, port, record, seed, h_close, launches, metrics, health, devices, log) -> dict:
+def run_checks(
+    *, port, record, config, mix, seed, h_close, launches, metrics, health, devices, log,
+    static_reference: bool = False,
+) -> dict:
     """Every check; returns the failures in words, the two counts of host
     answers (the readers `verify.host_fallbacks` and `hash.host_fallbacks`
-    report them) and each number compared beside its limit."""
+    report them) and each number compared beside its limit. With
+    `static_reference` (the control of that name) the sample is held to
+    the genesis set at every height."""
     failures: list[str] = []
     compared: dict[str, list] = {}
-    heights = sample_heights(seed, h_close, 32)
-    bad, n_compared = check_sample(port, record, heights)
-    log(f"check sample: {len(heights)} heights, {n_compared} fields and {len(heights)} commits compared, {len(bad)} differ (limit 0)")
+    sets = reference_sets(record, config, mix)
+    differ = record_sets_differ(record, sets)
+    around = boundary_heights(sets, h_close)
+    log(
+        f"check validator sets: {len(sets)} stretches by the reference's set arithmetic, "
+        f"{differ} differ from the generator's record (limit 0)"
+    )
+    compared["record_valsets_differ"] = [differ, 0]
+    if differ:
+        failures.append(f"{differ} stretches of the generator's validator sets are not the reference's")
+    heights = sorted({*sample_heights(seed, h_close, 32), *around})
+    got = check_sample(port, record, heights, sets[:1] if static_reference else sets)
+    bad = got["bad"]
+    log(
+        f"check sample: {len(heights)} heights ({len(around)} of them around the first and the last "
+        f"set change applied: {around}), {got['compared']} fields and {len(heights)} commits compared, "
+        f"{len(bad)} differ (limit 0); validators_hash not the set's of its height: {got['hash_differ']}, "
+        f"commits not verified by the set of their height: {got['commit_differ']} (limits 0)"
+    )
     compared["sample_differ"] = [len(bad), 0]
+    compared["sample_valset_hash_differ"] = [got["hash_differ"], 0]
+    compared["sample_commit_differ"] = [got["commit_differ"], 0]
     failures += bad
     bad = check_last_write(port, record)
     log(f"check last write: {len(bad)} differ (limit 0)")

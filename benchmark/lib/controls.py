@@ -9,6 +9,10 @@ runs (`run.py --control <name>`); a benchmark run never installs one.
 * `host_trees` (`host_tree_hasher`): the node is built with the host tree
   hasher, so every Merkle tree, a block's 10,000 txs with them, is
   answered by the host library where a device tree is due.
+* `static_reference` (in `lib/checks.py`): the sample is held to the
+  genesis validator set at every height, as a node that never changed its
+  set would serve it: false on a chain whose set changes, true on one
+  whose set does not.
 * `apphash_off_by_one` (in `drivers/catchup.py`): the record the node is
   held to has every app hash one height off, as a node that applied the
   wrong state would show.

@@ -37,8 +37,14 @@ def share_of_window(obs: dict, stages: tuple[str, ...]) -> float | None:
     return 100.0 * sum(_rise(obs, SECONDS, stage=s) for s in stages) / (end - start)
 
 
-def full_window_share(obs: dict) -> float | None:
+def window_cut_share(obs: dict, cut: str) -> float | None:
+    """Windows that ended for the reason `cut` (`full`, `pool_gap`,
+    `boundary`) over all windows joined, in percent."""
     windows = _rise(obs, WINDOWS)
     if _rise(obs, BLOCKS) <= 0 or windows <= 0:
         return None
-    return 100.0 * _rise(obs, WINDOWS, cut="full") / windows
+    return 100.0 * _rise(obs, WINDOWS, cut=cut) / windows
+
+
+def full_window_share(obs: dict) -> float | None:
+    return window_cut_share(obs, "full")

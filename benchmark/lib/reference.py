@@ -10,7 +10,14 @@ seed (validator keys and powers: `signer.private_key`, `chain.powers`):
   at the largest power of two below n);
 * the commit that sealed it carries valid ed25519 signatures, over the
   canonical sign-bytes of a precommit for that block id, from validators
-  holding more than 2/3 of the voting power.
+  holding more than 2/3 of the voting power of the set of THAT height;
+* the header's `validators_hash` is the SimpleMerkle root of that set,
+  which the plain set arithmetic below derives from the genesis set and
+  the `(key, power)` changes the chain's blocks carry: replace on an equal
+  key, drop on power 0, insert, order by address. A key's address is an
+  input (the generator's record has it): how the program derives one is
+  not what is compared. So `ValidatorSet.apply_changes` and
+  `ValidatorSet.hash` are held to something they did not produce.
 """
 
 from __future__ import annotations
@@ -36,6 +43,68 @@ def merkle_root(items: list[bytes]) -> bytes:
         return hashlib.sha256(b"\x01" + root(hs[:k]) + root(hs[k:])).digest()
 
     return root(level)
+
+
+def uvarint(n: int) -> bytes:
+    out = bytearray()
+    while n >= 0x80:
+        out.append(n & 0x7F | 0x80)
+        n >>= 7
+    out.append(n)
+    return bytes(out)
+
+
+def validators_hash(validators: list[tuple[bytes, bytes, int]]) -> bytes:
+    """The root over a set's (address, pubkey, power) rows in address
+    order. A validator's leaf is its address and its key, each behind its
+    length as a uvarint, then its power as a uvarint."""
+    return merkle_root(
+        [
+            uvarint(len(address)) + address + uvarint(len(pubkey)) + pubkey + uvarint(power)
+            for address, pubkey, power in validators
+        ]
+    )
+
+
+def validator_sets(
+    genesis: list[tuple[bytes, int]], changes: dict[int, list[tuple[bytes, int]]],
+    address_of: dict[bytes, bytes],
+) -> list[dict]:
+    """The chain's validator sets, one entry a stretch of heights, oldest
+    first: `from_height`, `pubkeys` and `powers` in address order, and the
+    `validators_hash` every header of the stretch carries. `genesis` is the
+    (pubkey, power) list of height 1; `changes[h]` are the (pubkey, power)
+    changes block h carries, which take effect at height h + 1."""
+    members = dict(genesis)
+
+    def entry(from_height: int) -> dict:
+        rows = sorted((address_of[k], k, w) for k, w in members.items())
+        return {
+            "from_height": from_height, "pubkeys": [k for _a, k, _w in rows],
+            "powers": [w for _a, _k, w in rows], "validators_hash": validators_hash(rows),
+        }
+
+    sets = [entry(1)]
+    for height in sorted(changes):
+        for key, power in changes[height]:
+            if power < 0:
+                raise ValueError(f"block {height}: negative power")
+            if power:
+                members[key] = power
+            elif members.pop(key, None) is None:
+                raise ValueError(f"block {height} removes a key that is in no set")
+        sets.append(entry(height + 1))
+    return sets
+
+
+def set_at(sets: list[dict], height: int) -> dict:
+    """The set that header `height` names and commit `height` is signed by."""
+    found = sets[0]
+    for s in sets:
+        if s["from_height"] > height:
+            break
+        found = s
+    return found
 
 
 def sign_bytes(chain_id: str, vote: dict) -> bytes:

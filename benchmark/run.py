@@ -140,6 +140,8 @@ def main(argv=None) -> int:
     }
     if args.trace and obs.get("breakdown"):
         line["breakdown"] = obs["breakdown"]
+    # what a driver shows beside `compared` (keys the contract does not read)
+    line.update(obs.get("line_extras", {}))
     # each number `correct` was decided from beside its limit ([number,
     # limit]; every limit is exact): last in the line, and the last lines
     # of standard error
@@ -157,6 +159,8 @@ def main(argv=None) -> int:
         json.dump(detail, f, indent=1)
     for name, m in sorted(metrics.items()):
         log(f"metric {name} = {m['value']} {m['unit']}")
+    for name, value in obs.get("line_extras", {}).items():
+        print(f"{name}: {json.dumps(value)}", file=sys.stderr)
     for name, (number, limit) in line["compared"].items():
         print(f"compared {name}: {number} (limit {limit})", file=sys.stderr)
     print(json.dumps(line), flush=True)
