@@ -21,6 +21,35 @@ from tendermint_tpu.abci.application import Application
 from tendermint_tpu.abci.types import Result, ResultInfo, ResultQuery, Validator
 
 
+class CommittedHeight:
+    """The height of the last block whose `Commit` the app has answered
+    over a consensus connection: `EndBlock` names the height and the
+    `Commit` that returns ends it. A block is in the block store before
+    it is applied; `/abci_query` waits here so as not to answer from
+    the block before. None until this process has committed a block:
+    the handshake has levelled the app with the store by then."""
+
+    def __init__(self) -> None:
+        self._cond = threading.Condition()
+        self._ending: int | None = None
+        self.height: int | None = None
+
+    def ending(self, height: int) -> None:
+        self._ending = height
+
+    def ended(self) -> None:
+        with self._cond:
+            self.height = self._ending
+            self._cond.notify_all()
+
+    def wait_for(self, height: int, timeout: float) -> bool:
+        """False if the app has not committed `height` in `timeout` s."""
+        with self._cond:
+            return self._cond.wait_for(
+                lambda: self.height is None or self.height >= height, timeout
+            )
+
+
 class _LocalClient:
     """Mutex-wrapped in-process app access (reference localClient)."""
 
@@ -81,6 +110,10 @@ def _accepts_evidence(begin_block) -> bool:
 
 
 class AppConnConsensus(_LocalClient):
+    def __init__(self, app: Application, lock: threading.Lock) -> None:
+        super().__init__(app, lock)
+        self.committed = CommittedHeight()
+
     def init_chain_sync(self, validators: list[Validator]) -> None:
         with self._lock:
             self._app.init_chain(validators)
@@ -105,12 +138,16 @@ class AppConnConsensus(_LocalClient):
         return res
 
     def end_block_sync(self, height: int) -> list[Validator]:
+        self.committed.ending(height)
         with self._lock:
             return self._app.end_block(height)
 
     def commit_sync(self) -> Result:
         with self._lock:
-            return self._app.commit()
+            res = self._app.commit()
+        if res.is_ok:
+            self.committed.ended()
+        return res
 
 
 class AppConns:

@@ -261,7 +261,10 @@ def _read_result(r: Reader) -> Result:
 
 class _RemoteConsensus:
     def __init__(self, conn: _SocketConn) -> None:
+        from tendermint_tpu.abci.client import CommittedHeight
+
         self._conn = conn
+        self.committed = CommittedHeight()
 
     def close(self) -> None:
         self._conn.close()
@@ -293,11 +296,15 @@ class _RemoteConsensus:
         return res
 
     def end_block_sync(self, height: int):
+        self.committed.ending(height)
         r = self._conn.call(Writer().uvarint(_MSG_END_BLOCK).uvarint(height).build())
         return _dec_validators(r)
 
     def commit_sync(self) -> Result:
-        return _read_result(self._conn.call(Writer().uvarint(_MSG_COMMIT).build()))
+        res = _read_result(self._conn.call(Writer().uvarint(_MSG_COMMIT).build()))
+        if res.is_ok:
+            self.committed.ended()
+        return res
 
 
 def socket_client_creator(addr: str):

@@ -2,7 +2,8 @@
 
 Mirrors the `dbm.DB` seam in the reference (`tmlibs/db`): Get/Set/Delete
 with synchronous variants, a write `Batch`, and ordered iteration;
-consumers are the block store, state DB, tx index, and address book.
+consumers are the block store, state DB, address book, and the tx index
+of a node that is given its databases (`MemDB` in tests).
 
 What is durable when. A write is one transaction: `set`, `set_sync` and
 `delete` are a transaction of one row, `Batch.write` / `write_sync` a
@@ -13,8 +14,21 @@ fsync of the WAL and is on disk when the call returns. A fast-synced or
 committed block has three acknowledged points, in this order, one WAL
 fsync each and atomic per block: the block store's watermark (with the
 block's rows, one batch), the ABCI responses (`set_sync`, before the
-app's commit), the state (with its validators pointer, one batch). The
-tx index is a fourth transaction that nothing acknowledges.
+app's commit), the state (with its validators pointer, one batch).
+
+The tx index is a fourth durable write that nothing acknowledges, and
+on a node that keeps files it is not a `SQLiteDB`: it is a log of
+sorted runs in a directory of its own, `<db dir>/txindex/`
+(`db/runlog.py`, built by `state/txindex.py` `RunTxIndexer`). When
+`add_batch` returns, every row of the block is on disk under one fsync
+of one appended record; a crash leaves all of a block's rows or none; a
+`/tx` reader on another thread sees all or none. `add_batch` returns
+between the responses and the state, before the app's commit, and no
+one reads the index back before going on. After a restart `/tx` answers
+for every block whose `add_batch` returned; a block the crash caught
+before that stays unindexed, since the handshake replays it without an
+indexer (`consensus/replay.py`). A `txindex.db` from before the run
+log keeps answering for its rows, read-only.
 """
 
 from __future__ import annotations

@@ -29,7 +29,7 @@ from tendermint_tpu.p2p.tcp import TcpListener, dial
 from tendermint_tpu.rpc.core import make_routes
 from tendermint_tpu.rpc.server import RPCServer
 from tendermint_tpu.state.state import State, load_state, make_genesis_state
-from tendermint_tpu.state.txindex import KVTxIndexer
+from tendermint_tpu.state.txindex import KVTxIndexer, RunTxIndexer
 from tendermint_tpu.types import events as ev
 from tendermint_tpu.types.genesis import GenesisDoc
 from tendermint_tpu.types.priv_validator import PrivValidatorFS
@@ -170,7 +170,13 @@ class Node:
         # re-validate txs that were in flight before a crash; the WAL is
         # compacted to the survivors so it cannot grow across restarts
         self.mempool.replay_wal()
-        self.tx_indexer = KVTxIndexer(_db("txindex"))
+        # a node that keeps files indexes into a run log of its own
+        # directory; a provided DB (MemDB in tests) is indexed as it is
+        self.tx_indexer = (
+            KVTxIndexer(db_provider("txindex"))
+            if db_provider is not None
+            else RunTxIndexer(os.path.dirname(cfg.db_path("txindex")))
+        )
         self.event_switch = ev.EventSwitch()
 
         # replica mode (tendermint_tpu/lightclient/): never join
@@ -695,6 +701,7 @@ class Node:
         self.mempool.close()
         self.evidence_pool.close()
         self.app_conns.close()
+        self.tx_indexer.close()
         if getattr(self, "_span_log", None) is not None:
             from tendermint_tpu.telemetry import TRACER
 

@@ -17,6 +17,7 @@ from tendermint_tpu.types import events as ev
 from tendermint_tpu.types.tx import tx_hash
 
 BROADCAST_TX_COMMIT_TIMEOUT_S = 60.0  # reference waits up to 120s
+APPLY_WAIT_S = 5.0  # the longest `abci_query` waits for the commit of a stored block
 
 
 def _header_json(header) -> dict:
@@ -340,6 +341,17 @@ def make_routes(node) -> dict:
         return out
 
     def abci_query(path: str = "", data: str = "", height: int = 0, prove: bool = False) -> dict:
+        # A block is in the store, and in `/status`, before it is applied:
+        # a query that arrives in between waits for the app's `Commit` of
+        # that block and is not answered from the block before. A node
+        # whose apply failed stays a block behind its store, and says so.
+        stored = node.block_store.height
+        committed = node.app_conns.consensus.committed
+        if not committed.wait_for(stored, APPLY_WAIT_S):
+            raise RPCError(
+                -32000,
+                f"block {stored} is stored and the app has committed {committed.height}",
+            )
         res = node.app_conns.query.query_sync(
             path, bytes.fromhex(data) if data else b"", int(height), bool(prove)
         )

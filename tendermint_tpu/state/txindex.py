@@ -1,17 +1,37 @@
 """Transaction indexing (reference `state/txindex/`).
 
-`KVTxIndexer` stores each tx's execution result keyed by tx hash in a
-KV DB, batched per block (reference `kv/kv.go:17-60`, batch built in
-`state/execution.go:279-293`); `NullTxIndexer` is the disabled default.
+Each tx's execution result keyed by tx hash, batched per block
+(reference `kv/kv.go:17-60`, batch built in
+`state/execution.go:279-293`). `RunTxIndexer` is the index of a node
+that keeps files: a log of sorted runs in a directory of its own
+(`db/runlog.py`, the role LevelDB has in the reference). `KVTxIndexer`
+is the same index over a `DB` a caller provides (`MemDB` in tests);
+`NullTxIndexer` is the disabled default.
+
+What the index promises (`db/kv.py`'s header has the block's other
+three writes). When `add_batch` returns every row of the block is on
+disk, under one fsync; a crash leaves all of a block's rows or none; a
+`/tx` reader on another thread sees all of them or none. Nothing
+acknowledges the index: `add_batch` returns before the state is saved
+and before the app commits, and nothing reads the index back. After a
+restart `/tx` answers for every block whose `add_batch` returned. The
+block a crash caught between the store's watermark and `add_batch`
+stays unindexed: the handshake replays it without an indexer
+(`consensus/replay.py`), as it did when the index was a SQLite file.
 """
 
 from __future__ import annotations
 
 import json
+import os
+import pathlib
+import sqlite3
+import threading
 from dataclasses import dataclass
 
 from tendermint_tpu.abci.types import Result
 from tendermint_tpu.db.kv import DB
+from tendermint_tpu.db.runlog import RunLog
 from tendermint_tpu.types.tx import tx_hash
 
 
@@ -55,6 +75,9 @@ class TxIndexer:
     def get(self, tx_hash: bytes) -> TxResult | None:
         raise NotImplementedError
 
+    def close(self) -> None:
+        pass
+
 
 class NullTxIndexer(TxIndexer):
     """Indexing disabled (reference `null.TxIndex`)."""
@@ -66,22 +89,76 @@ class NullTxIndexer(TxIndexer):
         return None
 
 
+def _rows(block, abci_responses) -> dict[bytes, bytes]:
+    """A block's index rows by tx hash; of a tx that is in the block
+    twice the later one stays."""
+    height = block.header.height
+    rows = {}
+    for i, tx in enumerate(block.data.txs):
+        tx = bytes(tx)
+        rows[tx_hash(tx)] = TxResult(
+            height=height, index=i, tx=tx, result=abci_responses.deliver_tx[i]
+        ).to_json()
+    return rows
+
+
 class KVTxIndexer(TxIndexer):
     def __init__(self, db: DB) -> None:
         self._db = db
 
     def add_batch(self, block, abci_responses) -> None:
         batch = self._db.batch()
-        for i, tx in enumerate(block.data.txs):
-            tr = TxResult(
-                height=block.header.height,
-                index=i,
-                tx=bytes(tx),
-                result=abci_responses.deliver_tx[i],
-            )
-            batch.set(b"tx:" + tx_hash(bytes(tx)), tr.to_json())
+        for key, row in _rows(block, abci_responses).items():
+            batch.set(b"tx:" + key, row)
         batch.write()
 
     def get(self, tx_hash: bytes) -> TxResult | None:
         raw = self._db.get(b"tx:" + tx_hash)
         return TxResult.from_json(raw) if raw is not None else None
+
+
+class RunTxIndexer(TxIndexer):
+    """The index under `<db_dir>/txindex/`. A data directory from before
+    it holds a `txindex.db` (`KVTxIndexer` over `SQLiteDB`): a hash the
+    run log does not have is looked up there, and that file is never
+    written again."""
+
+    def __init__(self, db_dir: str) -> None:
+        self._log = RunLog(os.path.join(db_dir, "txindex"))
+        old = os.path.join(db_dir, "txindex.db")
+        self._old = _OldIndexFile(old) if os.path.exists(old) else None
+
+    def add_batch(self, block, abci_responses) -> None:
+        self._log.append(block.header.height, _rows(block, abci_responses))
+
+    def get(self, tx_hash: bytes) -> TxResult | None:
+        raw = self._log.get(tx_hash)
+        if raw is None and self._old is not None:
+            raw = self._old.get(b"tx:" + tx_hash)
+        return TxResult.from_json(raw) if raw is not None else None
+
+    def close(self) -> None:
+        self._log.close()
+        if self._old is not None:
+            self._old.close()
+
+
+class _OldIndexFile:
+    """`SQLiteDB`'s one table, opened read-only."""
+
+    def __init__(self, path: str) -> None:
+        self._conn = sqlite3.connect(
+            pathlib.Path(path).resolve().as_uri() + "?mode=ro",
+            uri=True,
+            check_same_thread=False,
+        )
+        self._lock = threading.Lock()
+
+    def get(self, key: bytes) -> bytes | None:
+        with self._lock:
+            row = self._conn.execute("SELECT v FROM kv WHERE k = ?", (key,)).fetchone()
+        return row[0] if row else None
+
+    def close(self) -> None:
+        with self._lock:
+            self._conn.close()

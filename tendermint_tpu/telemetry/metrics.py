@@ -590,11 +590,41 @@ VALSET_HASHES = Counter(
 
 DB_COMMITS = Counter(
     "tendermint_db_commits_total",
-    "SQLite write transactions (one WAL fsync each) by database file: a "
-    "set, a set_sync, a delete or a whole write batch is one. Over "
-    "tendermint_fastsync_blocks_applied_total a block reads 4: blockstore "
-    "1, state 2 (ABCI responses, state), txindex 1",
+    "Durable writes (one fsync each) by store: on a SQLite file a "
+    "transaction (a set, a set_sync, a delete or a whole write batch), on "
+    "the tx index's run log (db/runlog.py) a block's one appended record. "
+    "Over tendermint_fastsync_blocks_applied_total a block reads 4: "
+    "blockstore 1, state 2 (ABCI responses, state), txindex 1",
     labelnames=("db",),
+)
+
+# -- the tx index's run log (db/runlog.py) ------------------------------------
+
+TXINDEX_BYTES_WRITTEN = Counter(
+    "tendermint_txindex_bytes_written_total",
+    "Bytes the tx index wrote, by kind: append (a block's record: its "
+    "sorted keys, its values, header and checksum) and merge (key files a "
+    "merge wrote; values are never rewritten). merge over append is the "
+    "write amplification",
+    labelnames=("kind",),
+)
+for _kind in ("append", "merge"):
+    TXINDEX_BYTES_WRITTEN.labels(kind=_kind).inc(0)
+TXINDEX_MERGES = Counter(
+    "tendermint_txindex_merges_total",
+    "Merges of the tx index's runs finished (each: one key file written "
+    "and fsynced, one manifest switch), all off the thread that appends",
+)
+TXINDEX_RUNS = Gauge(
+    "tendermint_txindex_runs",
+    "Live runs of the tx index: what a lookup of an absent hash probes. "
+    "Under 8 a size tier once the merger has caught up",
+)
+TXINDEX_PROBES = Histogram(
+    "tendermint_txindex_probes",
+    "Runs probed by one lookup of the tx index (newest first, until the "
+    "hash is found)",
+    buckets=(1, 2, 4, 8, 16, 32, 64),
 )
 
 # -- state sync ---------------------------------------------------------------

@@ -29,7 +29,7 @@ from tendermint_tpu.codec import Reader
 from tendermint_tpu.db.kv import SQLiteDB
 from tendermint_tpu.services.verifier import HostBatchVerifier
 from tendermint_tpu.state import apply_block, load_state, make_genesis_state
-from tendermint_tpu.state.txindex import KVTxIndexer
+from tendermint_tpu.state.txindex import RunTxIndexer
 from tendermint_tpu.types.tx import tx_hash
 
 from tests.helpers import ChainSim
@@ -179,22 +179,23 @@ class TestABlocksTransactions:
     def _files(self, tmp_path):
         return {
             name: SQLiteDB(str(tmp_path / f"{name}.db"))
-            for name in ("blockstore", "state", "txindex")
+            for name in ("blockstore", "state")
         }
 
     def test_a_fast_synced_block_is_four_commits(self, tmp_path):
         """blockstore 1 (rows and watermark), state 2 (ABCI responses;
-        validators pointer and state), txindex 1 (its rows). A fifth
-        fails here, not in a chip run."""
+        validators pointer and state), txindex 1 (its rows: one record
+        appended to the run log the node builds beside its files). A
+        fifth fails here, not in a chip run."""
         sim = ChainSim(n_vals=4)
         for h in range(self.N_BLOCKS + 1):
             sim.advance(txs=[b"k%d-%d=v" % (h, i) for i in range(3)])
         dbs = self._files(tmp_path)
+        indexer = RunTxIndexer(str(tmp_path))
         try:
             state = make_genesis_state(dbs["state"], sim.genesis)
             state.save()
             store = BlockStore(dbs["blockstore"])
-            indexer = KVTxIndexer(dbs["txindex"])
             reactor = BlockchainReactor(
                 state=state, store=store,
                 app_conn=local_client_creator(KVStoreApp())().consensus,
@@ -204,14 +205,16 @@ class TestABlocksTransactions:
             reactor.pool.set_peer_height("srv", len(sim.blocks))
             for h, b in enumerate(sim.blocks, start=1):
                 reactor.pool._blocks[h] = (b, "srv")
-            before = {name: commits(name) for name in dbs}
+            names = (*dbs, "txindex")
+            before = {name: commits(name) for name in names}
             reactor._try_sync()
-            rise = {name: commits(name) - before[name] for name in dbs}
+            rise = {name: commits(name) - before[name] for name in names}
             n = self.N_BLOCKS
             assert store.height == n == state.last_block_height == reactor.blocks_synced
             assert (rise["blockstore"], rise["state"], rise["txindex"]) == (n, 2 * n, n)
             assert indexer.get(tx_hash(b"k7-2=v")).height == 8
         finally:
+            indexer.close()
             for db in dbs.values():
                 db.close()
 
@@ -220,6 +223,7 @@ class TestABlocksTransactions:
         for h in range(5):
             sim.advance(txs=[b"k%d=v" % h])
         dbs = self._files(tmp_path)
+        indexer = RunTxIndexer(str(tmp_path))
         try:
             state = make_genesis_state(dbs["state"], sim.genesis)
             state.save()
@@ -232,7 +236,7 @@ class TestABlocksTransactions:
                 store.save_block(block, parts, sim.commits[i])
                 apply_block(
                     state, block, parts.header, conns.consensus,
-                    verifier=HostBatchVerifier(), tx_indexer=KVTxIndexer(dbs["txindex"]),
+                    verifier=HostBatchVerifier(), tx_indexer=indexer,
                 )
                 # back from the call: the state of this height is on the file
                 other = SQLiteDB(str(tmp_path / "state.db"))
@@ -242,6 +246,7 @@ class TestABlocksTransactions:
                     other.close()
             assert app.seen == [(h, h, True, h - 1) for h in range(1, 5)]
         finally:
+            indexer.close()
             for db in dbs.values():
                 db.close()
 
