@@ -31,6 +31,8 @@ from tendermint_tpu.telemetry import TRACER
 from tendermint_tpu.telemetry import launchlog as _launchlog
 from tendermint_tpu.telemetry.metrics import (
     FASTSYNC_BLOCKS_APPLIED,
+    FASTSYNC_CHILD_STAGES,
+    FASTSYNC_STAGE_CPU_SECONDS,
     FASTSYNC_STAGE_SECONDS,
     FASTSYNC_STAGES,
     FASTSYNC_WINDOWS,
@@ -61,19 +63,30 @@ VERIFY_WINDOW = 16  # commits batched per device call
 PIPELINE_DEPTH = 2
 
 _STAGE_SECONDS = {
-    s: FASTSYNC_STAGE_SECONDS.labels(stage=s) for s in FASTSYNC_STAGES
+    s: FASTSYNC_STAGE_SECONDS.labels(stage=s)
+    for s in FASTSYNC_STAGES + FASTSYNC_CHILD_STAGES
 }
+_STAGE_CPU_SECONDS = {
+    s: FASTSYNC_STAGE_CPU_SECONDS.labels(stage=s) for s in _STAGE_SECONDS
+}
+
+
+def _observe_stage(name: str, seconds: float, cpu_seconds: float) -> None:
+    _STAGE_SECONDS[name].observe(seconds)
+    _STAGE_CPU_SECONDS[name].inc(cpu_seconds)
 
 
 def _stage(name: str, window: dict | None = None):
     """Stopwatch around one stage of a block's life (`TRACER.stage`):
-    the duration goes to `tendermint_fastsync_stage_seconds{stage}` and,
-    for a stage that belongs to a verify window, into that window's
-    per-stage seconds (its `fastsync.window` span); the stretch shows
-    as `fastsync.<name>` in a profiler trace."""
+    the duration goes to `tendermint_fastsync_stage_seconds{stage}`, the
+    CPU time its thread took in it to
+    `tendermint_fastsync_stage_cpu_seconds_total{stage}` and, for a
+    stage that belongs to a verify window, the duration into that
+    window's per-stage seconds (its `fastsync.window` span); the stretch
+    shows as `fastsync.<name>` in a profiler trace."""
 
-    def sink(seconds: float) -> None:
-        _STAGE_SECONDS[name].observe(seconds)
+    def sink(seconds: float, cpu_seconds: float) -> None:
+        _observe_stage(name, seconds, cpu_seconds)
         if window is not None:
             window[name] = window.get(name, 0.0) + seconds
 
@@ -217,7 +230,7 @@ class BlockchainReactor(Reactor):
             else:
                 peer.try_send(BLOCKCHAIN_CHANNEL, _enc(_MSG_NO_BLOCK, arg))
         elif kind == "block_response":
-            _STAGE_SECONDS["decode"].observe(decode.seconds)
+            _observe_stage("decode", decode.seconds, decode.cpu_seconds)
             self.pool.add_block(peer.id, arg, size=len(payload))
         elif kind == "status_request":
             peer.try_send(

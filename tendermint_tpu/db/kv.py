@@ -38,7 +38,36 @@ import sqlite3
 import threading
 from typing import Iterator
 
-from tendermint_tpu.telemetry.metrics import DB_COMMITS
+from tendermint_tpu.telemetry import TRACER
+from tendermint_tpu.telemetry.metrics import (
+    DB_COMMIT_CPU_SECONDS,
+    DB_COMMIT_SECONDS,
+    DB_COMMITS,
+)
+
+
+class CommitClock:
+    """One store's durable writes, counted and timed where they happen:
+    `with clock.stage() as st:` around exactly the write (the `db.commit`
+    stage: wall and CPU, in the profiler's host plane), then
+    `clock.add(st)` once it has returned, so
+    `tendermint_db_commit_seconds_count{db}` is
+    `tendermint_db_commits_total{db}`. A write that raised is neither."""
+
+    def __init__(self, db: str) -> None:
+        self._commits = DB_COMMITS.labels(db=db)
+        self._seconds = DB_COMMIT_SECONDS.labels(db=db)
+        self._cpu_seconds = DB_COMMIT_CPU_SECONDS.labels(db=db)
+        self._cpu_seconds.inc(0)
+
+    @staticmethod
+    def stage():
+        return TRACER.stage("db.commit")
+
+    def add(self, stage) -> None:
+        self._commits.inc()
+        self._seconds.observe(stage.seconds)
+        self._cpu_seconds.inc(stage.cpu_seconds)
 
 
 class DB:
@@ -140,16 +169,16 @@ class SQLiteDB(DB):
     pay the one fsync). No write checkpoints: copying the WAL into the
     database file adds no durability, it bounds the WAL's size, and
     SQLite's automatic checkpoint (1,000 pages) and the one it makes as
-    the last connection closes do that. `tendermint_db_commits_total{db}` counts the transactions.
+    the last connection closes do that. `tendermint_db_commits_total{db}`
+    counts the transactions and `tendermint_db_commit_seconds{db}` times
+    their `commit()` (`CommitClock`).
     """
 
     def __init__(self, path: str) -> None:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         self._conn = sqlite3.connect(path, check_same_thread=False)
         self._lock = threading.Lock()
-        self._commits = DB_COMMITS.labels(
-            db=os.path.splitext(os.path.basename(path))[0]
-        )
+        self._commits = CommitClock(os.path.splitext(os.path.basename(path))[0])
         with self._lock:
             self._conn.execute("PRAGMA journal_mode=WAL")
             self._conn.execute("PRAGMA synchronous=FULL")
@@ -176,13 +205,14 @@ class SQLiteDB(DB):
                     )
                 if deletes:
                     self._conn.executemany("DELETE FROM kv WHERE k = ?", deletes)
-                self._conn.commit()
+                with self._commits.stage() as commit:
+                    self._conn.commit()
             except BaseException:
                 # never leave half a transaction open on the shared
                 # connection: the next caller's commit would land it
                 self._conn.rollback()
                 raise
-        self._commits.inc()
+        self._commits.add(commit)
 
     def iterate(self, prefix: bytes = b"") -> Iterator[tuple[bytes, bytes]]:
         with self._lock:

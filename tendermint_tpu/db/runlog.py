@@ -55,13 +55,14 @@ import zlib
 
 import numpy as np
 
+from tendermint_tpu.db.kv import CommitClock
 from tendermint_tpu.telemetry.metrics import (
-    DB_COMMITS,
     TXINDEX_BYTES_WRITTEN,
     TXINDEX_MERGES,
     TXINDEX_PROBES,
     TXINDEX_RUNS,
 )
+from tendermint_tpu.telemetry.process import retire_thread
 
 KEY_LEN = 32
 ENTRY = np.dtype([("key", f"S{KEY_LEN}"), ("ptr", "<u8")])
@@ -163,7 +164,7 @@ class RunLog:
         self._cond = threading.Condition()  # guards _runs, _closed, _merger
         self._closed = False
         self._merger: threading.Thread | None = None
-        self._commits = DB_COMMITS.labels(db="txindex")
+        self._commits = CommitClock("txindex")
         self._next_file = 0
         try:
             self._runs: tuple[_Run, ...] = self._recover()
@@ -281,8 +282,9 @@ class RunLog:
             crc = zlib.crc32(values, zlib.crc32(keyed, zlib.crc32(head)))
             record = b"".join((head, keyed, values, _U32.pack(crc)))
             try:
-                _write_all(self._fd, record)
-                os.fsync(self._fd)
+                with self._commits.stage() as commit:
+                    _write_all(self._fd, record)
+                    os.fsync(self._fd)
             except BaseException:
                 os.ftruncate(self._fd, at)  # none of the block's rows
                 raise
@@ -293,7 +295,7 @@ class RunLog:
                 self._runs += (run,)
                 live = len(self._runs)
                 TXINDEX_RUNS.set(live)
-        self._commits.inc()
+        self._commits.add(commit)
         TXINDEX_BYTES_WRITTEN.labels(kind="append").inc(len(record))
         self._kick()
         if live >= 2 * FAN_IN:
@@ -341,22 +343,27 @@ class RunLog:
         """Merges until none is due, then ends: `_kick` starts another.
         Whether one is due and whether one runs change under one lock,
         so an append never finds a merger that has just given up."""
-        while True:
-            with self._cond:
-                plan = None if self._closed else _plan(self._runs)
-                if plan is None:
-                    self._merger = None
-                    self._cond.notify_all()
-                    return
-            try:
-                self._merge(*plan)
-            except Exception:
-                # the index is whole and answers; the next append retries
-                _log.exception("%s: a merge failed", self._dir)
+        try:
+            while True:
                 with self._cond:
-                    self._merger = None
-                    self._cond.notify_all()
-                return
+                    plan = None if self._closed else _plan(self._runs)
+                    if plan is None:
+                        self._merger = None
+                        self._cond.notify_all()
+                        return
+                try:
+                    self._merge(*plan)
+                except Exception:
+                    # the index is whole and answers; the next append retries
+                    _log.exception("%s: a merge failed", self._dir)
+                    with self._cond:
+                        self._merger = None
+                        self._cond.notify_all()
+                    return
+        finally:
+            # a merger lives for its merges, often between two scrapes:
+            # its CPU goes to `txindex_merge` as it leaves
+            retire_thread()
 
     def _merge(self, lo: int, hi: int) -> None:
         """Runs `lo` to `hi` of the list, neighbours in age, into one."""

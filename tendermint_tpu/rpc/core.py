@@ -11,7 +11,7 @@ from __future__ import annotations
 import queue
 import time
 
-from tendermint_tpu.rpc.server import RPCError
+from tendermint_tpu.rpc.server import RPCError, phase
 from tendermint_tpu.telemetry import metrics as _metrics
 from tendermint_tpu.types import events as ev
 from tendermint_tpu.types.tx import tx_hash
@@ -116,10 +116,14 @@ def make_routes(node) -> dict:
         }
 
     def block(height: int) -> dict:
-        b = node.block_store.load_block(int(height))
+        # inside `handle`: the store's part rows and `Block.decode`, then
+        # the answer's dict, each with its own clock
+        with phase("block", "load"):
+            b = node.block_store.load_block(int(height))
         if b is None:
             raise RPCError(-32000, f"no block at height {height}")
-        return {"block": _block_json(b)}
+        with phase("block", "render"):
+            return {"block": _block_json(b)}
 
     def blockchain(min_height: int = 1, max_height: int = 0) -> dict:
         top = node.block_store.height

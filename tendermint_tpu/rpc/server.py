@@ -5,6 +5,18 @@ Reference `rpc/lib/server/handlers.go:101` (JSON-RPC over POST) and
 by name with keyword params; results must be JSON-serializable dicts.
 WebSocket event subscription lives in `rpc/websocket.py` (RFC 6455
 upgrade served off this same listener).
+
+A read's life inside the server is timed as phases, each a `Stage`
+named `rpc.<phase>` on the connection's thread (wall and CPU:
+`tendermint_rpc_phase_seconds{method,phase}` and
+`tendermint_rpc_phase_cpu_seconds_total{method,phase}`): `parse` from
+the request line read to the dispatch, `handle` the route function,
+`encode` the answer's `json.dumps`, `write` status line, headers and
+body to the return of the socket write; a route may time children
+inside its `handle` (`phase`). What no phase holds, because the server
+cannot see it: the time between a request's bytes reaching the socket
+and its connection's thread getting the interpreter to read them.
+WebSocket sessions are not timed.
 """
 
 from __future__ import annotations
@@ -15,12 +27,32 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qsl, urlparse
 
+from tendermint_tpu.telemetry import TRACER
+from tendermint_tpu.telemetry import metrics as _metrics
+from tendermint_tpu.telemetry import process as _process
+
+UNKNOWN_METHOD = "<unknown>"  # no route of the table: the label stays bounded
+
 
 class RPCError(Exception):
     def __init__(self, code: int, message: str):
         super().__init__(message)
         self.code = code
         self.message = message
+
+
+def _observe_phase(method: str, phase: str, seconds: float, cpu_seconds: float) -> None:
+    _metrics.RPC_PHASE_SECONDS.labels(method=method, phase=phase).observe(seconds)
+    _metrics.RPC_PHASE_CPU_SECONDS.labels(method=method, phase=phase).inc(cpu_seconds)
+
+
+def phase(method: str, name: str):
+    """Stopwatch around one phase of a read of `method`: the server's own
+    four, and a route's children inside its `handle` (`block`'s `load`
+    and `render`)."""
+    return TRACER.stage(
+        "rpc." + name, lambda s, cpu: _observe_phase(method, name, s, cpu)
+    )
 
 
 class _TrackingHTTPServer(ThreadingHTTPServer):
@@ -41,6 +73,17 @@ class _TrackingHTTPServer(ThreadingHTTPServer):
         with self._live_lock:
             self._live.add(request)
         super().process_request(request, client_address)
+
+    def process_request_thread(self, request, client_address):
+        # a connection's own thread, for as long as the client keeps it:
+        # named, so the profiler and the process's thread CPU series
+        # file it under `rpc` and not under `other`
+        threading.current_thread().name = "rpc-conn"
+        try:
+            super().process_request_thread(request, client_address)
+        finally:
+            # it may have served one request and be gone before any scrape
+            _process.retire_thread()
 
     def shutdown_request(self, request):
         with self._live_lock:
@@ -94,41 +137,72 @@ def _make_handler(routes: dict, event_switch=None):
         def log_message(self, fmt, *args):  # quiet
             pass
 
+        # the read being served: its `parse` stage while that is open,
+        # and the label its phases go under once the dispatch knows it
+        _parse = None
+        _method = UNKNOWN_METHOD
+
+        def parse_request(self):
+            # the request line has been read: the read's life in here begins
+            self._method = UNKNOWN_METHOD
+            self._parse = TRACER.stage("rpc.parse")
+            self._parse.__enter__()
+            return super().parse_request()
+
+        def handle_one_request(self):
+            try:
+                super().handle_one_request()
+            finally:
+                # a request that never reached a dispatch (a bad request
+                # line, a verb this server has not)
+                self._end_parse(UNKNOWN_METHOD)
+
+        def _end_parse(self, method):
+            """The dispatch: `parse` ends and the read has its label.
+            `method` None drops the stage unobserved (a WebSocket
+            upgrade is no read)."""
+            parse, self._parse = self._parse, None
+            if parse is None:
+                return
+            parse.__exit__(None, None, None)
+            if method is not None:
+                self._method = method
+                _observe_phase(method, "parse", parse.seconds, parse.cpu_seconds)
+
         def _respond(self, obj, status=200):
-            body = json.dumps(obj).encode()
-            self.send_response(status)
-            self.send_header("Content-Type", "application/json")
-            self.send_header("Content-Length", str(len(body)))
-            self.end_headers()
-            self.wfile.write(body)
+            self._end_parse(self._method)
+            with phase(self._method, "encode"):
+                body = json.dumps(obj).encode()
+            self._write(status, "application/json", body)
+
+        def _write(self, status, content_type, body):
+            with phase(self._method, "write"):
+                self.send_response(status)
+                self.send_header("Content-Type", content_type)
+                self.send_header("Content-Length", str(len(body)))
+                self.end_headers()
+                self.wfile.write(body)
+            _metrics.RPC_RESPONSE_BYTES.labels(method=self._method).inc(len(body))
 
         def _call(self, req_id, method, params):
-            import time as time_mod
-
-            from tendermint_tpu.telemetry import metrics as _metrics
-
             fn = routes.get(method)
             if fn is None:
+                self._end_parse(UNKNOWN_METHOD)
                 _metrics.RPC_REQUESTS.labels(
-                    method="<unknown>", result="error"
+                    method=UNKNOWN_METHOD, result="error"
                 ).inc()
                 return {
                     "jsonrpc": "2.0",
                     "id": req_id,
                     "error": {"code": -32601, "message": f"unknown method {method}"},
                 }
-            t0 = time_mod.perf_counter()
+            self._end_parse(method)
             try:
-                result = fn(**params) if isinstance(params, dict) else fn(*params)
-                _metrics.RPC_SECONDS.labels(method=method).observe(
-                    time_mod.perf_counter() - t0
-                )
+                with phase(method, "handle"):
+                    result = fn(**params) if isinstance(params, dict) else fn(*params)
                 _metrics.RPC_REQUESTS.labels(method=method, result="ok").inc()
                 return {"jsonrpc": "2.0", "id": req_id, "result": result}
             except RPCError as e:
-                _metrics.RPC_SECONDS.labels(method=method).observe(
-                    time_mod.perf_counter() - t0
-                )
                 _metrics.RPC_REQUESTS.labels(method=method, result="error").inc()
                 return {
                     "jsonrpc": "2.0",
@@ -187,6 +261,7 @@ def _make_handler(routes: dict, event_switch=None):
                 and event_switch is not None
                 and "upgrade" in self.headers.get("Connection", "").lower()
             ):
+                self._end_parse(None)
                 self._upgrade_websocket()
                 return
             if method == "metrics":
@@ -217,21 +292,20 @@ def _make_handler(routes: dict, event_switch=None):
 
         def _serve_metrics(self):
             from tendermint_tpu.telemetry import REGISTRY
-            from tendermint_tpu.telemetry import metrics as _metrics
 
-            body = REGISTRY.prometheus_text().encode()
+            self._end_parse("metrics")
+            with phase("metrics", "handle"):
+                text = REGISTRY.prometheus_text()
+            with phase("metrics", "encode"):
+                body = text.encode()
             _metrics.RPC_REQUESTS.labels(method="metrics", result="ok").inc()
-            self.send_response(200)
-            self.send_header("Content-Type", "text/plain; version=0.0.4; charset=utf-8")
-            self.send_header("Content-Length", str(len(body)))
-            self.end_headers()
-            self.wfile.write(body)
+            self._write(200, "text/plain; version=0.0.4; charset=utf-8", body)
 
         def _serve_health(self):
-            from tendermint_tpu.telemetry import metrics as _metrics
-
+            self._end_parse("health")
             try:
-                body = routes["health"]()
+                with phase("health", "handle"):
+                    body = routes["health"]()
             except Exception as e:
                 _metrics.RPC_REQUESTS.labels(method="health", result="error").inc()
                 self._respond({"status": "error", "error": str(e)}, status=500)

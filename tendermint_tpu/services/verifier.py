@@ -397,7 +397,7 @@ class TableBatchVerifier(DeviceBatchVerifier):
                 _metrics.TABLE_CACHE.labels(event="hit").inc()
                 return hit[1], hit[2]
         _metrics.TABLE_CACHE.labels(event="miss").inc()
-        tables, ok = self._build_tables(pubkeys)
+        tables, ok = _timed_build(self, pubkeys)
         with self._cache_lock:
             self._tables[key] = (tuple(pubkeys), tables, ok)
             while len(self._tables) > self._cache_size:
@@ -417,7 +417,7 @@ class TableBatchVerifier(DeviceBatchVerifier):
                 device_fail_point("tables")
                 built = self._incremental_build(pubkeys)
                 if built is not None:
-                    _metrics.TABLE_CACHE.labels(event="incremental").inc()
+                    _built_as("incremental")
                 else:
                     from tendermint_tpu.ops.ed25519_tables import build_key_tables
 
@@ -443,7 +443,7 @@ class TableBatchVerifier(DeviceBatchVerifier):
 
             from tendermint_tpu.ops.ed25519_tables import host_build_key_tables
 
-            _metrics.TABLE_CACHE.labels(event="host_build").inc()
+            _built_as("host_build")
             t, ok = host_build_key_tables(list(pubkeys))
             return jnp.asarray(t), ok
         raise TableBuildError(
@@ -501,7 +501,7 @@ class TableBatchVerifier(DeviceBatchVerifier):
             return
 
         threading.Thread(
-            target=lambda: self._tables_for(pubs), daemon=True
+            target=lambda: self._tables_for(pubs), daemon=True, name=_PREBUILD
         ).start()
 
     @staticmethod
@@ -1102,3 +1102,43 @@ def default_verifier() -> BatchVerifier:
 def set_default_verifier(v: BatchVerifier) -> None:
     global _DEFAULT
     _DEFAULT = v
+
+
+# -- a table build's clock ------------------------------------------------------
+#
+# Down here, and the lines above changed one for one: line numbers above
+# the launches' callers are in the compile-cache key of every executable
+# that holds a Pallas kernel (PERF.md section 6).
+
+import threading  # noqa: E402
+
+from tendermint_tpu.telemetry import TRACER  # noqa: E402
+
+_PREBUILD = "table-prebuild"  # the thread `prebuild` starts
+_BUILD = threading.local()  # .kind: how the build this thread is in went
+
+
+def _built_as(event: str) -> None:
+    """Count a build that went another way than whole on the device
+    (`incremental`, `host_build`), and tell `_timed_build` on this
+    thread."""
+    _metrics.TABLE_CACHE.labels(event=event).inc()
+    _BUILD.kind = event
+
+
+def _observe_build(seconds: float, _cpu_seconds: float) -> None:
+    prebuild = threading.current_thread().name == _PREBUILD
+    _metrics.TABLE_BUILD_SECONDS.labels(
+        kind="prebuild" if prebuild else _BUILD.kind
+    ).observe(seconds)
+
+
+def _timed_build(verifier: TableBatchVerifier, pubkeys):
+    """`_build_tables` under the `tables.build` stage:
+    `tendermint_verify_table_build_seconds{kind}`, and a stretch in the
+    profiler's host plane, so a launch that has to build its table no
+    longer hides the build in its `host_prep_s` and a prebuild is timed
+    at all."""
+    _BUILD.kind = "full"
+    with TRACER.stage("tables.build", _observe_build):
+        return verifier._build_tables(pubkeys)

@@ -161,9 +161,10 @@ def apply_block(
 
     `stage(name)` gives a context manager held around each stage of the
     apply: `validate`, `exec` (the ABCI calls and the app commit with
-    the mempool update) and `state_save` (everything persisted). The
-    fast-sync reactor passes its stopwatch; without one (consensus)
-    nothing is timed."""
+    the mempool update) and `state_save` (everything persisted), and
+    inside `state_save` the tx indexer holds `index_rows` around
+    building the block's rows. The fast-sync reactor passes its
+    stopwatch; without one (consensus) nothing is timed."""
     stage = stage or _untimed
     with stage("validate"):
         validate_block(
@@ -184,7 +185,7 @@ def apply_block(
 
         fail_point()  # responses saved, before state advance + app commit
         if tx_indexer is not None:
-            tx_indexer.add_batch(block, abci_responses)
+            tx_indexer.add_batch(block, abci_responses, stage=stage)
         state.set_block_and_validators(
             block.header, part_set_header, abci_responses
         )

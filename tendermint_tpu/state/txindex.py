@@ -27,6 +27,7 @@ import os
 import pathlib
 import sqlite3
 import threading
+from contextlib import nullcontext
 from dataclasses import dataclass
 
 from tendermint_tpu.abci.types import Result
@@ -68,8 +69,14 @@ class TxResult:
         )
 
 
+_UNTIMED = nullcontext()
+
+
 class TxIndexer:
-    def add_batch(self, block, abci_responses) -> None:
+    def add_batch(self, block, abci_responses, stage=None) -> None:
+        """Index a block's txs. `stage`, when given, is `apply_block`'s
+        stopwatch: `stage("index_rows")` is held around building the
+        rows, apart from the write that follows."""
         raise NotImplementedError
 
     def get(self, tx_hash: bytes) -> TxResult | None:
@@ -82,23 +89,25 @@ class TxIndexer:
 class NullTxIndexer(TxIndexer):
     """Indexing disabled (reference `null.TxIndex`)."""
 
-    def add_batch(self, block, abci_responses) -> None:
+    def add_batch(self, block, abci_responses, stage=None) -> None:
         pass
 
     def get(self, tx_hash: bytes) -> TxResult | None:
         return None
 
 
-def _rows(block, abci_responses) -> dict[bytes, bytes]:
+def _rows(block, abci_responses, stage=None) -> dict[bytes, bytes]:
     """A block's index rows by tx hash; of a tx that is in the block
-    twice the later one stays."""
+    twice the later one stays. Built under `stage("index_rows")` where
+    the caller has a stopwatch."""
     height = block.header.height
     rows = {}
-    for i, tx in enumerate(block.data.txs):
-        tx = bytes(tx)
-        rows[tx_hash(tx)] = TxResult(
-            height=height, index=i, tx=tx, result=abci_responses.deliver_tx[i]
-        ).to_json()
+    with stage("index_rows") if stage else _UNTIMED:
+        for i, tx in enumerate(block.data.txs):
+            tx = bytes(tx)
+            rows[tx_hash(tx)] = TxResult(
+                height=height, index=i, tx=tx, result=abci_responses.deliver_tx[i]
+            ).to_json()
     return rows
 
 
@@ -106,9 +115,9 @@ class KVTxIndexer(TxIndexer):
     def __init__(self, db: DB) -> None:
         self._db = db
 
-    def add_batch(self, block, abci_responses) -> None:
+    def add_batch(self, block, abci_responses, stage=None) -> None:
         batch = self._db.batch()
-        for key, row in _rows(block, abci_responses).items():
+        for key, row in _rows(block, abci_responses, stage).items():
             batch.set(b"tx:" + key, row)
         batch.write()
 
@@ -128,8 +137,8 @@ class RunTxIndexer(TxIndexer):
         old = os.path.join(db_dir, "txindex.db")
         self._old = _OldIndexFile(old) if os.path.exists(old) else None
 
-    def add_batch(self, block, abci_responses) -> None:
-        self._log.append(block.header.height, _rows(block, abci_responses))
+    def add_batch(self, block, abci_responses, stage=None) -> None:
+        self._log.append(block.header.height, _rows(block, abci_responses, stage))
 
     def get(self, tx_hash: bytes) -> TxResult | None:
         raw = self._log.get(tx_hash)

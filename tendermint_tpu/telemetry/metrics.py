@@ -20,6 +20,7 @@ from __future__ import annotations
 from tendermint_tpu.telemetry.registry import (
     LATENCY_BUCKETS,
     SIZE_BUCKETS,
+    CallbackCounter,
     Counter,
     Gauge,
     Histogram,
@@ -157,6 +158,20 @@ TABLE_CACHE = Counter(
     "Valset comb-table cache outcomes (hit/miss/incremental/host_fallback)",
     labelnames=("event",),
 )
+TABLE_BUILD_KINDS = ("full", "incremental", "host_build", "prebuild")
+TABLE_BUILD_SECONDS = Histogram(
+    "tendermint_verify_table_build_seconds",
+    "One build of a validator set's comb table (a table-cache miss, "
+    "the `tables.build` stage of services/verifier.py), by kind: full "
+    "(every column on the device), incremental (the new keys' columns "
+    "joined to a cached set's), host_build (on the host behind an open "
+    "breaker), prebuild (any of those on the thread state/execution.py "
+    "starts when a block changes the set, beside the launches)",
+    labelnames=("kind",),
+    buckets=LATENCY_BUCKETS,
+)
+for _kind in TABLE_BUILD_KINDS:
+    TABLE_BUILD_SECONDS.labels(kind=_kind)
 XLA_CACHE_ENABLED = Gauge(
     "tendermint_xla_persistent_cache_enabled",
     "1 when the persistent XLA executable cache is active",
@@ -500,6 +515,24 @@ from tendermint_tpu.telemetry import process as _process  # noqa: E402
 PROCESS_RSS.set_function(_process.rss_bytes)
 PROCESS_FDS.set_function(_process.open_fds)
 PROCESS_THREADS.set_function(_process.thread_count)
+PROCESS_CPU_SECONDS = CallbackCounter(
+    "tendermint_process_cpu_seconds_total",
+    "CPU time of this process, every thread of it (time.process_time, "
+    "read at scrape time): a rise of one second a second is one core; "
+    "more only by C code that let the interpreter lock go",
+    _process.cpu_seconds,
+)
+PROCESS_THREAD_CPU_SECONDS = CallbackCounter(
+    "tendermint_process_thread_cpu_seconds",
+    "CPU time of the interpreter's threads (each thread's own CPU "
+    "clock, read at scrape time), summed by the contention profiler's "
+    "thread classes (telemetry/profiler.py classify_thread, by thread "
+    "name). A thread that exited keeps the last value read for it: what "
+    "it ran between its last scrape and its exit is in the process's "
+    "total alone",
+    _process.thread_cpu_seconds,
+    labelnames=("thread",),
+)
 
 # -- fast sync (blockchain/reactor.py) ----------------------------------------
 #
@@ -513,6 +546,8 @@ FASTSYNC_STAGES = (
     "decode", "part_set", "verify_submit", "verify_wait", "store",
     "validate", "exec", "state_save", "starved",
 )
+# stages inside another stage: their time is their parent's too
+FASTSYNC_CHILD_STAGES = ("index_rows",)
 FASTSYNC_CUTS = ("full", "pool_gap", "boundary")
 
 FASTSYNC_STAGE_SECONDS = Histogram(
@@ -522,9 +557,20 @@ FASTSYNC_STAGE_SECONDS = Histogram(
     "verify_submit (sign-bytes, lanes, launch submit), verify_wait (the "
     "verdict join the pipeline failed to hide, plus the tally), store "
     "(save_block), validate / exec / state_save (apply_block), starved "
-    "(the sync loop's idle tick: nothing to prepare, nothing in flight)",
+    "(the sync loop's idle tick: nothing to prepare, nothing in flight); "
+    "and inside state_save, index_rows (building the block's tx index "
+    "rows, before the run log's append)",
     labelnames=("stage",),
     buckets=LATENCY_BUCKETS,
+)
+FASTSYNC_STAGE_CPU_SECONDS = Counter(
+    "tendermint_fastsync_stage_cpu_seconds_total",
+    "CPU time of the thread that ran each fast-sync stage, over the "
+    "same stretches as tendermint_fastsync_stage_seconds (one Stage, "
+    "two clocks). A stage's seconds less its CPU seconds is what its "
+    "thread slept (a disk, the device, a socket) or waited for the "
+    "interpreter lock",
+    labelnames=("stage",),
 )
 FASTSYNC_BLOCKS_APPLIED = Counter(
     "tendermint_fastsync_blocks_applied_total",
@@ -538,8 +584,9 @@ FASTSYNC_WINDOWS = Counter(
     labelnames=("cut",),
 )
 
-for _stage in FASTSYNC_STAGES:
+for _stage in FASTSYNC_STAGES + FASTSYNC_CHILD_STAGES:
     FASTSYNC_STAGE_SECONDS.labels(stage=_stage)
+    FASTSYNC_STAGE_CPU_SECONDS.labels(stage=_stage).inc(0)
 for _cut in FASTSYNC_CUTS:
     FASTSYNC_WINDOWS.labels(cut=_cut).inc(0)
 
@@ -595,6 +642,23 @@ DB_COMMITS = Counter(
     "the tx index's run log (db/runlog.py) a block's one appended record. "
     "Over tendermint_fastsync_blocks_applied_total a block reads 4: "
     "blockstore 1, state 2 (ABCI responses, state), txindex 1",
+    labelnames=("db",),
+)
+
+DB_COMMIT_SECONDS = Histogram(
+    "tendermint_db_commit_seconds",
+    "One durable write, timed where it happens (the `db.commit` stage): "
+    "exactly what tendermint_db_commits_total counts, so _count equals "
+    "it: on a SQLite file the commit() of the one transaction, on the tx "
+    "index's run log the record's write and fsync",
+    labelnames=("db",),
+    buckets=LATENCY_BUCKETS,
+)
+DB_COMMIT_CPU_SECONDS = Counter(
+    "tendermint_db_commit_cpu_seconds_total",
+    "CPU time of the committing thread inside those durable writes (a "
+    "commit runs some C as well as sleeping on the disk): "
+    "tendermint_db_commit_seconds_sum less this is the sleep",
     labelnames=("db",),
 )
 
@@ -997,11 +1061,31 @@ RPC_REQUESTS = Counter(
     "RPC calls served, by method and outcome",
     labelnames=("method", "result"),
 )
-RPC_SECONDS = Histogram(
-    "tendermint_rpc_request_seconds",
-    "RPC handler latency by method (dispatch to result, excl. socket I/O)",
-    labelnames=("method",),
+RPC_PHASE_SECONDS = Histogram(
+    "tendermint_rpc_phase_seconds",
+    "A read's life inside the server, by phase (each a `rpc.<phase>` "
+    "stage on the connection's thread): parse (from the request line "
+    "read to the dispatch: headers, body, JSON or query), handle (the "
+    "route function), encode (json.dumps of the answer), write (status "
+    "line, headers and body, to the return of the socket write); and "
+    "inside block's handle, load (the store's part rows and "
+    "Block.decode) and render (the answer's dict). The wait between a "
+    "request's bytes reaching the socket and its thread getting the "
+    "interpreter to read them is before parse, and in no phase",
+    labelnames=("method", "phase"),
     buckets=LATENCY_BUCKETS,
+)
+RPC_PHASE_CPU_SECONDS = Counter(
+    "tendermint_rpc_phase_cpu_seconds_total",
+    "CPU time of the connection's thread inside each phase of "
+    "tendermint_rpc_phase_seconds: a phase's seconds less this is what "
+    "the read waited, for the interpreter lock, a store or the socket",
+    labelnames=("method", "phase"),
+)
+RPC_RESPONSE_BYTES = Counter(
+    "tendermint_rpc_response_bytes_total",
+    "Bytes of answer bodies written, by method",
+    labelnames=("method",),
 )
 
 

@@ -12,6 +12,11 @@ Exported through the catalog (`telemetry/metrics.py`):
 * ``tendermint_process_rss_bytes`` / ``_open_fds`` / ``_threads`` —
   callback gauges read at scrape time only (`/proc/self` on Linux,
   `resource.getrusage` fallback elsewhere); idle cost is zero.
+* ``tendermint_process_cpu_seconds_total`` and
+  ``tendermint_process_thread_cpu_seconds{thread}`` — who has the
+  interpreter: the process's CPU clock and every live thread's, summed
+  by the contention profiler's thread classes; read at scrape time
+  only, nothing on any hot path (`cpu_seconds`, `thread_cpu_seconds`).
 * ``tendermint_process_gc_pause_seconds`` +
   ``tendermint_process_gc_collections_total{gen}`` — a `gc.callbacks`
   hook stamps `perf_counter` across each collection. CPython invokes
@@ -33,6 +38,7 @@ import gc
 import os
 import threading
 import time
+import weakref
 from collections import deque
 
 _PAGE_SIZE = 4096
@@ -68,6 +74,74 @@ def open_fds() -> float:
 
 def thread_count() -> float:
     return float(threading.active_count())
+
+
+# -- who has the interpreter ----------------------------------------------------
+
+_cpu_lock = threading.Lock()
+# live threads seen at the last scrape: thread -> (class, its CPU clock then)
+_cpu_seen: "dict[threading.Thread, tuple[str, float]]" = {}
+# class -> the last readings of its threads that have exited since
+_cpu_exited: dict[str, float] = {}
+# threads that read their own clock a last time (`retire_thread`): counted
+_cpu_retired: "weakref.WeakSet[threading.Thread]" = weakref.WeakSet()
+
+
+def cpu_seconds() -> dict:
+    """The process's CPU clock: every thread of it, the interpreter's
+    and the libraries' own (XLA's, the profiler's)."""
+    return {(): time.process_time()}
+
+
+def thread_cpu_seconds() -> dict:
+    """CPU seconds of the interpreter's threads by class
+    (`profiler.classify_thread` of the thread's NAME: a thread keeps the
+    class it was first seen under, so no class's sum ever falls), every
+    class of the vocabulary present. A thread that has exited keeps the
+    last value read for it. What that loses: the CPU a thread took
+    between its last scrape and its exit, and all of a thread that
+    lived between two scrapes, unless it reads its own clock as it
+    leaves (`retire_thread`: the RPC server's connection threads and
+    the tx index's merger do);
+    `tendermint_process_cpu_seconds_total` holds those, and the threads
+    no `threading.enumerate()` lists (a library's own), in no class."""
+    from tendermint_tpu.telemetry import profiler as _profiler
+
+    with _cpu_lock:
+        for thread in threading.enumerate():
+            cpu = _profiler._cpu_clock(thread)
+            if cpu is None or thread in _cpu_retired:
+                continue
+            seen = _cpu_seen.get(thread)
+            sub = seen[0] if seen else _profiler.classify_thread(thread.name)
+            _cpu_seen[thread] = (sub, cpu)
+        for thread in [t for t in _cpu_seen if not t.is_alive()]:
+            sub, cpu = _cpu_seen.pop(thread)
+            _cpu_exited[sub] = _cpu_exited.get(sub, 0.0) + cpu
+        totals = {sub: _cpu_exited.get(sub, 0.0) for sub in _profiler.SUBSYSTEMS}
+        for sub, cpu in _cpu_seen.values():
+            totals[sub] += cpu
+    return {(sub,): total for sub, total in totals.items()}
+
+
+def retire_thread() -> None:
+    """The calling thread's CPU clock read a last time, by a thread about
+    to exit whose kind comes and goes between scrapes: an RPC connection's
+    thread lives as long as its connection (one request, for a client
+    that keeps none open), the tx index's merger as long as merges are
+    due. Its whole life is then in its class's sum, and not only what a
+    scrape happened to see of it."""
+    from tendermint_tpu.telemetry import profiler as _profiler
+
+    thread = threading.current_thread()
+    cpu = _profiler._cpu_clock(thread)
+    if cpu is None:
+        return
+    with _cpu_lock:
+        seen = _cpu_seen.pop(thread, None)
+        sub = seen[0] if seen else _profiler.classify_thread(thread.name)
+        _cpu_exited[sub] = _cpu_exited.get(sub, 0.0) + cpu
+        _cpu_retired.add(thread)
 
 
 # -- GC pause timing ----------------------------------------------------------

@@ -65,6 +65,8 @@ SUBSYSTEMS = (
     "p2p_recv",
     "p2p_send",
     "statesync",
+    "fastsync",
+    "txindex_merge",
     "rpc",
     "abci",
     "main",
@@ -81,6 +83,7 @@ _NAME_MAP: tuple[tuple[str, str], ...] = (
     ("verify-coalescer", "coalescer"),
     ("dispatch-", "dispatch"),
     ("warm-build", "dispatch"),
+    ("table-prebuild", "dispatch"),  # the next validator set's table
     ("mconn-recv", "p2p_recv"),
     ("mconn-", "p2p_send"),  # send + ping loops
     ("p2p-", "p2p_recv"),  # accept / handshake (inbound edge)
@@ -88,8 +91,9 @@ _NAME_MAP: tuple[tuple[str, str], ...] = (
     ("persistent-dial", "p2p_send"),
     ("evidence-gossip", "p2p_send"),
     ("statesync", "statesync"),
-    ("fastsync", "statesync"),
-    ("rpc-", "rpc"),
+    ("fastsync", "fastsync"),  # the one thread that stores and applies
+    ("txindex-merge", "txindex_merge"),  # db/runlog.py, off the sync thread
+    ("rpc-", "rpc"),  # rpc-http (accept), rpc-conn (a connection's thread)
     ("abci-", "abci"),
     ("MainThread", "main"),
 )
@@ -107,7 +111,7 @@ _MODULE_MAP: tuple[tuple[str, str], ...] = (
     ("/parallel/", "dispatch"),
     ("/consensus/", "consensus"),
     ("/statesync/", "statesync"),
-    ("/blockchain/", "statesync"),
+    ("/blockchain/", "fastsync"),
     ("/rpc/", "rpc"),
     ("/abci/", "abci"),
     ("/p2p/", "p2p_recv"),

@@ -187,6 +187,41 @@ class Counter(_Metric):
         return self._child0().value
 
 
+class CallbackCounter(_Metric):
+    """A counter family whose samples ONE function gives at collect
+    time: totals something else keeps (the kernel's CPU clocks), read
+    when scraped and at no other time. `fn()` returns `{label values:
+    total}` (the empty tuple for a family without labels); a value
+    never reads lower than it did, and a call that raises keeps the
+    last readings (a scrape must never fail on a live view)."""
+
+    type_name = "counter"
+    CHILD = _CounterChild
+
+    def __init__(
+        self,
+        name: str,
+        help: str,
+        fn: Callable[[], dict],
+        labelnames: Sequence[str] = (),
+        registry: "Registry | None" = None,
+    ) -> None:
+        super().__init__(name, help, labelnames, registry)
+        self._fn = fn
+        self._read: dict[tuple[str, ...], float] = {}
+
+    def samples(self) -> list[tuple[tuple[str, ...], object]]:
+        with self._lock:
+            try:
+                now = self._fn()
+            except Exception:
+                now = {}
+            for values, total in now.items():
+                if total > self._read.get(values, -1.0):
+                    self._read[values] = float(total)
+            return list(self._read.items())
+
+
 class _GaugeChild:
     def __init__(self, lock: threading.Lock) -> None:
         self._lock = lock

@@ -675,9 +675,18 @@ class Nemesis:
         (links inherit the live partition state)."""
         node = self.nodes[i]
         node.restart()
+        me = node.switch.node_info.node_id
         for j, other in enumerate(self.nodes):
             if j == i or not other.running:
                 continue
+            # a survivor drops the crashed peer on its own threads, when
+            # they meet the closed endpoint: under load that can come
+            # after this call, and the new link would be a "duplicate peer"
+            gone_by = time.monotonic() + 10
+            while time.monotonic() < gone_by and any(
+                p.id == me for p in other.switch.peers()
+            ):
+                time.sleep(0.005)
             key = (min(i, j), max(i, j))
             self._links.pop(key, None)  # old endpoints died with the crash
             self._connect(*key)

@@ -24,6 +24,35 @@ from tendermint_tpu.types import (
 CHAIN_ID = "test-chain"
 
 
+def _thread_clock_step() -> float:
+    """The smallest rise of a thread's CPU clock seen while spinning
+    20 ms (`get_clock_info("thread_time").resolution` says 1 ns where the
+    kernel keeps the clock in scheduler ticks of 10 ms, as a TPU host
+    does); a clock that did not move in that time steps by more."""
+    seen, end = set(), time.perf_counter() + 0.02
+    while time.perf_counter() < end:
+        seen.add(time.thread_time_ns())
+    readings = sorted(seen)
+    if len(readings) < 2:
+        return 0.02
+    return min(b - a for a, b in zip(readings, readings[1:])) * 1e-9
+
+
+THREAD_CLOCK_STEP_S = _thread_clock_step()
+# a stage's CPU is sure to have risen only where its clock steps by less
+# than the stage is long: assert `> 0` under this, `>= 0` otherwise
+THREAD_CLOCK_IS_FINE = THREAD_CLOCK_STEP_S < 1e-4
+
+
+def cpu_slack(stretches: int = 1) -> float:
+    """Seconds by which the CPU of `stretches` `Stage`s may read over
+    their wall: a step of the thread's clock each, and the reading a
+    stage may share with the boundary before it (`CPU_SHARE_NS`)."""
+    from tendermint_tpu.telemetry.tracer import CPU_SHARE_NS
+
+    return stretches * (THREAD_CLOCK_STEP_S + CPU_SHARE_NS * 1e-9)
+
+
 def det_priv_keys(n: int) -> list[PrivKey]:
     return [PrivKey(i.to_bytes(32, "little")) for i in range(1, n + 1)]
 

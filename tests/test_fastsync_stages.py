@@ -1,6 +1,8 @@
 """Fast-sync's stage clock: the
 `tendermint_fastsync_stage_seconds{stage}` histogram with its two
-counters, and the one `fastsync.window` span a window. (The stopwatch
+counters, the CPU counter beside it
+(`tendermint_fastsync_stage_cpu_seconds_total{stage}`: one `Stage`, two
+clocks), and the one `fastsync.window` span a window. (The stopwatch
 itself, `TRACER.stage`, is tested with the tracer in test_telemetry.py.)
 
 All on the CPU with four validators and the host library passed as the
@@ -25,15 +27,17 @@ from tendermint_tpu.state import make_genesis_state
 from tendermint_tpu.telemetry import REGISTRY, TRACER
 from tendermint_tpu.telemetry.launchlog import LAUNCHLOG
 from tendermint_tpu.telemetry.metrics import (
+    FASTSYNC_CHILD_STAGES,
     FASTSYNC_CUTS,
     FASTSYNC_STAGES,
     SPAN_CATALOG,
 )
 
-from tests.helpers import CHAIN_ID, ChainSim
+from tests.helpers import CHAIN_ID, THREAD_CLOCK_IS_FINE, ChainSim, cpu_slack
 from tests.test_fastsync import _pipelined_reactor, _serving_node, wait_until
 
 STAGE_SECONDS = "tendermint_fastsync_stage_seconds"
+STAGE_CPU_SECONDS = "tendermint_fastsync_stage_cpu_seconds_total"
 APPLY_STAGES = ("validate", "exec", "state_save")
 
 
@@ -56,6 +60,10 @@ class Readings:
         out.update(
             (f"sum.{s['labels']['stage']}", s["sum"])
             for s in series[STAGE_SECONDS]["series"]
+        )
+        out.update(
+            (f"cpu.{s['labels']['stage']}", s["value"])
+            for s in series[STAGE_CPU_SECONDS]["series"]
         )
         out.update(
             (f"cut.{s['labels']['cut']}", s["value"])
@@ -97,8 +105,10 @@ def chain(n_blocks: int, app=None) -> ChainSim:
 class TestCatalog:
     def test_every_stage_and_cut_is_on_metrics_before_any_sync(self):
         text = REGISTRY.prometheus_text()
-        for stage in FASTSYNC_STAGES:
+        assert FASTSYNC_CHILD_STAGES == ("index_rows",)
+        for stage in FASTSYNC_STAGES + FASTSYNC_CHILD_STAGES:
             assert f'{STAGE_SECONDS}_count{{stage="{stage}"}}' in text
+            assert f'{STAGE_CPU_SECONDS}{{stage="{stage}"}}' in text
         for cut in FASTSYNC_CUTS:
             assert f'tendermint_fastsync_windows_total{{cut="{cut}"}}' in text
         assert "tendermint_fastsync_blocks_applied_total" in text
@@ -179,6 +189,7 @@ class TestApplyBlock:
         chain(2)  # ChainSim applies as consensus does: no stage passed
         rise = before.rise()
         assert all(rise["count." + s] == 0 for s in FASTSYNC_STAGES)
+        assert all(rise["cpu." + s] == 0 for s in FASTSYNC_STAGES + FASTSYNC_CHILD_STAGES)
         assert rise["blocks"] == 0 and before.windows() == []
 
     def test_the_stages_bracket_the_apply_in_order(self):
@@ -198,6 +209,48 @@ class TestApplyBlock:
         apply_block(sim.state, block, parts.header, sim.conns.consensus, stage=stage)
         assert seen == ["validate", "exec", "state_save", "exec", "state_save"]
         assert sim.state.last_block_height == 2
+
+    def test_the_index_rows_are_a_stage_inside_state_save(self):
+        """Building a block's tx index rows is timed apart from the
+        write that follows it, inside the first `state_save`."""
+        from contextlib import contextmanager
+
+        from tendermint_tpu.state import apply_block
+        from tendermint_tpu.state.txindex import KVTxIndexer
+        from tendermint_tpu.types.tx import tx_hash
+
+        sim = chain(1)
+        block, parts = sim.make_next_block(txs=[b"a=1", b"b=2"])
+        seen, indexer = [], KVTxIndexer(MemDB())
+
+        @contextmanager
+        def stage(name):
+            seen.append("+" + name)
+            yield
+            seen.append("-" + name)
+
+        apply_block(
+            sim.state, block, parts.header, sim.conns.consensus,
+            tx_indexer=indexer, stage=stage,
+        )
+        assert seen[4:8] == ["+state_save", "+index_rows", "-index_rows", "-state_save"]
+        assert [n for n in seen if n.startswith("+")] == [
+            "+validate", "+exec", "+state_save", "+index_rows", "+exec", "+state_save"
+        ]
+        assert indexer.get(tx_hash(b"b=2")).index == 1
+        # without a stopwatch (consensus) the rows are built all the same
+        indexer.add_batch(*_block_of(sim, [b"c=3"]))
+        assert indexer.get(tx_hash(b"c=3")).height == 9
+
+
+def _block_of(sim, txs):
+    """A block and its responses as `add_batch` reads them."""
+    from types import SimpleNamespace
+
+    from tendermint_tpu.abci.types import Result
+
+    block = SimpleNamespace(header=SimpleNamespace(height=9), data=SimpleNamespace(txs=txs))
+    return block, SimpleNamespace(deliver_tx=[Result(0, b"", "")] * len(txs))
 
 
 class TestOverTheWire:
@@ -233,6 +286,15 @@ class TestOverTheWire:
         for stage in FASTSYNC_STAGES:
             assert rise["count." + stage] > 0, stage
             assert rise["sum." + stage] > 0, stage
+            # the thread's CPU clock beside the wall's, over the same
+            # stretches: it rose (where the clock steps finely enough to
+            # tell), and by no more than the wall did, give or take a
+            # step of that clock a stretch
+            slack = cpu_slack(rise["count." + stage])
+            assert 0 <= rise["cpu." + stage] <= rise["sum." + stage] + slack, stage
+            assert rise["cpu." + stage] > 0 or not THREAD_CLOCK_IS_FINE, stage
+        # the idle tick sleeps: next to no CPU in it
+        assert rise["cpu.starved"] < 0.5 * rise["sum.starved"]
         assert rise["count.decode"] == 24  # one a block_response
         assert rise["blocks"] == fresh_store.height == reactor.blocks_synced
         assert sum(w["height_hi"] - w["height_lo"] + 1 for w in spans) == fresh_store.height

@@ -75,8 +75,13 @@ class TestClassification:
             "persistent-dial-x": "p2p_send",
             "evidence-gossip": "p2p_send",
             "statesync": "statesync",
-            "fastsync": "statesync",
+            # the thread that stores and applies, told from state sync's
+            "fastsync": "fastsync",
+            "txindex-merge": "txindex_merge",
+            "warm-build-kernel": "dispatch",
+            "table-prebuild": "dispatch",
             "rpc-http": "rpc",
+            "rpc-conn": "rpc",
             "abci-accept": "abci",
             "abci-conn": "abci",
             "MainThread": "main",

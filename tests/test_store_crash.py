@@ -32,8 +32,21 @@ from tendermint_tpu.state import apply_block, load_state, make_genesis_state
 from tendermint_tpu.state.txindex import RunTxIndexer
 from tendermint_tpu.types.tx import tx_hash
 
-from tests.helpers import ChainSim
+from tests.helpers import THREAD_CLOCK_IS_FINE, ChainSim, cpu_slack
 from tests.test_db_batch import commits
+
+def commits_timed(db_name: str) -> tuple[float, float, float]:
+    """`tendermint_db_commit_seconds{db}`'s count and sum and the CPU
+    counter beside it, as they stand."""
+    from tendermint_tpu.telemetry import REGISTRY
+
+    hist = [
+        s for s in REGISTRY.to_dict()["tendermint_db_commit_seconds"]["series"]
+        if s["labels"]["db"] == db_name
+    ]
+    cpu = REGISTRY.counter_value("tendermint_db_commit_cpu_seconds_total", db=db_name)
+    return (hist[0]["count"], hist[0]["sum"], cpu) if hist else (0, 0.0, cpu)
+
 
 _CHILD = textwrap.dedent(
     """
@@ -45,7 +58,7 @@ _CHILD = textwrap.dedent(
     from tendermint_tpu.db.kv import SQLiteDB
     from tendermint_tpu.services.verifier import HostBatchVerifier
     from tendermint_tpu.state import apply_block
-    from tests.helpers import ChainSim
+    from tests.helpers import THREAD_CLOCK_IS_FINE, ChainSim, cpu_slack
 
     sim = ChainSim(n_vals=4, db=SQLiteDB(home + "/state.db"))
     store = BlockStore(SQLiteDB(home + "/blockstore.db"))
@@ -207,11 +220,22 @@ class TestABlocksTransactions:
                 reactor.pool._blocks[h] = (b, "srv")
             names = (*dbs, "txindex")
             before = {name: commits(name) for name in names}
+            timed_before = {name: commits_timed(name) for name in names}
             reactor._try_sync()
             rise = {name: commits(name) - before[name] for name in names}
             n = self.N_BLOCKS
             assert store.height == n == state.last_block_height == reactor.blocks_synced
             assert (rise["blockstore"], rise["state"], rise["txindex"]) == (n, 2 * n, n)
+            # each is timed where it happens, and only it: the histogram's
+            # count is the counter, its CPU no more than its wall (give
+            # or take a step of the thread's clock a commit)
+            for name in names:
+                count, seconds, cpu = (
+                    now - was for now, was in zip(commits_timed(name), timed_before[name])
+                )
+                assert count == rise[name], name
+                assert 0 <= cpu <= seconds + cpu_slack(count), name
+                assert cpu > 0 or not THREAD_CLOCK_IS_FINE, name
             assert indexer.get(tx_hash(b"k7-2=v")).height == 8
         finally:
             indexer.close()

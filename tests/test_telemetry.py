@@ -25,7 +25,9 @@ from tendermint_tpu.telemetry.registry import (
     Histogram,
     Registry,
 )
-from tendermint_tpu.telemetry.tracer import Tracer
+from tendermint_tpu.telemetry.tracer import CPU_SHARE_NS, Tracer
+
+from tests.helpers import THREAD_CLOCK_STEP_S, cpu_slack
 
 
 class TestCountersAndGauges:
@@ -246,19 +248,154 @@ class TestStage:
     def test_the_duration_is_the_monotonic_clocks_and_goes_to_the_sink(self):
         tracer = Tracer(capacity=4)
         got = []
-        with tracer.stage("fastsync.store", got.append) as st:
+        with tracer.stage("fastsync.store", lambda *clocks: got.append(clocks)) as st:
             time.sleep(0.01)
         assert 0.009 < st.seconds < 0.5
-        assert got == [st.seconds] and st.name == "fastsync.store"
+        # the sink's two values: the wall's seconds, then the thread's CPU seconds
+        assert got == [(st.seconds, st.cpu_seconds)] and st.name == "fastsync.store"
         # a stage is no span: the ring stays as it was
         assert len(tracer) == 0
 
     def test_the_sink_hears_of_a_stage_that_raised(self):
         got = []
         with pytest.raises(KeyError):
-            with TRACER.stage("s", got.append):
+            with TRACER.stage("s", lambda *clocks: got.append(clocks)):
                 raise KeyError("boom")
-        assert len(got) == 1
+        assert len(got) == 1 and len(got[0]) == 2
+
+    def test_a_busy_stage_reads_cpu_under_wall_and_a_sleeping_one_near_none(self):
+        # a spin much longer than the clock's step (10 ms on a TPU host)
+        spin = max(0.05, 8 * THREAD_CLOCK_STEP_S)
+        with TRACER.stage("busy") as busy:
+            end = time.perf_counter() + spin
+            while time.perf_counter() < end:
+                pass
+        # it ran all the while (less what the host took the core away for)
+        assert 0.4 * spin < busy.cpu_seconds <= busy.seconds + cpu_slack()
+        with TRACER.stage("asleep") as asleep:
+            time.sleep(0.05)
+        assert asleep.seconds >= 0.05 and 0 <= asleep.cpu_seconds < 0.01 + cpu_slack()
+
+    def test_a_stage_does_not_count_another_threads_cpu(self):
+        stop = threading.Event()
+
+        def spin():
+            while not stop.is_set():
+                pass
+
+        other = threading.Thread(target=spin, daemon=True)
+        other.start()
+        try:
+            with TRACER.stage("waiting") as st:
+                time.sleep(0.1)
+        finally:
+            stop.set()
+            other.join()
+        # the other thread burned 0.1 s of CPU meanwhile: none of it is here
+        assert st.seconds >= 0.1 and st.cpu_seconds < 0.02 + cpu_slack()
+
+    @staticmethod
+    def _clocks_by_hand(monkeypatch):
+        """The tracer's two clocks moved by hand, the CPU clock's reads
+        counted; no reading of one clock is ever met on the other."""
+        from tendermint_tpu.telemetry import tracer
+
+        monkeypatch.setattr(tracer, "_reading", threading.local())
+
+        class Clocks:
+            wall = 10**12
+            cpu = 10**9
+            reads = 0
+            time = staticmethod(time.time)
+
+            def perf_counter_ns(self) -> int:
+                return self.wall
+
+            def thread_time_ns(self) -> int:
+                self.reads += 1
+                return self.cpu
+
+            def run(self, ns: int) -> None:
+                self.wall += ns
+                self.cpu += ns
+
+            def wait(self, ns: int) -> None:
+                self.wall += ns
+
+        clocks = Clocks()
+        monkeypatch.setattr(tracer, "time", clocks)
+        return clocks
+
+    def test_stages_that_meet_at_a_boundary_share_one_reading_of_the_cpu_clock(self, monkeypatch):
+        """The clock is a system call of 6-24 us on a TPU host: a chain of
+        back-to-back stages reads it once a stage, not twice, and a
+        child that ends where its parent ends adds one reading, not two."""
+        clocks = self._clocks_by_hand(monkeypatch)
+        got = []
+        for _ in range(5):
+            with TRACER.stage("chain", lambda s, c: got.append((s, c))):
+                clocks.run(200_000)
+            clocks.run(3_000)  # the sink, the next stage's making
+        assert clocks.reads == 6  # the first enter, then every exit
+        # the chain's CPU is the thread's own: what ran between two
+        # stages is the later one's, nothing of it is lost
+        assert [round(c * 1e9) for _s, c in got] == [200_000] + 4 * [203_000]
+        assert [round(s * 1e9) for s, _c in got] == 5 * [200_000]
+        clocks.run(2 * CPU_SHARE_NS)
+        clocks.reads = 0
+        with TRACER.stage("parent") as parent:  # 1: its enter
+            clocks.run(1_000)
+            with TRACER.stage("first child") as first:  # shares the parent's
+                clocks.run(200_000)  # 2: its exit
+            clocks.run(200_000)
+            with TRACER.stage("last child") as last:  # 3: its enter
+                clocks.run(150_000)
+                clocks.wait(50_000)  # 4: its exit, and the parent's
+            clocks.run(2_000)
+        assert clocks.reads == 4
+        assert round(first.cpu_seconds * 1e9) == 201_000 and round(last.cpu_seconds * 1e9) == 150_000
+        # what a shared reading can misplace is under the share
+        assert round(parent.cpu_seconds * 1e9) == 551_000 and round(parent.seconds * 1e9) == 603_000
+
+    def test_a_boundary_later_than_the_share_reads_the_clock_anew(self, monkeypatch):
+        clocks = self._clocks_by_hand(monkeypatch)
+        with TRACER.stage("a"):
+            clocks.run(100_000)
+        clocks.wait(5_000_000)  # the thread lost the interpreter
+        clocks.run(CPU_SHARE_NS)
+        with TRACER.stage("b") as b:
+            clocks.run(100_000)
+        assert clocks.reads == 4 and round(b.cpu_seconds * 1e9) == 100_000
+
+    def test_a_stage_shorter_than_the_share_still_reads_its_own_clock(self, monkeypatch):
+        clocks = self._clocks_by_hand(monkeypatch)
+        with TRACER.stage("short") as short:
+            clocks.run(7_000)
+        # never the reading it entered with: that would be no CPU at all
+        assert clocks.reads == 2 and round(short.cpu_seconds * 1e9) == 7_000
+        with TRACER.stage("empty") as empty:  # shares `short`'s exit, then its own
+            pass
+        assert clocks.reads == 3 and empty.cpu_seconds == 0.0
+
+    def test_a_reading_is_its_threads_own(self, monkeypatch):
+        reads, clock = [], time.thread_time_ns
+
+        def counted() -> int:
+            reads.append(threading.get_ident())
+            return clock()
+
+        monkeypatch.setattr(time, "thread_time_ns", counted)
+
+        def elsewhere() -> None:
+            with TRACER.stage("there"):
+                pass
+
+        with TRACER.stage("here"):
+            other = threading.Thread(target=elsewhere)
+            other.start()
+            other.join()
+        # microseconds after this thread's reading, the other takes its own two
+        assert [t != threading.get_ident() for t in reads].count(True) == 2
 
     def test_without_jax_loaded_the_stage_is_timed_without_an_annotation(self, monkeypatch):
         monkeypatch.delitem(sys.modules, "jax", raising=False)
