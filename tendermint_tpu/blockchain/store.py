@@ -17,6 +17,15 @@ watermark that names it are on disk together; after a crash there is
 never a row above the watermark nor a height under it that loads in
 part. `height` (what `/status` answers) moves after that transaction
 has committed, so a reader never hears of a height it cannot load.
+
+What the read side promises: a height the store names loads whole, and
+from two reads. `load_block_bytes` takes the meta row by one `get` and
+all of the block's part rows by one `get_many` (one critical section of
+the database: all of a block's rows or none), and joins the parts' bytes
+into the block's wire form without building a `Part`, a proof or a
+`Block`; `/block` answers from that. A part row's Merkle proof is there
+for gossip (`load_block_part`); `load_block` gives the objects to those
+who need them (replay, the serving side of fast-sync, snapshots).
 """
 
 from __future__ import annotations
@@ -24,7 +33,7 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass
 
-from tendermint_tpu.codec import Reader, Writer
+from tendermint_tpu.codec import Reader, Writer, decode_bytes, decode_uvarint
 from tendermint_tpu.db.kv import DB, Batch
 from tendermint_tpu.types.block import Block, Commit, Header
 from tendermint_tpu.types.block_id import BlockID
@@ -205,6 +214,29 @@ class BlockStore:
                 return None
             buf += part.bytes_
         return Block.decode(buf)
+
+    def load_block_bytes(self, height: int) -> tuple[BlockMeta, bytes] | None:
+        """The block's meta and its wire form (`Block.encode`'s bytes)
+        from two reads of the database; None wherever `load_block`
+        answers None (below `base`, pruned, not yet stored, a part row
+        gone). Of a part row only the part's bytes are read."""
+        meta = self.load_block_meta(height)
+        if meta is None:
+            return None
+        rows = self._db.get_many(
+            [
+                self._part_key(height, i)
+                for i in range(meta.block_id.parts_header.total)
+            ]
+        )
+        chunks = []
+        for row in rows:
+            if row is None:
+                return None
+            # `Part.encode`: uvarint index, length-prefixed bytes, the proof
+            _, offset = decode_uvarint(row, 0)
+            chunks.append(decode_bytes(row, offset)[0])
+        return meta, b"".join(chunks)
 
     def load_block_commit(self, height: int) -> Commit | None:
         """Canonical commit for block at `height` (from block height+1)."""

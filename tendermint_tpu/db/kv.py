@@ -43,7 +43,12 @@ from tendermint_tpu.telemetry.metrics import (
     DB_COMMIT_CPU_SECONDS,
     DB_COMMIT_SECONDS,
     DB_COMMITS,
+    DB_READS,
 )
+
+# keys a `get_many` statement asks for at once: one result column and one
+# bound variable a key, under SQLite's limits on both (2,000 and 999)
+_GET_MANY_CHUNK = 500
 
 
 class CommitClock:
@@ -74,6 +79,13 @@ class DB:
     """Interface: bytes -> bytes with ordered iteration."""
 
     def get(self, key: bytes) -> bytes | None:
+        raise NotImplementedError
+
+    def get_many(self, keys: list[bytes]) -> list[bytes | None]:
+        """The values of `keys`, in their order, None for an absent
+        key, all read in ONE critical section: what `_apply` committed
+        and nothing else, so never half of a batch another thread is
+        writing."""
         raise NotImplementedError
 
     def set(self, key: bytes, value: bytes) -> None:
@@ -142,6 +154,10 @@ class MemDB(DB):
         with self._lock:
             return self._data.get(bytes(key))
 
+    def get_many(self, keys: list[bytes]) -> list[bytes | None]:
+        with self._lock:
+            return [self._data.get(bytes(key)) for key in keys]
+
     def _apply(self, rows: dict[bytes, bytes | None]) -> None:
         with self._lock:
             for key, value in rows.items():
@@ -171,14 +187,24 @@ class SQLiteDB(DB):
     SQLite's automatic checkpoint (1,000 pages) and the one it makes as
     the last connection closes do that. `tendermint_db_commits_total{db}`
     counts the transactions and `tendermint_db_commit_seconds{db}` times
-    their `commit()` (`CommitClock`).
+    their `commit()` (`CommitClock`); `tendermint_db_reads_total{db}`
+    counts the reads, one a `get` and one a `get_many` whatever its keys.
+
+    A read is one statement that answers with ONE row: the `sqlite3`
+    module lets go of the interpreter lock around every step of a
+    statement, and beside busy threads each step may have to wait a
+    switch interval or more to get it back, under `_lock`. So `get_many`
+    asks for its keys as the columns of one row (a scalar subquery a
+    key), not as a row a key.
     """
 
     def __init__(self, path: str) -> None:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         self._conn = sqlite3.connect(path, check_same_thread=False)
         self._lock = threading.Lock()
-        self._commits = CommitClock(os.path.splitext(os.path.basename(path))[0])
+        name = os.path.splitext(os.path.basename(path))[0]
+        self._commits = CommitClock(name)
+        self._reads = DB_READS.labels(db=name)
         with self._lock:
             self._conn.execute("PRAGMA journal_mode=WAL")
             self._conn.execute("PRAGMA synchronous=FULL")
@@ -192,7 +218,21 @@ class SQLiteDB(DB):
             row = self._conn.execute(
                 "SELECT v FROM kv WHERE k = ?", (bytes(key),)
             ).fetchone()
+        self._reads.inc()
         return row[0] if row else None
+
+    def get_many(self, keys: list[bytes]) -> list[bytes | None]:
+        keys = [bytes(key) for key in keys]
+        values: list[bytes | None] = []
+        with self._lock:
+            for i in range(0, len(keys), _GET_MANY_CHUNK):
+                chunk = keys[i : i + _GET_MANY_CHUNK]
+                sql = "SELECT " + ", ".join(
+                    ["(SELECT v FROM kv WHERE k = ?)"] * len(chunk)
+                )
+                values.extend(self._conn.execute(sql, chunk).fetchone())
+        self._reads.inc()
+        return values
 
     def _apply(self, rows: dict[bytes, bytes | None]) -> None:
         sets = [(k, v) for k, v in rows.items() if v is not None]

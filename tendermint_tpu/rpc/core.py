@@ -11,16 +11,20 @@ from __future__ import annotations
 import queue
 import time
 
+from tendermint_tpu.codec import Reader, decode_bytes, decode_uvarint
 from tendermint_tpu.rpc.server import RPCError, phase
 from tendermint_tpu.telemetry import metrics as _metrics
 from tendermint_tpu.types import events as ev
+from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.tx import tx_hash
 
 BROADCAST_TX_COMMIT_TIMEOUT_S = 60.0  # reference waits up to 120s
 APPLY_WAIT_S = 5.0  # the longest `abci_query` waits for the commit of a stored block
 
 
-def _header_json(header) -> dict:
+def _header_json(header, block_hash: bytes | None = None) -> dict:
+    """`block_hash`: the header's hash where the caller has it stored (a
+    block's meta row), else it is computed."""
     return {
         "chain_id": header.chain_id,
         "height": header.height,
@@ -37,11 +41,13 @@ def _header_json(header) -> dict:
         "data_hash": header.data_hash.hex(),
         "validators_hash": header.validators_hash.hex(),
         "app_hash": header.app_hash.hex(),
-        "hash": header.hash().hex(),
+        "hash": (header.hash() if block_hash is None else block_hash).hex(),
     }
 
 
 def _block_json(block) -> dict:
+    """What `/block` shows of a block, from the `Block`: the definition
+    `_block_json_from_bytes` is held to (tests/test_rpc_block_bytes.py)."""
     return {
         "header": _header_json(block.header),
         "txs": [bytes(tx).hex() for tx in block.data.txs],
@@ -52,6 +58,52 @@ def _block_json(block) -> dict:
             "precommits": sum(
                 1 for p in block.last_commit.precommits if p is not None
             ),
+        },
+    }
+
+
+def _block_json_from_bytes(meta, wire: bytes) -> dict:
+    """`_block_json` of the block whose meta row and wire form
+    (`Block.encode`) these are, read off the bytes: no `Block`, no `Vote`,
+    no tx object. The header is the meta row's and its hash the meta's
+    block id's (`BlockStore._put_block` stores `block.hash()` there);
+    of the wire form's sections the header's is passed over, the data's
+    is hexed once and cut at its length prefixes, the commit's gives the
+    block id and the number of its non-empty length-prefixed votes (what
+    `Commit.decode_from` makes `Vote`s of), and an evidence section after
+    it is not looked at: the answer shows none."""
+    n, start = decode_uvarint(wire, 0)  # the header's section: the meta row has it
+    data, offset = decode_bytes(wire, start + n)
+    commit, _ = decode_bytes(wire, offset)
+    n_txs, offset = decode_uvarint(data, 0)
+    hexed = data.hex()
+    txs = []
+    for _ in range(n_txs):
+        n = data[offset]
+        offset += 1
+        if n >= 0x80:
+            n, offset = decode_uvarint(data, offset - 1)
+        txs.append(hexed[2 * offset : 2 * (offset + n)])
+        offset += n
+    if offset != len(data):
+        raise ValueError("data section does not end with its last tx")
+    r = Reader(commit)
+    block_id = BlockID.decode_from(r)
+    n_precommits = r.uvarint()
+    offset = r.offset
+    present = 0
+    for _ in range(n_precommits):
+        n, offset = decode_uvarint(commit, offset)
+        present += n > 0
+        offset += n
+    if offset != len(commit):
+        raise ValueError("commit section does not end with its last vote")
+    return {
+        "header": _header_json(meta.header, meta.block_id.hash),
+        "txs": txs,
+        "last_commit": {
+            "block_id": block_id.hash.hex() if n_precommits else "",
+            "precommits": present,
         },
     }
 
@@ -116,14 +168,15 @@ def make_routes(node) -> dict:
         }
 
     def block(height: int) -> dict:
-        # inside `handle`: the store's part rows and `Block.decode`, then
-        # the answer's dict, each with its own clock
+        # inside `handle`: the store's two reads and the join of the
+        # parts' bytes, then the scan of the wire form and the answer's
+        # dict, each with its own clock
         with phase("block", "load"):
-            b = node.block_store.load_block(int(height))
-        if b is None:
+            loaded = node.block_store.load_block_bytes(int(height))
+        if loaded is None:
             raise RPCError(-32000, f"no block at height {height}")
         with phase("block", "render"):
-            return {"block": _block_json(b)}
+            return {"block": _block_json_from_bytes(*loaded)}
 
     def blockchain(min_height: int = 1, max_height: int = 0) -> dict:
         top = node.block_store.height

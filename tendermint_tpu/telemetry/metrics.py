@@ -645,6 +645,17 @@ DB_COMMITS = Counter(
     labelnames=("db",),
 )
 
+DB_READS = Counter(
+    "tendermint_db_reads_total",
+    "Reads of a SQLite file by store, one a get and one a get_many "
+    "whatever the number of its keys: each is one taking of the "
+    "database's lock and one statement of one row. Over "
+    "tendermint_rpc_phase_seconds_count{method=\"block\",phase=\"handle\"} "
+    "the blockstore's reads are 2 a /block answer (the meta row, then "
+    "all part rows) where they were 1 + the block's parts",
+    labelnames=("db",),
+)
+
 DB_COMMIT_SECONDS = Histogram(
     "tendermint_db_commit_seconds",
     "One durable write, timed where it happens (the `db.commit` stage): "
