@@ -568,8 +568,12 @@ class BlockchainReactor(Reactor):
             self._redo(block.header.height)
             return
         try:
-            # synchronous: the whole verify is a wait nothing hides
-            with _stage("verify_wait", stages):
+            # synchronous: the whole verify is a wait nothing hides; its
+            # launch record names the height, as a window's names its own
+            height = block.header.height
+            with _stage("verify_wait", stages), _launchlog.tag(
+                height_lo=height, height_hi=height
+            ):
                 self.state.validators.verify_commit(
                     self.state.chain_id,
                     block_id,

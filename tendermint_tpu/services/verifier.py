@@ -294,29 +294,29 @@ class TableBatchVerifier(DeviceBatchVerifier):
     """Valset-table-cached backend: the steady-state consensus fast path.
 
     Commit-shaped verification (lanes aligned to a known validator set)
-    routes through per-validator comb tables (`ops.ed25519_tables`) built
-    ON DEVICE once per validator set and cached by the hash of its pubkey
-    sequence — SURVEY.md §7 hard part 4's pre-staged valset arrays. A
-    cached verify costs ~0.7k field muls/signature vs ~4.8k for the
-    generic ladder `DeviceBatchVerifier` falls back to for ad-hoc
-    triples (proposal sigs, mixed-key batches).
-    """
+    routes through per-validator comb tables (`ops.ed25519_tables`), one
+    table a set, kept for `cache_size` sets under the hash of the set's
+    key sequence AS LAUNCHED: on the TPU a padded set (`_launch_keys`
+    repeats `PLACEHOLDER_KEY` up to the launch width: 1,000 keys -> 1,024
+    columns, 125.8 MB), so a table is wider than its set has distinct keys.
+    A build on the chip (PERF.md section 5): 19-33 s whole, most of it the
+    build executable's first call; 57-61 ms where one or two keys join."""
 
-    # diffs up to this many NEW keys build host-side (0.14 s/key,
-    # compile-free); larger diffs build the missing keys as one device
-    # kernel call (~0.7 s warm per 2048 keys — the persistent XLA cache
-    # keeps even a fresh process warm, utils/jax_cache.py)
+    # a set with up to this many keys no cached set has builds them on
+    # the host (26 ms a key alone on the chip's host, about 38 beside a
+    # syncing node's threads) and joins them to the cached table by one
+    # concatenate and one gather (4 ms); above it: the device's builder
     MAX_INCREMENTAL_KEYS = 128
 
     def __init__(self, cache_size: int = 4, min_device_batch: int | None = None) -> None:
         super().__init__(min_device_batch)
-        import threading
         from collections import OrderedDict
 
         from tendermint_tpu.utils.circuit import CircuitBreaker
 
-        # key -> (pubkeys tuple, tables, ok)
+        # key -> (pubkeys tuple, tables, ok); key -> a build in flight
         self._tables: "OrderedDict[bytes, tuple]" = OrderedDict()
+        self._building: "dict[bytes, Future]" = {}
         self._cache_size = cache_size
         self._cache_lock = threading.RLock()
         # Table CONSTRUCTION gets its own breaker (ROADMAP open item):
@@ -341,54 +341,51 @@ class TableBatchVerifier(DeviceBatchVerifier):
         return hashlib.sha256(b"".join(pubkeys)).digest()
 
     def _incremental_build(self, pubkeys: tuple[bytes, ...]):
-        """Assemble tables for `pubkeys` from the cached set with the
-        largest overlap: device-gather the shared columns, host-build
-        only the new keys. Returns None when no cached set shares enough
-        (EndBlock diffs touch few keys — reference
-        `state/execution.go:120-159` — so turnover is usually tiny)."""
+        """Assemble tables for `pubkeys` from the cached set that shares
+        most of its real keys: build only the keys that set lacks, put
+        their columns behind the cached table's and gather `pubkeys`'
+        columns out of the two (EndBlock diffs touch few keys: reference
+        `state/execution.go:120-159`). None when no cached set shares one.
+
+        Sound at a padded shape: the new columns start at the cached
+        table's WIDTH, on the TPU more than the count of its distinct
+        keys; a repeated key (the placeholder, a malformed key degraded
+        to it) maps to the first of its columns, which all hold one
+        table; each new key is built once, the placeholder among them
+        only when the cached set has none (it fills its tile); the `ok`
+        returned is that of every column of `pubkeys`, pad columns
+        (well-formed: the placeholder decompresses) included."""
         import jax.numpy as jnp
 
-        from tendermint_tpu.ops.ed25519_tables import host_build_key_tables
-
+        real = set(pubkeys) - {PLACEHOLDER_KEY}
+        best = None
         with self._cache_lock:
-            best = None
-            for _k, (old_pubs, old_t, old_ok) in self._tables.items():
-                pos = {pk: i for i, pk in enumerate(old_pubs)}
-                hits = sum(1 for pk in pubkeys if pk in pos)
-                if best is None or hits > best[0]:
-                    best = (hits, pos, old_t, old_ok)
-        if best is None or best[0] == 0:
-            # no overlap: concatenating against an unrelated cached set
-            # would copy its whole table on device for nothing
+            for cached in self._tables.values():
+                hits = len(real.intersection(cached[0]))
+                if hits and (best is None or hits > best[0]):
+                    best = (hits, cached)
+        if best is None:  # nothing shared: a concatenation would only copy
             return None
-        hits, pos, old_t, old_ok = best
-        missing = [pk for pk in pubkeys if pk not in pos]
+        old_pubs, tables, ok = best[1]
+        column: dict[bytes, int] = {}
+        for i, pk in enumerate(old_pubs):
+            column.setdefault(pk, i)
+        missing = [pk for pk in dict.fromkeys(pubkeys) if pk not in column]
         if missing:
-            if len(missing) <= self.MAX_INCREMENTAL_KEYS:
-                new_t, new_ok = host_build_key_tables(missing)
-                new_t = jnp.asarray(new_t)
-            else:
-                # big turnover (e.g. a 500-key valset rotation): the
-                # device build kernel beats 0.14 s/key host work (and
-                # pads to the ONE chunk-shaped executable on TPU itself)
-                from tendermint_tpu.ops.ed25519_tables import build_key_tables
-
-                miss_arr = np.frombuffer(b"".join(missing), dtype=np.uint8)
-                new_t, new_ok = build_key_tables(miss_arr.reshape(-1, 32))
-            combined = jnp.concatenate([old_t, new_t], axis=3)
-            ok_comb = np.concatenate([old_ok, new_ok])
-        else:  # same keys, different order/subset: pure gather
-            combined, ok_comb = old_t, old_ok
-        new_pos = {pk: i for i, pk in enumerate(missing)}
-        n_old = len(pos)
-        perm = np.array(
-            [pos.get(pk, n_old + new_pos.get(pk, 0)) for pk in pubkeys],
-            dtype=np.int32,
-        )
-        tables = jnp.take(combined, jnp.asarray(perm), axis=3)
-        return tables, ok_comb[perm]
+            few = len(missing) <= self.MAX_INCREMENTAL_KEYS
+            new_t, new_ok = (_host_keys if few else _device_keys)(missing)
+            tables = jnp.concatenate([tables, new_t], axis=3)
+            ok = np.concatenate([ok, new_ok])
+            column.update((pk, len(old_pubs) + j) for j, pk in enumerate(missing))
+        perm = np.array([column[pk] for pk in pubkeys], dtype=np.int32)
+        return jnp.take(tables, jnp.asarray(perm), axis=3), ok[perm]
 
     def _tables_for(self, pubkeys: tuple[bytes, ...]):
+        """The set's table and its columns' well-formedness: from the
+        cache (`hit`); from a build of this very set in flight on
+        another thread, `prebuild`'s or a launch's (`joined`: it waits
+        and builds nothing); else by building it (`miss`). A build that
+        fails fails its waiters the same way (`TableBuildError`)."""
         key = self._cache_key(pubkeys)
         with self._cache_lock:
             hit = self._tables.get(key)
@@ -396,20 +393,33 @@ class TableBatchVerifier(DeviceBatchVerifier):
                 self._tables.move_to_end(key)
                 _metrics.TABLE_CACHE.labels(event="hit").inc()
                 return hit[1], hit[2]
+            flight = self._building.get(key)
+            if flight is None:
+                mine = self._building[key] = Future()
+        if flight is not None:
+            _metrics.TABLE_CACHE.labels(event="joined").inc()
+            return flight.result()
         _metrics.TABLE_CACHE.labels(event="miss").inc()
-        tables, ok = _timed_build(self, pubkeys)
+        try:
+            built = _timed_build(self, pubkeys)
+        except BaseException as e:
+            with self._cache_lock:
+                del self._building[key]
+            mine.set_exception(e)
+            raise
         with self._cache_lock:
-            self._tables[key] = (tuple(pubkeys), tables, ok)
+            self._tables[key] = (tuple(pubkeys), *built)
+            del self._building[key]
             while len(self._tables) > self._cache_size:
                 self._tables.popitem(last=False)
-        return tables, ok
+        mine.set_result(built)
+        return built
 
     def _build_tables(self, pubkeys: tuple[bytes, ...]):
         """Construct tables for an uncached set, behind the table-build
-        breaker: device build (incremental when a cached set overlaps),
-        degrading to the compile-free host build for sets small enough
-        to afford it (~0.14 s/key), else raising `TableBuildError` so
-        `verify_commits` answers with host crypto."""
+        breaker: on the device (incremental when a cached set overlaps),
+        else on the host where the set is small enough to afford it, else
+        `TableBuildError`, and `verify_commits` answers with host crypto."""
         from tendermint_tpu.utils.fail import device_fail_point
 
         if self._build_breaker.allow():
@@ -419,12 +429,7 @@ class TableBatchVerifier(DeviceBatchVerifier):
                 if built is not None:
                     _built_as("incremental")
                 else:
-                    from tendermint_tpu.ops.ed25519_tables import build_key_tables
-
-                    pub = np.frombuffer(b"".join(pubkeys), dtype=np.uint8).reshape(
-                        len(pubkeys), 32
-                    )
-                    built = build_key_tables(pub)
+                    built = _device_keys(pubkeys)
                 self._build_breaker.record_success()
                 return built
             except Exception as e:
@@ -439,13 +444,8 @@ class TableBatchVerifier(DeviceBatchVerifier):
                     breaker=self._build_breaker.state,
                 )
         if len(pubkeys) <= self.MAX_INCREMENTAL_KEYS:
-            import jax.numpy as jnp
-
-            from tendermint_tpu.ops.ed25519_tables import host_build_key_tables
-
             _built_as("host_build")
-            t, ok = host_build_key_tables(list(pubkeys))
-            return jnp.asarray(t), ok
+            return _host_keys(pubkeys)
         raise TableBuildError(
             f"table build unavailable for {len(pubkeys)} keys "
             f"(breaker {self._build_breaker.state})"
@@ -487,21 +487,21 @@ class TableBatchVerifier(DeviceBatchVerifier):
         threading.Thread(target=_warm, daemon=True, name="warm-build-kernel").start()
 
     def prebuild(self, pubkeys) -> None:
-        """Warm the table cache for a validator set in the background —
-        called when a valset rotation is decided (EndBlock diffs) so the
-        first verify against the NEXT set doesn't stall on a build. The
-        set is padded as a launch pads it, so this builds the table the
-        next launch looks up."""
-        import threading
-
+        """Build the NEXT set's table on a thread of its own, from when
+        a block's EndBlock diffs decide the set (`apply_block`). The set
+        is padded as a launch pads it, so this is the table the next
+        launch looks up; a launch that comes before the build ends
+        waits for it in `_tables_for` and builds nothing."""
         pubs, _ = self._launch_keys(
             pubkeys, self._fused(None), self._launch_chips(len(pubkeys))
         )
-        if self._cache_key(pubs) in self._tables:
-            return
+        key = self._cache_key(pubs)
+        with self._cache_lock:
+            if key in self._tables or key in self._building:
+                return
 
         threading.Thread(
-            target=lambda: self._tables_for(pubs), daemon=True, name=_PREBUILD
+            target=_prebuild, args=(self, pubs), daemon=True, name=_PREBUILD
         ).start()
 
     @staticmethod
@@ -870,8 +870,8 @@ class ShardedTableBatchVerifier(_MeshFlatMixin, TableBatchVerifier):
 
     def _tables_for_mesh(self, pubkeys: tuple[bytes, ...], mesh_obj):
         """Valset tables placed WITH the validator-axis sharding for the
-        active mesh (cached per device set; the underlying build rides
-        the same table-build breaker as the single-device path)."""
+        active mesh (cached per device set). The build is `_tables_for`'s,
+        of a set padded to the mesh's launch width: breaker, joins and all."""
         import jax as _jax
         from jax.sharding import NamedSharding, PartitionSpec as _P
 
@@ -1111,11 +1111,24 @@ def set_default_verifier(v: BatchVerifier) -> None:
 # that holds a Pallas kernel (PERF.md section 6).
 
 import threading  # noqa: E402
+from concurrent.futures import Future  # noqa: E402
 
 from tendermint_tpu.telemetry import TRACER  # noqa: E402
 
 _PREBUILD = "table-prebuild"  # the thread `prebuild` starts
-_BUILD = threading.local()  # .kind: how the build this thread is in went
+# how the build this thread is in went (.kind), and the keys whose
+# tables it has computed so far (.keys_new)
+_BUILD = threading.local()
+
+
+def _prebuild(verifier: "TableBatchVerifier", pubs) -> None:
+    """The `prebuild` thread's whole work. A build that cannot be had
+    was logged where it failed and fails the launch that needs the
+    table the same way: nothing to add from here."""
+    try:
+        verifier._tables_for(pubs)
+    except TableBuildError:
+        pass
 
 
 def _built_as(event: str) -> None:
@@ -1126,11 +1139,41 @@ def _built_as(event: str) -> None:
     _BUILD.kind = event
 
 
-def _observe_build(seconds: float, _cpu_seconds: float) -> None:
+def _keys_built(how: str, n: int) -> None:
+    _metrics.TABLE_KEYS_BUILT.labels(how=how).inc(n)
+    _BUILD.keys_new = getattr(_BUILD, "keys_new", 0) + n
+
+
+def _host_keys(keys):
+    """The comb tables of `keys`, a column each, by the host's Python
+    integers (no executable of its own), put on the device; and which
+    of the keys are well-formed."""
+    import jax.numpy as jnp
+
+    from tendermint_tpu.ops.ed25519_tables import host_build_key_tables
+
+    tables, ok = host_build_key_tables(list(keys))
+    _keys_built("host", len(keys))
+    return jnp.asarray(tables), ok
+
+
+def _device_keys(keys):
+    """The same by the device's build kernel."""
+    from tendermint_tpu.ops.ed25519_tables import build_key_tables
+
+    pub = np.frombuffer(b"".join(keys), dtype=np.uint8).reshape(len(keys), 32)
+    built = build_key_tables(pub)
+    _keys_built("device", len(keys))
+    return built
+
+
+def _build_kind() -> str:
     prebuild = threading.current_thread().name == _PREBUILD
-    _metrics.TABLE_BUILD_SECONDS.labels(
-        kind="prebuild" if prebuild else _BUILD.kind
-    ).observe(seconds)
+    return "prebuild" if prebuild else _BUILD.kind
+
+
+def _observe_build(seconds: float, _cpu_seconds: float) -> None:
+    _metrics.TABLE_BUILD_SECONDS.labels(kind=_build_kind()).observe(seconds)
 
 
 def _timed_build(verifier: TableBatchVerifier, pubkeys):
@@ -1138,7 +1181,21 @@ def _timed_build(verifier: TableBatchVerifier, pubkeys):
     `tendermint_verify_table_build_seconds{kind}`, and a stretch in the
     profiler's host plane, so a launch that has to build its table no
     longer hides the build in its `host_prep_s` and a prebuild is timed
-    at all."""
-    _BUILD.kind = "full"
-    with TRACER.stage("tables.build", _observe_build):
-        return verifier._build_tables(pubkeys)
+    at all. One `tables.build` span a build says how it went: its
+    `kind` (the histogram's), the keys whose tables it computed
+    (`keys_new`: 1 or 2 where a validator joins, the table's width
+    where nothing cached overlapped) and the table's `columns`."""
+    _BUILD.kind, _BUILD.keys_new = "full", 0
+    t0 = time.time()
+    try:
+        with TRACER.stage("tables.build", _observe_build):
+            return verifier._build_tables(pubkeys)
+    finally:
+        TRACER.add(
+            "tables.build",
+            t0,
+            time.time(),
+            kind=_build_kind(),
+            keys_new=_BUILD.keys_new,
+            columns=len(pubkeys),
+        )

@@ -11,11 +11,14 @@ light clients can verify old commits.
 from __future__ import annotations
 
 import json
+import time
 from dataclasses import dataclass, field
 
 from tendermint_tpu.abci.types import Result, Validator as ABCIValidator
 from tendermint_tpu.crypto.keys import PubKey
 from tendermint_tpu.db.kv import DB
+from tendermint_tpu.telemetry import TRACER
+from tendermint_tpu.telemetry.metrics import VALSET_CHANGES
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
 from tendermint_tpu.types.genesis import GenesisDoc
@@ -29,6 +32,37 @@ _STATE_KEY = b"stateKey"
 def _validators_info(last_changed: int, vs: ValidatorSet) -> bytes:
     """A full per-height validators row, as `json.dumps(sort_keys=True)`."""
     return ('{"last_changed": %d, "validators": %s}' % (last_changed, vs.to_json())).encode()
+
+
+def _apply_changes(vals: ValidatorSet, changes: list[ABCIValidator], height: int) -> None:
+    """The EndBlock diffs of the block at `height` onto the set of the
+    next height, and the new set's root, which the next header's check
+    and fast-sync's next window ask for at once (`hash()` keeps it).
+    Both are timed as one `valset.change` span, with the keys that
+    joined, left and were re-weighted (by the set as it stood before
+    the block), which `tendermint_valset_changes_total{kind}` counts."""
+    t0 = time.time()
+    counts = {"join": 0, "leave": 0, "power": 0}
+    diffs = []
+    for c in changes:
+        pub = PubKey(c.pub_key)
+        held = vals.get_by_address(pub.address)[1] is not None
+        counts["leave" if c.power == 0 else "power" if held else "join"] += 1
+        diffs.append(Validator(address=pub.address, pub_key=pub, voting_power=c.power))
+    vals.apply_changes(diffs)
+    vals.hash()
+    for kind, n in counts.items():
+        VALSET_CHANGES.labels(kind=kind).inc(n)
+    TRACER.add(
+        "valset.change",
+        t0,
+        time.time(),
+        height=height,
+        joined=counts["join"],
+        left=counts["leave"],
+        reweighted=counts["power"],
+        validators=len(vals),
+    )
 
 
 @dataclass
@@ -242,16 +276,7 @@ class State:
         prev_vals = self.validators.copy()
         next_vals = self.validators.copy()
         if abci_responses.end_block_changes:
-            next_vals.apply_changes(
-                [
-                    Validator(
-                        address=PubKey(v.pub_key).address,
-                        pub_key=PubKey(v.pub_key),
-                        voting_power=v.power,
-                    )
-                    for v in abci_responses.end_block_changes
-                ]
-            )
+            _apply_changes(next_vals, abci_responses.end_block_changes, header.height)
             self.last_height_validators_changed = header.height + 1
         next_vals.increment_accum(1)
         self.last_block_height = header.height

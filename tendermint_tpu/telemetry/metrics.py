@@ -155,8 +155,19 @@ HASH_SECONDS = Histogram(
 )
 TABLE_CACHE = Counter(
     "tendermint_verify_table_cache_total",
-    "Valset comb-table cache outcomes (hit/miss/incremental/host_fallback)",
+    "Valset comb-table cache outcomes. A lookup is one of hit, miss (it "
+    "builds the table) and joined (it waited for a build of the same set "
+    "in flight on another thread and built nothing); a miss is also "
+    "counted incremental (new keys' columns joined to a cached set's) or "
+    "host_build (on the host behind an open breaker) by how it was built",
     labelnames=("event",),
+)
+TABLE_KEYS_BUILT = Counter(
+    "tendermint_verify_table_keys_built_total",
+    "Keys whose comb table was computed, by the builder: host (Python "
+    "integers, the few keys an incremental build lacks) or device (the "
+    "build kernel: every column of a full build, pad columns with them)",
+    labelnames=("how",),
 )
 TABLE_BUILD_KINDS = ("full", "incremental", "host_build", "prebuild")
 TABLE_BUILD_SECONDS = Histogram(
@@ -172,6 +183,10 @@ TABLE_BUILD_SECONDS = Histogram(
 )
 for _kind in TABLE_BUILD_KINDS:
     TABLE_BUILD_SECONDS.labels(kind=_kind)
+for _event in ("hit", "miss", "joined", "incremental", "host_build"):
+    TABLE_CACHE.labels(event=_event).inc(0)
+for _how in ("host", "device"):
+    TABLE_KEYS_BUILT.labels(how=_how).inc(0)
 XLA_CACHE_ENABLED = Gauge(
     "tendermint_xla_persistent_cache_enabled",
     "1 when the persistent XLA executable cache is active",
@@ -411,7 +426,9 @@ SPAN_CATALOG = frozenset(
         "batcher.flush",
         "dispatch.launch",
         "fastsync.window",
+        "tables.build",
         "tx.e2e",
+        "valset.change",
         "vote.e2e",
     }
 )
@@ -632,6 +649,17 @@ VALSET_HASHES = Counter(
     "its root and copy() hands it on; only a membership or power change "
     "drops it): about one a process on a static set, not one a block",
 )
+
+VALSET_CHANGES = Counter(
+    "tendermint_valset_changes_total",
+    "Validators a block's EndBlock diffs changed, as state/state.py "
+    "applied them: join (a key the set did not hold), leave (power 0) or "
+    "power (a held key re-weighted); one `valset.change` span a block "
+    "that changes the set carries the three counts and the seconds",
+    labelnames=("kind",),
+)
+for _kind in ("join", "leave", "power"):
+    VALSET_CHANGES.labels(kind=_kind).inc(0)
 
 # -- databases (db/kv.py) -----------------------------------------------------
 
