@@ -28,7 +28,9 @@ one reads the index back before going on. After a restart `/tx` answers
 for every block whose `add_batch` returned; a block the crash caught
 before that stays unindexed, since the handshake replays it without an
 indexer (`consensus/replay.py`). A `txindex.db` from before the run
-log keeps answering for its rows, read-only.
+log keeps answering for its rows, read-only, and so does every row
+written as JSON before values were packed: a value's first byte says
+which it is (`state/txindex.py`).
 """
 
 from __future__ import annotations

@@ -20,10 +20,19 @@ Files, in a directory of the store's own:
                   records of `data` from there on are live as well
 
 Integers are little-endian. A pointer is the offset in `data` of a
-value's length; values are never moved or copied. A run is a sorted
+value's length; values are opaque bytes (what they hold is
+`state/txindex.py`'s) and are never moved or copied. A run is a sorted
 array of (key, pointer): a record's own, or a key file's. `get` probes
 the runs newest first, so a key written twice answers with its latest
 value.
+
+`append` is handed a block's rows as the record wants them: the keys
+end to end, the value section ready made (each value behind its
+length), and where each length lies in it. What is left to it is
+whole-array work: it checks that lengths and places agree, sorts the
+keys (stable: of a key that a block holds twice the last stays, and the
+earlier value stays in the section with no pointer to it), turns places
+into pointers and writes.
 
 What is durable when. `append` returns after one fsync that covers the
 whole record, and only then do readers see the run: all of a block's
@@ -254,29 +263,34 @@ class RunLog:
 
     # -- write ------------------------------------------------------------
 
-    def append(self, height: int, rows: dict[bytes, bytes]) -> None:
-        """All of `rows` as one run, durable and then visible when this
-        returns. Every key is `KEY_LEN` bytes."""
-        if not rows:
+    def append(self, height: int, keys: bytes, values: bytes, starts: np.ndarray) -> None:
+        """A block's rows as one run, durable and then visible when this
+        returns. `keys`: the rows' keys end to end, `KEY_LEN` bytes
+        each. `values`: the record's value section as it goes to disk,
+        every value behind its length u32, in the keys' order. `starts`:
+        where in `values` each value's length lies. Of equal keys the
+        last stays; the earlier one's value stays in the section with
+        no pointer to it."""
+        starts = np.asarray(starts, dtype=np.int64)
+        count = len(starts)
+        if not count:
             return
-        count = len(rows)
-        values = bytearray()
-        starts = np.empty(count, dtype="<u8")
-        for i, value in enumerate(rows.values()):
-            starts[i] = len(values)
-            values += _U32.pack(len(value))
-            values += value
-        keys = np.frombuffer(b"".join(rows), dtype=ENTRY["key"])
-        if keys.size != count:
+        if len(keys) != count * KEY_LEN:
             raise ValueError(f"a key is not {KEY_LEN} bytes")
+        if not _framed(values, starts):
+            raise ValueError("the values do not lie behind their lengths, end to end")
+        keys = np.frombuffer(keys, dtype=ENTRY["key"])
         order = np.argsort(keys, kind="stable")
+        keys = keys[order]
+        last = np.ones(count, dtype=bool)
+        last[:-1] = keys[1:] != keys[:-1]
+        if not last.all():
+            order, keys, count = order[last], keys[last], int(last.sum())
         entries = np.empty(count, dtype=ENTRY)
-        entries["key"] = keys[order]
+        entries["key"] = keys
         with self._append_lock:
             at = self._end
-            entries["ptr"] = starts[order] + np.uint64(
-                at + _RECORD_HEAD.size + count * ENTRY.itemsize
-            )
+            entries["ptr"] = starts[order] + (at + _RECORD_HEAD.size + count * ENTRY.itemsize)
             head = _RECORD_HEAD.pack(_RECORD_MAGIC, height, count, len(values))
             keyed = entries.tobytes()
             crc = zlib.crc32(values, zlib.crc32(keyed, zlib.crc32(head)))
@@ -436,6 +450,17 @@ class RunLog:
             if self._fd >= 0:
                 os.close(self._fd)
                 self._fd = -1
+
+
+def _framed(values: bytes, starts: np.ndarray) -> bool:
+    """Whether `values` is values end to end, each behind its length
+    u32, and `starts` the places of those lengths: `get` trusts both."""
+    if starts[0] != 0 or starts.min() < 0 or starts.max() + _U32.size > len(values):
+        return False
+    raw = np.frombuffer(values, dtype=np.uint8)
+    lengths = raw[starts[:, None] + np.arange(_U32.size)].view("<u4")[:, 0]
+    ends = starts + _U32.size + lengths
+    return bool((ends[:-1] == starts[1:]).all() and ends[-1] == len(values))
 
 
 def _merged(inputs: tuple[_Run, ...]):

@@ -26,14 +26,25 @@ import json
 import os
 import pathlib
 import sqlite3
+import struct
 import threading
 from contextlib import nullcontext
 from dataclasses import dataclass
 
+import numpy as np
+
 from tendermint_tpu.abci.types import Result
 from tendermint_tpu.db.kv import DB
 from tendermint_tpu.db.runlog import RunLog
+from tendermint_tpu.telemetry.metrics import TXINDEX_VALUES_READ
 from tendermint_tpu.types.tx import tx_hash
+
+_PACKED = 1  # the form byte
+_VALUE = struct.Struct("<BQIIIII")
+# the same behind the length u32 that a record of the run log wants
+# before each value: one `pack` a row
+_FRAMED = struct.Struct("<IBQIIIII")
+_LENGTH = _FRAMED.size - _VALUE.size
 
 
 @dataclass
@@ -45,27 +56,29 @@ class TxResult:
     tx: bytes
     result: Result
 
-    def to_json(self) -> bytes:
-        return json.dumps(
-            {
-                "height": self.height,
-                "index": self.index,
-                "tx": self.tx.hex(),
-                "code": self.result.code,
-                "data": self.result.data.hex(),
-                "log": self.result.log,
-            },
-            sort_keys=True,
-        ).encode()
-
     @classmethod
-    def from_json(cls, raw: bytes) -> "TxResult":
-        d = json.loads(raw.decode())
+    def decode(cls, raw: bytes) -> "TxResult":
+        """A stored value of either form, told apart by its first byte."""
+        if raw[:1] == b"{":
+            TXINDEX_VALUES_READ.labels(form="json").inc()
+            d = json.loads(raw)
+            return cls(
+                height=d["height"],
+                index=d["index"],
+                tx=bytes.fromhex(d["tx"]),
+                result=Result(d["code"], bytes.fromhex(d["data"]), d["log"]),
+            )
+        form, height, index, code, n_tx, n_data, n_log = _VALUE.unpack_from(raw)
+        data = _VALUE.size + n_tx
+        log = data + n_data
+        if form != _PACKED or log + n_log != len(raw):
+            raise ValueError(f"not a tx index value: form {form}, {len(raw)} bytes")
+        TXINDEX_VALUES_READ.labels(form="packed").inc()
         return cls(
-            height=d["height"],
-            index=d["index"],
-            tx=bytes.fromhex(d["tx"]),
-            result=Result(d["code"], bytes.fromhex(d["data"]), d["log"]),
+            height=height,
+            index=index,
+            tx=raw[_VALUE.size : data],
+            result=Result(code, raw[data:log], raw[log:].decode("utf-8", "surrogatepass")),
         )
 
 
@@ -76,7 +89,8 @@ class TxIndexer:
     def add_batch(self, block, abci_responses, stage=None) -> None:
         """Index a block's txs. `stage`, when given, is `apply_block`'s
         stopwatch: `stage("index_rows")` is held around building the
-        rows, apart from the write that follows."""
+        rows (keys, packed values, the run log's value section and its
+        pointers), apart from the write that follows."""
         raise NotImplementedError
 
     def get(self, tx_hash: bytes) -> TxResult | None:
@@ -96,19 +110,21 @@ class NullTxIndexer(TxIndexer):
         return None
 
 
-def _rows(block, abci_responses, stage=None) -> dict[bytes, bytes]:
-    """A block's index rows by tx hash; of a tx that is in the block
-    twice the later one stays. Built under `stage("index_rows")` where
-    the caller has a stopwatch."""
+def _rows(block, abci_responses) -> tuple[list[bytes], list[bytes]]:
+    """A block's index rows in the block's order: the keys (tx hashes)
+    and, beside each, its packed value behind its length u32."""
     height = block.header.height
-    rows = {}
-    with stage("index_rows") if stage else _UNTIMED:
-        for i, tx in enumerate(block.data.txs):
-            tx = bytes(tx)
-            rows[tx_hash(tx)] = TxResult(
-                height=height, index=i, tx=tx, result=abci_responses.deliver_tx[i]
-            ).to_json()
-    return rows
+    txs = [bytes(tx) for tx in block.data.txs]
+    pack, body = _FRAMED.pack, _VALUE.size
+    framed = []
+    for i, (tx, result) in enumerate(zip(txs, abci_responses.deliver_tx, strict=True)):
+        data, log = result.data, result.log.encode("utf-8", "surrogatepass")
+        n_tx, n_data, n_log = len(tx), len(data), len(log)
+        framed.append(
+            pack(body + n_tx + n_data + n_log, _PACKED, height, i, result.code, n_tx, n_data, n_log)
+            + tx + data + log
+        )
+    return [tx_hash(tx) for tx in txs], framed
 
 
 class KVTxIndexer(TxIndexer):
@@ -116,14 +132,16 @@ class KVTxIndexer(TxIndexer):
         self._db = db
 
     def add_batch(self, block, abci_responses, stage=None) -> None:
+        with stage("index_rows") if stage else _UNTIMED:
+            keys, framed = _rows(block, abci_responses)
         batch = self._db.batch()
-        for key, row in _rows(block, abci_responses, stage).items():
-            batch.set(b"tx:" + key, row)
+        for key, value in zip(keys, framed):  # of a tx twice in the block the later stays
+            batch.set(b"tx:" + key, value[_LENGTH:])
         batch.write()
 
     def get(self, tx_hash: bytes) -> TxResult | None:
         raw = self._db.get(b"tx:" + tx_hash)
-        return TxResult.from_json(raw) if raw is not None else None
+        return TxResult.decode(raw) if raw is not None else None
 
 
 class RunTxIndexer(TxIndexer):
@@ -138,13 +156,17 @@ class RunTxIndexer(TxIndexer):
         self._old = _OldIndexFile(old) if os.path.exists(old) else None
 
     def add_batch(self, block, abci_responses, stage=None) -> None:
-        self._log.append(block.header.height, _rows(block, abci_responses, stage))
+        with stage("index_rows") if stage else _UNTIMED:
+            keys, framed = _rows(block, abci_responses)
+            sizes = np.fromiter(map(len, framed), dtype=np.int64, count=len(framed))
+            rows = b"".join(keys), b"".join(framed), np.cumsum(sizes) - sizes
+        self._log.append(block.header.height, *rows)
 
     def get(self, tx_hash: bytes) -> TxResult | None:
         raw = self._log.get(tx_hash)
         if raw is None and self._old is not None:
             raw = self._old.get(b"tx:" + tx_hash)
-        return TxResult.from_json(raw) if raw is not None else None
+        return TxResult.decode(raw) if raw is not None else None
 
     def close(self) -> None:
         self._log.close()

@@ -576,7 +576,8 @@ FASTSYNC_STAGE_SECONDS = Histogram(
     "(save_block), validate / exec / state_save (apply_block), starved "
     "(the sync loop's idle tick: nothing to prepare, nothing in flight); "
     "and inside state_save, index_rows (building the block's tx index "
-    "rows, before the run log's append)",
+    "rows: keys, packed values and the run log's value section, before "
+    "its append)",
     labelnames=("stage",),
     buckets=LATENCY_BUCKETS,
 )
@@ -706,7 +707,8 @@ DB_COMMIT_CPU_SECONDS = Counter(
 TXINDEX_BYTES_WRITTEN = Counter(
     "tendermint_txindex_bytes_written_total",
     "Bytes the tx index wrote, by kind: append (a block's record: its "
-    "sorted keys, its values, header and checksum) and merge (key files a "
+    "sorted keys, its values, header and checksum; 40 bytes a row and a "
+    "packed value of 33 and the tx, data and log) and merge (key files a "
     "merge wrote; values are never rewritten). merge over append is the "
     "write amplification",
     labelnames=("kind",),
@@ -729,6 +731,16 @@ TXINDEX_PROBES = Histogram(
     "hash is found)",
     buckets=(1, 2, 4, 8, 16, 32, 64),
 )
+TXINDEX_VALUES_READ = Counter(
+    "tendermint_txindex_values_read_total",
+    "Values of the tx index decoded for a lookup, by the form their first "
+    "byte gives: packed (a fixed header and the raw tx, data and log) or "
+    "json (a row written before the packed form, in a txindex/ directory "
+    "or a txindex.db)",
+    labelnames=("form",),
+)
+for _form in ("packed", "json"):
+    TXINDEX_VALUES_READ.labels(form=_form).inc(0)
 
 # -- state sync ---------------------------------------------------------------
 

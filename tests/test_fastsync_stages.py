@@ -210,18 +210,31 @@ class TestApplyBlock:
         assert seen == ["validate", "exec", "state_save", "exec", "state_save"]
         assert sim.state.last_block_height == 2
 
-    def test_the_index_rows_are_a_stage_inside_state_save(self):
+    @pytest.mark.parametrize("kind", ["kv", "runlog"])
+    def test_the_index_rows_are_a_stage_inside_state_save(self, kind, tmp_path, monkeypatch):
         """Building a block's tx index rows is timed apart from the
-        write that follows it, inside the first `state_save`."""
+        write that follows it, inside the first `state_save`, once a
+        block: the keys, the packed values and, for the run log, its
+        value section and pointers, which `append` gets ready made."""
         from contextlib import contextmanager
 
+        from tendermint_tpu.db.runlog import RunLog
         from tendermint_tpu.state import apply_block
-        from tendermint_tpu.state.txindex import KVTxIndexer
+        from tendermint_tpu.state.txindex import KVTxIndexer, RunTxIndexer
         from tendermint_tpu.types.tx import tx_hash
 
         sim = chain(1)
         block, parts = sim.make_next_block(txs=[b"a=1", b"b=2"])
-        seen, indexer = [], KVTxIndexer(MemDB())
+        seen = []
+        indexer = KVTxIndexer(MemDB()) if kind == "kv" else RunTxIndexer(str(tmp_path))
+        real = RunLog.append
+
+        def append(self, height, keys, values, starts):
+            assert (type(keys), type(values), len(starts)) == (bytes, bytes, len(keys) // 32)
+            seen.append("append")
+            real(self, height, keys, values, starts)
+
+        monkeypatch.setattr(RunLog, "append", append)
 
         @contextmanager
         def stage(name):
@@ -233,7 +246,10 @@ class TestApplyBlock:
             sim.state, block, parts.header, sim.conns.consensus,
             tx_indexer=indexer, stage=stage,
         )
-        assert seen[4:8] == ["+state_save", "+index_rows", "-index_rows", "-state_save"]
+        write = ["append"] if kind == "runlog" else []
+        assert seen[4 : 8 + len(write)] == [
+            "+state_save", "+index_rows", "-index_rows", *write, "-state_save"
+        ]
         assert [n for n in seen if n.startswith("+")] == [
             "+validate", "+exec", "+state_save", "+index_rows", "+exec", "+state_save"
         ]
@@ -241,6 +257,7 @@ class TestApplyBlock:
         # without a stopwatch (consensus) the rows are built all the same
         indexer.add_batch(*_block_of(sim, [b"c=3"]))
         assert indexer.get(tx_hash(b"c=3")).height == 9
+        indexer.close()
 
 
 def _block_of(sim, txs):

@@ -392,10 +392,9 @@ class TestWhoHasTheInterpreter:
         log = RunLog(str(tmp_path / "txindex"))
         try:
             for height in range(1, FAN_IN + 1):
-                rows = {
-                    hashlib.sha256(b"%d-%d" % (height, i)).digest(): b"v" for i in range(200)
-                }
-                log.append(height, rows)
+                keys = [hashlib.sha256(b"%d-%d" % (height, i)).digest() for i in range(200)]
+                # every value `v` behind its length u32, five bytes apart
+                log.append(height, b"".join(keys), b"\x01\0\0\0v" * 200, np.arange(200) * 5)
             deadline = time.monotonic() + 20
             while REGISTRY.counter_value(merges) == merged and time.monotonic() < deadline:
                 time.sleep(0.01)
