@@ -1,15 +1,19 @@
-"""Driver `catchup`: a fresh node fast-syncs a seeded chain from one peer.
+"""Driver `catchup`: a fresh node fast-syncs a seeded chain from the
+deployment's serving peers (`"peers"` in its file; one in every listed cell).
 
 The node under test is `tendermint_tpu.node.Node`, built and started in
 this process through the calls `cmd.py::_cmd_node` makes
 (`enable_persistent_cache()`, `load_config`, `Node(cfg).start()`), so the
-profiler trace is this process's own. The serving peer (`lib/peer.py`,
-which also generates the chain) and the read client (`lib/client.py`) are
-children; neither touches the chip. The window is observed over RPC.
+profiler trace is this process's own. The serving peers (`lib/peer.py`,
+a child each; the first also generates the chain) and the read client
+(`lib/client.py`) are children; none touches the chip. The window is
+observed over RPC. What a peer answers is the mix's peer rule
+(`lib/peers.py`; none: the generator's bytes), and the node is held to it:
+a debit of a peer that lied is due, one of a peer that did not is a failure.
 
-Set-up, in order: peer child (chain from the seed) -> JAX up here, no
-accelerator is a refusal -> node home, node start with the peer as its
-seed, after one verifier call per window size -> client child -> wait until the node has applied the mix's
+Set-up, in order: peer children (chain from the seed) -> JAX up here, no
+accelerator is a refusal -> node home, node start with the peers as its
+seeds, after one verifier call per window size -> client child -> wait until the node has applied the mix's
 `warm_blocks`. Then `--seconds` of catch-up, then, outside the window,
 the checks.
 """
@@ -82,6 +86,28 @@ def _child_env(root: str) -> dict:
     env["PYTHONUNBUFFERED"] = "1"
     env["JAX_PLATFORMS"] = "cpu"
     return env
+
+
+def peer_commands(here: str, cell: dict, seed: int, peer_home: str, n_peers: int) -> list[list[str]]:
+    """The command line of each serving peer. One peer: the line it always
+    had. Of several, each is told which it is (`--index`, `--of`)."""
+    workers = max(1, min(10, (os.cpu_count() or 2) - 3))
+    argv = [
+        sys.executable, "-m", "benchmark.lib.peer",
+        "--home", peer_home,
+        "--config", os.path.join(here, "configs", cell["config"] + ".json"),
+        "--mix", os.path.join(here, "traffic", cell["traffic"] + ".json"),
+        "--seed", str(seed), "--blocks", str(int(cell["chain_blocks"])), "--workers", str(workers),
+    ]
+    if n_peers == 1:
+        return [argv]
+    return [[*argv, "--index", str(i), "--of", str(n_peers)] for i in range(n_peers)]
+
+
+def seeds(p2p_ports: list[int]) -> str:
+    """The node's `p2p.seeds`: every serving peer's address (`node/node.py`
+    splits the list at its commas)."""
+    return ",".join(f"127.0.0.1:{port}" for port in p2p_ports)
 
 
 def _evict_chains(chains_dir: str, keep: str) -> None:
@@ -201,12 +227,113 @@ def _longest_standstill(status: list[dict]) -> float:
     return max((hi - lo for lo, hi in seen.values()), default=0.0)
 
 
+class PeerWatch(threading.Thread):
+    """Under a peer rule only: who the node has debited and how many peers
+    it holds, looked at twenty times a second, a row a change, with the
+    height the store and the pool stood at. The switch's counter names no
+    peer and the node's log is held to errors, so this is where "who, at
+    which height" comes from."""
+
+    def __init__(self, node) -> None:
+        super().__init__(name="bench-peer-watch", daemon=True)
+        self.node, self.rows, self._halt = node, [], threading.Event()
+
+    def run(self) -> None:
+        last = None
+        pool = self.node.blockchain_reactor.pool
+        while not self._halt.wait(0.05):
+            now = (tuple(_debited(self.node)), len(self.node.switch.peers()), pool.num_peers())
+            if now != last:
+                last = now
+                self.rows.append({
+                    "wall": time.time(), "store_height": self.node.block_store.height,
+                    "pool_height": pool.height, "debited": list(now[0]), "connected": now[1], "pool_peers": now[2],
+                })
+
+    def stop(self) -> list[dict]:
+        self._halt.set()
+        self.join(5)
+        return self.rows
+
+
+def _debited(node) -> list[str]:
+    """The node ids the node's scorer holds a score or a ban for."""
+    held = node.switch.scorer.snapshot()
+    return sorted({*held["scores"], *held["bans"]})
+
+
+def _judge_peers(node, serving, peer_home, connected, metrics, h_close, record, events, log) -> dict:
+    """After the window: the three numbers `correct` holds the node's
+    treatment of its peers to, beside their limits (`lib/peers.py`
+    `account`, `checks.forged_blocks_applied`), the failures in words, what
+    `failed` counts of the debits, and how many peers served and how many
+    the node ended with; `events` are `PeerWatch`'s rows, where it ran. A
+    peer is named by its index among the serving peers; a node id that is
+    none of theirs by its first 12 characters."""
+    from benchmark.lib import checks, rpc
+    from benchmark.lib import peers as peerlib
+    from tendermint_tpu.p2p.node_key import NodeKey
+
+    index_of = {
+        NodeKey.load_or_gen(os.path.join(peer_home, peerlib.key_file(i))).node_id: i for i in range(len(serving))
+    }
+
+    def named(node_ids) -> list:
+        return sorted((index_of.get(i, i[:12]) for i in node_ids), key=str)
+
+    lies = {i: peerlib.lies_sent(c.output()) for i, c in enumerate(serving)}
+    got = peerlib.account(
+        lies=lies, debited=set(named(_debited(node))),
+        debits=int(rpc.metric(metrics, "tendermint_p2p_peer_misbehavior_total")),
+        connected=set(named(connected)), h_close=h_close,
+    )
+    forged = checks.forged_blocks_applied(node.block_store, record)
+    ended = {
+        "served": len(serving), "connected_at_close": len(connected),
+        "pool_peers_at_end": node.blockchain_reactor.pool.num_peers(),
+    }
+    by_kind = {
+        ls.get("kind", ""): int(v) for ls, v in metrics.get("tendermint_p2p_peer_misbehavior_total", []) if v
+    }
+    log(
+        f"check peers: {json.dumps(ended)}; debited {got['debited']} (debits by kind, at the window's close: "
+        f"{json.dumps(by_kind)}), {len(got['undue'])} of them gave no "
+        f"unsound answer (limit 0); peers that lied {got['liars']}, {len(got['kept'])} of them still connected at "
+        f"the window's end after a lie the node got past (limit 0); {len(forged)} applied heights hold another "
+        f"block than the source chain's (limit 0){': ' + str(forged[:8]) if forged else ''}"
+    )
+    failures = []
+    if forged:
+        failures.append(f"{len(forged)} applied heights hold another block than the source chain's: {forged[:8]}")
+    if got["undue"]:
+        failures.append(f"the node debited peers that gave no unsound answer: {got['undue']}")
+    if got["kept"]:
+        failures.append(f"the node still held peers that lied to it: {got['kept']}")
+    notes = {
+        **ended, "lies_sent": {i: sent for i, sent in lies.items() if sent}, "debited": got["debited"],
+        "debits_by_kind": by_kind,
+    }
+    if events is not None:
+        notes["events"] = [{**row, "debited": named(row["debited"])} for row in events]
+        for row in notes["events"]:
+            log(f"peer event: {json.dumps(row)}")
+    return {
+        "compared": {
+            "forged_blocks_applied": [len(forged), 0],
+            "peers_debited_undue": [len(got["undue"]), 0],
+            "liars_kept": [len(got["kept"]), 0],
+        },
+        "failures": failures, "debits_undue": got["debits_undue"], "ended": ended, "notes": notes,
+    }
+
+
 def run(ctx: dict) -> dict | None:
     log = ctx["log"]
     root, here = ctx["root"], ctx["here"]
     cell, config, mix, seed = ctx["cell"], ctx["config"], ctx["mix"], ctx["seed"]
     sys.path.insert(0, root)
     from benchmark.lib import chain as chainlib
+    from benchmark.lib import peers as peerlib
     from benchmark.lib import rpc
     from benchmark.lib.stats import percentile
 
@@ -215,7 +342,7 @@ def run(ctx: dict) -> dict | None:
     os.makedirs(work_root, exist_ok=True)
     work = tempfile.mkdtemp(prefix=f"{cell['name']}-{seed}-", dir=work_root)
     children: list[Child] = []
-    node = None
+    node = watch = None
     n_blocks = int(cell["chain_blocks"])
     try:
         # -- 1. the peer child: the chain from the seed, then it serves ----
@@ -226,19 +353,12 @@ def run(ctx: dict) -> dict | None:
         _evict_chains(chains_dir, key)
         if os.path.isdir(peer_home) and not os.path.exists(os.path.join(peer_home, "record.json")):
             shutil.rmtree(peer_home)  # a generation that was cut short
-        workers = max(1, min(10, (os.cpu_count() or 2) - 3))
-        peer = Child(
-            "peer",
-            [
-                sys.executable, "-m", "benchmark.lib.peer",
-                "--home", peer_home,
-                "--config", os.path.join(here, "configs", cell["config"] + ".json"),
-                "--mix", os.path.join(here, "traffic", cell["traffic"] + ".json"),
-                "--seed", str(seed), "--blocks", str(n_blocks), "--workers", str(workers),
-            ],
-            _child_env(root), root, work,
-        )
-        children.append(peer)
+        n_peers = peerlib.count(config)
+        serving = [
+            Child("peer" if i == 0 else f"peer{i}", argv, _child_env(root), root, work)
+            for i, argv in enumerate(peer_commands(here, cell, seed, peer_home, n_peers))
+        ]
+        children.extend(serving)
 
         # -- 2. JAX up in this process; no accelerator is a refusal --------
         if ctx["control"] == "host_answers":
@@ -276,15 +396,19 @@ def run(ctx: dict) -> dict | None:
 
             install_accept_all()
 
-        # -- 3. the peer is up ----------------------------------------------
-        m = _wait(lambda: PEER_UP.search(peer.output()), 900.0, "the peer", peer, tick=0.2)
-        p2p_port = int(m.group(1))
+        # -- 3. the peers are up ----------------------------------------------
+        p2p_ports = [
+            int(_wait(lambda c=c: PEER_UP.search(c.output()), 900.0, f"the {c.name}", c, tick=0.2).group(1))
+            for c in serving
+        ]
         t_peer = time.monotonic() - ctx["t0"]
         record = chainlib.Record.load(os.path.join(peer_home, "record.json"))
         log(
             f"peer up after {t_peer:.1f}s: {n_blocks} blocks, built in "
             f"{record.build_seconds:.1f}s ({chainlib.digest(record)})"
         )
+        if peerlib.keeps_notes(mix, n_peers):
+            log(f"{n_peers} serving peers on ports {p2p_ports}, peer rule {json.dumps(mix.get('peers'))}")
         if ctx["control"] == "apphash_off_by_one":
             record.app_hash = [""] + record.app_hash[:-1]
 
@@ -301,7 +425,7 @@ def run(ctx: dict) -> dict | None:
         cfg.rpc.laddr = "tcp://127.0.0.1:0"
         cfg.p2p.pex = False
         cfg.p2p.send_rate = cfg.p2p.recv_rate = int(config["p2p_rate_bytes_per_s"])
-        cfg.p2p.seeds = f"127.0.0.1:{p2p_port}"
+        cfg.p2p.seeds = seeds(p2p_ports)
         write_config(cfg)
         shutil.copy(os.path.join(peer_home, "genesis.json"), cfg.genesis_path())
         cfg = load_config(node_home)
@@ -319,6 +443,9 @@ def run(ctx: dict) -> dict | None:
         node.start()
         port = node.rpc_port
         log(f"node {node.node_id[:12]} up: rpc :{port}")
+        if "peers" in mix:
+            watch = PeerWatch(node)
+            watch.start()
 
         # -- 5. the client child ---------------------------------------------
         reads = mix["reads"]
@@ -361,7 +488,7 @@ def run(ctx: dict) -> dict | None:
             import faulthandler
 
             faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
-            print("---- peer log ----\n" + peer.output()[-3000:], file=sys.stderr)
+            print("---- peer log ----\n" + serving[0].output()[-3000:], file=sys.stderr)
             raise
         t_first_block = t_first[0]
         setup_s = time.monotonic() - ctx["t0"]
@@ -388,7 +515,10 @@ def run(ctx: dict) -> dict | None:
         def close_window() -> None:
             # on a thread of its own: in a traced run the main thread may
             # still be inside stop_trace when the window ends
-            closed.update(wall=time.time(), height=store.height, metrics=rpc.pull_metrics(port))
+            closed.update(
+                wall=time.time(), height=store.height, metrics=rpc.pull_metrics(port),
+                connected={p.id for p in node.switch.peers()},
+            )
 
         closer = threading.Timer(seconds, close_window)
         closer.start()
@@ -532,12 +662,26 @@ def run(ctx: dict) -> dict | None:
         compared["no_rate_read"] = [int("catchup_blocks_per_s" not in end_to_end), 0]
         if "catchup_blocks_per_s" not in end_to_end:
             failures.append("no two /status reads answered inside the window")
+        # the node's treatment of its peers against what each of them did
+        peers_seen = _judge_peers(
+            node, serving, peer_home, closed["connected"], metrics_end, h_close, record,
+            watch.stop() if watch is not None else None, log,
+        )
+        compared.update(peers_seen["compared"])
+        failures += peers_seen["failures"]
+        notes["peers"] = peers_seen["notes"]
+        if peerlib.keeps_notes(mix, n_peers):
+            # a peer of several, or under a rule, says at its end what it served
+            for child in serving:
+                child.stop()
+            notes["peers"]["heights_served"] = {
+                i: peerlib.heights_served(child.output()) for i, child in enumerate(serving)
+            }
         for f in failures[:8]:
             log(f"NOT CORRECT: {f}")
         if len(failures) > 8:
             log(f"NOT CORRECT: and {len(failures) - 8} more")
         heights_fetched = max(0, h_close - h_open)
-        refused = int(rpc.metric(metrics_end, "tendermint_p2p_peer_misbehavior_total"))
         stats = devices[0].memory_stats() or {}
         peak = max(
             int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devices
@@ -555,20 +699,24 @@ def run(ctx: dict) -> dict | None:
             }
         obs.update(
             end_to_end=end_to_end, correct=not failures, attempted=heights_fetched + len(rd),
-            failed=len(bad_reads) + refused + len(failures), device=device,
+            failed=len(bad_reads) + peers_seen["debits_undue"] + len(failures), device=device,
             checks={"failures": failures, "host_fallbacks": checked["host_fallbacks"],
                     "hash_host_fallbacks": checked["hash_host_fallbacks"], "compared": compared},
             breakdown=breakdown, notes=notes,
         )
+        # how many peers served and how many the node ended with go into the
+        # result line, and where the set changes the table cache's four
+        # events, before `compared`
+        obs["line_extras"] = {"peers": peers_seen["ended"]}
         if "valset" in mix:
-            # where the set changes, the table cache's four events go into
-            # the result line, before `compared`
-            obs["line_extras"] = {"table_cache_events": table_events}
+            obs["line_extras"]["table_cache_events"] = table_events
         return obs
     except RunFailure as e:
         print(f"benchmark: {e}", file=sys.stderr)
         raise SystemExit(2) from None
     finally:
+        if watch is not None:
+            watch.stop()
         if node is not None:
             try:
                 node.stop()

@@ -12,17 +12,26 @@ checks, cut to what a catch-up run can show).
    of the set the node applied (h - 1, h, h + 1, h + 2 around the first
    header that carries a new hash); the generator's record of the sets is
    compared with the reference's too.
-2. The last applied height's write is read back through `abci_query`.
+2. The last write of the height the app answers at (`/abci_info`; the
+   store and `/status` are a block ahead while that block is executed) is
+   read back through `abci_query`.
 3. Every verify launch of k*n >= 512 lanes was answered by a device
    backend, no breaker moved, no call fell back to the host; and the
    same of every Merkle tree of 8,192 leaves or more (a block's 10,000
-   txs): the hash spine answers it on the device.
+   txs): the hash spine answers it on the device. And the answers that
+   were due came (`device_answers_due`): a device verify launch where a
+   window carried 512 lanes or more, a device tree where a block held
+   8,192 txs or more, and on an accelerator at least one of either kind.
 4. The node's `/health` names the device JAX gave this process.
 5. The planted fault: the chain's last 16-commit window (heights the node
    never reaches in a run) with one seeded bit of one signature's R
    flipped goes through `ValidatorSet.verify_commit_batched` on the
    process's own verifier and is refused at exactly that height and
    validator; the clean window passes.
+6. Every height in the node's store holds the source chain's block
+   (`forged_blocks_applied`: all of them, by the store's metas), and, in
+   `drivers/catchup.py` over `lib/peers.py` `account`, the node debited
+   no peer that gave it no unsound answer and kept no peer that lied to it.
 
 Limits: every comparison is exact (limit 0). Each line printed gives the
 number compared beside its limit.
@@ -174,18 +183,69 @@ def check_sample(port: int, record, heights: list[int], sets: list[dict]) -> dic
 
 
 def check_last_write(port: int, record) -> list[str]:
-    """The write of the last applied height, read back. The node keeps
-    syncing while we ask, and a fixed key space is rewritten by every
-    block: any height applied between the two status reads may answer."""
-    h1 = int(rpc.call(port, "status")["sync_info"]["latest_block_height"])
+    """The last write of the height the APP answers at, read back. The
+    store, and `/status`, are a block ahead of the app while that block is
+    executed, so the height is the app's own (`/abci_info`), before and
+    after the query. The node keeps syncing while we ask, the app answers
+    from the state its `DeliverTx` calls are writing, and a fixed key
+    space is rewritten by every block: any height from the first reading
+    to the block in execution after the second may answer."""
+    h1 = int(rpc.call(port, "abci_info")["last_block_height"])
+    if h1 < 1:
+        return ["abci_info: the app says it has applied no block"]
     key, _ = record.last_write[h1 - 1]
     got = rpc.call(port, f"abci_query?data={key}")
-    h2 = int(rpc.call(port, "status")["sync_info"]["latest_block_height"])
+    h2 = int(rpc.call(port, "abci_info")["last_block_height"])
     value = got.get("value", "")
-    allowed = {record.last_write[h - 1][1] for h in range(h1, h2 + 1) if record.last_write[h - 1][0] == key}
+    top = min(h2 + 1, record.n_blocks)
+    allowed = {record.last_write[h - 1][1] for h in range(h1, top + 1) if record.last_write[h - 1][0] == key}
     if value not in allowed:
-        return [f"abci_query: key {bytes.fromhex(key)!r} holds {value!r}, not the write of heights {h1}..{h2}"]
+        return [f"abci_query: key {bytes.fromhex(key)!r} holds {value!r}, not the write of heights {h1}..{top}"]
     return []
+
+
+def forged_blocks_applied(store, record) -> list[int]:
+    """The heights in the node's store whose block id (the header's hash,
+    the part set's count and root: the root covers every byte of the
+    block, its `last_commit` with them) is not the source chain's. Every
+    height, from the store's metas, and not a sample: a forged block is
+    one height."""
+    forged = []
+    for h in range(1, min(store.height, record.n_blocks) + 1):
+        meta = store.load_block_meta(h)
+        bid = meta.block_id if meta is not None else None
+        if bid is None or (bid.hash.hex(), bid.parts_header.total, bid.parts_header.hash.hex()) != (
+            record.block_hash[h - 1], record.parts_total[h - 1], record.parts_hash[h - 1]
+        ):
+            forged.append(h)
+    return forged
+
+
+def device_answers_due(launches: list[dict], platform: str) -> list[str]:
+    """What a run on an accelerator owes of device answers, by the rule
+    `verify_host_answers` and `tree_host_answers` follow: a device verify
+    launch when some window of the run carried `DEVICE_MIN_LANES` real
+    lanes or more (the tagged launch records, host-answered ones with
+    them), a device tree when some block held `DEVICE_MIN_LEAVES` txs or
+    more, and at least one device answer of either kind: every cell drives
+    the device path. Returns what is owed and missing, in words."""
+    if platform == "cpu":
+        return []
+    verify = [r for r in launches if r.get("kind") in VERIFY_KINDS]
+    trees = [r for r in launches if r.get("kind") == "hash"]
+    by_device = [r for r in verify if not r.get("error") and r.get("backend") in DEVICE_BACKENDS]
+    trees_by_device = [r for r in trees if not r.get("error") and r.get("backend") in HASH_DEVICE_BACKENDS]
+    owed = []
+    if not by_device and any(
+        int(r.get("rows", 0)) + int(r.get("rows_cached", 0)) >= DEVICE_MIN_LANES
+        for r in verify if r.get("height_lo") is not None
+    ):
+        owed.append(f"a window carried >= {DEVICE_MIN_LANES} lanes and the launch ledger holds no device verify launch")
+    if not trees_by_device and any(int(r.get("rows", 0)) >= DEVICE_MIN_LEAVES for r in trees):
+        owed.append(f"a block held >= {DEVICE_MIN_LEAVES} txs and the launch ledger holds no device tree")
+    if not by_device and not trees_by_device:
+        owed.append("the launch ledger holds no device answer of either kind, verify or tree")
+    return owed
 
 
 def validator_set(record):
@@ -280,9 +340,10 @@ def run_checks(
     compared["tree_host_answers"] = [hash_fallbacks, 0]
     if hash_fallbacks:
         failures.append(f"{hash_fallbacks} Merkle trees of >= {DEVICE_MIN_LEAVES} leaves were not answered by the device")
-    verify_launches = [r for r in launches if r.get("kind") in VERIFY_KINDS and r.get("backend") in DEVICE_BACKENDS]
-    if devices[0].platform != "cpu" and not verify_launches:
-        failures.append("the launch ledger holds no device verify launch")
+    owed = device_answers_due(launches, devices[0].platform)
+    log(f"check device answers due: {len(owed)} kinds of answer owed and missing (limit 0)")
+    compared["device_answers_missing"] = [len(owed), 0]
+    failures += owed
     dev = health.get("device", {})
     got = (dev.get("platform"), dev.get("device_kind"), dev.get("device_count"))
     want = (devices[0].platform, devices[0].device_kind, len(devices))

@@ -27,7 +27,7 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "peers", "compared"}
 
 
 def bench_json() -> dict:
@@ -154,6 +154,102 @@ def test_kernel_metrics_count_only_what_the_device_answered():
     # only host launches inside the stretch: nothing to read
     obs["launches"] = obs["launches"][:1]
     assert ledger.traced_kernel(obs) is None
+
+
+def ledger_of(n_vals: int, txs: int, device: bool, blocks: int = 40) -> list[dict]:
+    """A hand-made launch ledger of a run on an accelerator: windows of 16
+    commits of `n_vals` validators (answered by the device where they carry
+    512 lanes or more and `device` holds, else by the host library) and, of
+    a block of 8,192 txs or more, a tree a block (a smaller one never goes
+    through the hash spine's launch records)."""
+    recs = []
+    for lo in range(1, blocks, 16):
+        on_device = device and 16 * n_vals >= 512
+        recs.append({"kind": "tables" if on_device else "verify", "backend": "tables" if on_device else "host",
+                     "t": 1000.0 + lo, "height_lo": lo, "height_hi": lo + 15, "rows": 16 * n_vals})
+    if txs >= 8192:
+        recs += [{"kind": "hash", "backend": "device" if device else "host", "t": 1000.0 + h, "rows": txs}
+                 for h in range(1, blocks)]
+    return recs
+
+
+@pytest.mark.parametrize(
+    "n_vals, txs, device, owed",
+    [
+        # `local-4` under `full`: no window ever reaches 512 lanes, and its 10,000-leaf trees are the device's
+        (4, 10_000, True, []),
+        # `local-4` under `sparse` drives no device path at all
+        (4, 3, True, ["no device answer of either kind"]),
+        # the four accepted cells owe a verify launch, as before
+        (100, 3, True, []), (1000, 3, True, []), (1000, 10_000, True, []),
+        (1000, 3, False, ["no device verify launch", "no device answer of either kind"]),
+        (100, 3, False, ["no device verify launch", "no device answer of either kind"]),
+        # 1,000 validators at 10,000 txs and nothing from the device: all three are owed
+        (1000, 10_000, False, ["no device verify launch", "no device tree", "no device answer of either kind"]),
+    ],
+)
+def test_a_run_owes_the_device_answers_its_shapes_make_due(n_vals, txs, device, owed):
+    from benchmark.lib import checks
+
+    got = checks.device_answers_due(ledger_of(n_vals, txs, device), "tpu")
+    assert len(got) == len(owed) and all(want in row for want, row in zip(owed, got)), got
+    # the CPU's rehearsals owe nothing: no launch there is the device's to answer
+    assert checks.device_answers_due(ledger_of(n_vals, txs, device), "cpu") == []
+
+
+def test_a_device_answer_is_owed_kind_by_kind():
+    from benchmark.lib import checks
+
+    # the trees reached the device and the 16,000-lane windows did not: the verify launch is still owed
+    mixed = [r for r in ledger_of(1000, 10_000, True) if r["kind"] == "hash"] + ledger_of(1000, 3, False)
+    assert [row.split(" and ")[0] for row in checks.device_answers_due(mixed, "tpu")] == ["a window carried >= 512 lanes"]
+    # a failed device launch is no answer; an empty ledger owes one of either kind
+    failed = [{**r, "error": "Boom"} for r in ledger_of(1000, 3, True)]
+    assert len(checks.device_answers_due(failed, "tpu")) == 2
+    assert checks.device_answers_due([], "tpu") == ["the launch ledger holds no device answer of either kind, verify or tree"]
+    # cached lanes count towards a window's size, as `verify_host_answers` counts them
+    cached = [{"kind": "verify", "backend": "host", "height_lo": 1, "height_hi": 16, "rows": 12, "rows_cached": 500}]
+    assert any("512 lanes" in row for row in checks.device_answers_due(cached, "tpu"))
+
+
+def test_the_last_write_is_asked_at_the_height_the_app_answers_at(monkeypatch):
+    """The store (and `/status`) is a block ahead of the app while that
+    block is executed: the check reads the app's own height, before and
+    after, and takes the write of any height from the first reading to the
+    block in execution after the second."""
+    from benchmark.lib import checks
+
+    class Record:
+        n_blocks = 9
+        # fresh keys: a key is written by one height; fixed keys: one key, rewritten by every block
+        last_write = [[f"{h:02x}", f"a{h}"] for h in range(1, 10)]
+
+    def node(app_heights, value, asked):
+        heights = iter(app_heights)
+
+        def call(port, route):
+            asked.append(route)
+            if route == "abci_info":
+                return {"last_block_height": next(heights)}
+            assert route.startswith("abci_query?data=")
+            return {"value": value}
+
+        return call
+
+    asked: list[str] = []
+    monkeypatch.setattr(checks.rpc, "call", node([5, 6], "a5", asked))
+    assert checks.check_last_write(0, Record) == []
+    assert asked == ["abci_info", "abci_query?data=05", "abci_info"] and "status" not in " ".join(asked)
+    # the app held the key of height 5 and answers with another height's write
+    monkeypatch.setattr(checks.rpc, "call", node([5, 5], "a4", []))
+    assert "not the write of heights 5..6" in checks.check_last_write(0, Record)[0]
+    Record.last_write = [["6b", f"v{h}"] for h in range(1, 10)]
+    for app, value, ok in (([5, 5], "v5", True), ([5, 5], "v6", True), ([5, 7], "v8", True), ([5, 5], "v4", False),
+                           ([5, 5], "v7", False), ([9, 9], "v9", True)):
+        monkeypatch.setattr(checks.rpc, "call", node(app, value, []))
+        assert (checks.check_last_write(0, Record) == []) is ok, (app, value)
+    monkeypatch.setattr(checks.rpc, "call", node([0], "", []))
+    assert "applied no block" in checks.check_last_write(0, Record)[0]
 
 
 def test_a_slow_answer_is_a_latency_and_not_a_failure():
@@ -289,7 +385,11 @@ def test_a_tiny_cell_end_to_end_from_new_files_alone(copy):
     # each number compared beside its limit: last in the line, and the last lines of standard error
     assert list(line)[-1] == "compared" and len(line["compared"]) >= 7
     assert all(number == limit == 0 for number, limit in line["compared"].values())
-    assert proc.stderr.strip().splitlines()[-1] == "compared no_rate_read: 0 (limit 0)"
+    # (the last three: the node's treatment of its peers, all sound here)
+    assert list(line["compared"])[-4:] == ["no_rate_read", "forged_blocks_applied", "peers_debited_undue", "liars_kept"]
+    assert proc.stderr.strip().splitlines()[-1] == "compared liars_kept: 0 (limit 0)"
+    # one serving peer, and the node ended with it
+    assert line["peers"] == {"served": 1, "connected_at_close": 1, "pool_peers_at_end": 1}
     assert line["device"]["platform"] == "cpu"
     # every line that carries a number names the device
     for row in proc.stdout.splitlines()[:-1]:

@@ -4,13 +4,14 @@
 
 Their arithmetic over a hand-made pair of `/metrics` pulls, what they give
 a program that has no such series (the parent of PR 38), the meta files
-against the entries `withheld/own_work.json` holds ready for
-`BENCHMARK.json`, the three cells' lists as the PR that lists them must
-leave them, and a traced run of a tiny cell that lists all seventeen.
+against the entries `withheld/own_work.json` held ready for
+`BENCHMARK.json`, the four cells' lists as PR 42 (`benchmark`) left
+them, and a traced run of a tiny cell that lists all seventeen.
 
-No cell lists them yet: PR 38 (`tracing`) may edit no file the benchmark
-already has, and `run.py::find_cell` takes a cell's readers from
-`cells/<cell>.json`. `listed()` below is what that edit is.
+PR 38 (`tracing`) could list them in no cell: it may edit no file the
+benchmark already has, and `run.py::find_cell` takes a cell's readers
+from `cells/<cell>.json`. `listed()` below is the edit PR 42 made, and
+the cells' files are held to it.
 """
 
 from __future__ import annotations
@@ -29,7 +30,7 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, ROOT)
 
-CELLS = ["fastsync-100.sparse", "fastsync-1k.sparse", "fastsync-1k.full"]
+CELLS = ["fastsync-100.sparse", "fastsync-1k.sparse", "fastsync-1k.full", "valchange-1k.rotate"]
 # the names other rehearsals pin (test_fastsync_1k.py, test_fastsync_1k_full.py)
 VERIFY_KERNEL = ["kernel.verify_us_per_sig", "kernel.verify_tables_roofline"]
 HASH_READERS = [
@@ -39,11 +40,13 @@ PR31 = [
     "verify.pad_lane_share", "verify.single_commit_launch_share",
     "process.gc_pause_share", "fastsync.valset_roots_per_block", "fastsync.vote_encodes_per_block",
 ]
-# file pairs only: the table build's beside PR 36's three (a static set
-# builds none inside a window), and the reader of the answers' bytes,
-# which REVIEW.md asked for after ISSUE 38 had fixed the seventeen
-UNLISTED = ["verify.table_build_ms", "entry.block_answer_bytes"]
-BUILD, ANSWER = UNLISTED
+# file pairs only until PR 42: the table build's beside PR 36's three,
+# listed in `valchange-1k.rotate` alone (a static set builds none inside a
+# window: a reader with nothing to read in a cell is not listed there), and
+# the reader of the answers' bytes, which REVIEW.md asked for after ISSUE 38
+# had fixed the seventeen, listed in every cell behind them
+LISTED_LATER = ["verify.table_build_ms", "entry.block_answer_bytes"]
+BUILD, ANSWER = LISTED_LATER
 
 
 def load(*parts):
@@ -63,25 +66,25 @@ def reader(name):
 
 
 def listed(cell: str) -> list[str]:
-    """`cells/<cell>.json`'s `layer_metrics` with the seventeen appended:
-    at the end, and in `fastsync-1k.full` before the four hash readers,
-    where `fastsync-1k.sparse`'s order puts them."""
-    names = [n for n in load("cells", cell + ".json")["layer_metrics"] if n not in NEW]
+    """`cells/<cell>.json`'s `layer_metrics` with the seventeen and the
+    answers' bytes appended: at the end, and in `fastsync-1k.full` before
+    the four hash readers, where `fastsync-1k.sparse`'s order puts them."""
+    names = [n for n in load("cells", cell + ".json")["layer_metrics"] if n not in [*NEW, ANSWER]]
     tail = [n for n in names if n in HASH_READERS] if cell == "fastsync-1k.full" else []
-    return [n for n in names if n not in tail] + NEW + tail
+    return [n for n in names if n not in tail] + NEW + [ANSWER] + tail
 
 
 # -- the lists -------------------------------------------------------------------
 
 
 def test_there_are_seventeen_each_a_file_pair_and_an_entry_ready_for_the_contract():
-    assert len(NEW) == 17 == len(set(NEW)) and not set(UNLISTED) & set(NEW)
+    assert len(NEW) == 17 == len(set(NEW)) and not set(LISTED_LATER) & set(NEW)
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     known = {m["name"]: m for m in bench["per_layer"]}
     layers = {m["layer"] for m in bench["per_layer"]}
     ready = {m["name"]: m for m in OWN["per_layer"]}
     assert list(ready) == NEW
-    for name in [*NEW, *UNLISTED]:
+    for name in [*NEW, *LISTED_LATER]:
         meta = load("layer_metrics", name + ".json")
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", name + ".py"))
         assert meta["name"] == name and len(name) <= 64 and meta["what"]
@@ -92,29 +95,40 @@ def test_there_are_seventeen_each_a_file_pair_and_an_entry_ready_for_the_contrac
         assert (meta["unit"], meta["better"]) in (
             ("ms", "lower"), ("%", "lower"), ("%", "higher"), ("reads", "lower"), ("bytes", "lower"),
         )
-        if name in UNLISTED:
-            assert name not in known and name not in ready
-            continue
-        entry = ready[name]
+        # BENCHMARK.json has the entry, and for the seventeen it is the one held ready
+        entry = known[name]
+        assert (name in LISTED_LATER and name not in ready) or entry == ready[name]
         assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-        assert entry["workloads"] == CELLS
-        # once BENCHMARK.json has the entry it is this one
+        assert entry["workloads"] == (CELLS[-1:] if name == BUILD else CELLS)
         for key in ("name", "unit", "better", "source", "layer", "moves"):
-            assert entry[key] == meta[key] == known.get(name, entry)[key]
+            assert entry[key] == meta[key]
 
 
-def test_the_three_cells_list_the_seventeen_in_one_order_and_the_old_pins_hold():
-    small, sparse, full = (listed(cell) for cell in CELLS)
-    for cell, names in zip(CELLS, (small, sparse, full)):
+def test_the_four_cells_list_the_seventeen_in_one_order_and_the_old_pins_hold():
+    small, sparse, full, rotate = (load("cells", cell + ".json")["layer_metrics"] for cell in CELLS)
+    for cell, names in zip(CELLS, (small, sparse, full, rotate)):
+        # the cell's file is the edit `listed()` describes
+        assert names == listed(cell)
         assert [n for n in names if n in NEW] == NEW and len(set(names)) == len(names)
-        # nothing that was listed is lost or moved against its neighbours
-        was = [n for n in load("cells", cell + ".json")["layer_metrics"] if n not in NEW]
-        assert [n for n in names if n not in NEW] == was
-    # test_fastsync_1k.py: what `fastsync-1k.sparse` lists beyond `fastsync-100.sparse` is PR 31's five
-    assert [n for n in sparse if n not in small] == PR31 and set(small) < set(sparse)
+    # test_fastsync_1k.py: what `fastsync-1k.sparse` lists beyond `fastsync-100.sparse` is PR 31's five,
+    # less the collector's share, which `fastsync-100.sparse` lists too since PR 42
+    assert [n for n in sparse if n not in small] == [n for n in PR31 if n != "process.gc_pause_share"]
+    assert set(small) < set(sparse) and "process.gc_pause_share" in small
     # test_fastsync_1k_full.py: `.full` is `.sparse` less the verify kernel's two, then the hash readers
     assert full == [n for n in sparse if n not in VERIFY_KERNEL] + HASH_READERS
-    assert small[-17:] == NEW == sparse[-17:] and full[-21:-4] == NEW
+    # test_valchange_1k_cell.py: the rotating set's cell lists the table build's reader, and no other cell does
+    assert BUILD in rotate and not any(BUILD in names for names in (small, sparse, full))
+    assert small[-18:] == [*NEW, ANSWER] == sparse[-18:] == rotate[-18:] and full[-22:-4] == [*NEW, ANSWER]
+
+
+def test_every_listed_name_has_an_entry_that_holds_the_cell_and_the_reverse():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    lists = {cell: load("cells", cell + ".json")["layer_metrics"] for cell in CELLS}
+    assert [w["name"] for w in bench["workloads"]] == CELLS
+    for m in bench["per_layer"]:
+        assert m["workloads"] == [cell for cell in CELLS if m["name"] in lists[cell]], m["name"]
+    named = {m["name"] for m in bench["per_layer"]}
+    assert all(set(names) <= named for names in lists.values())
 
 
 # -- the arithmetic ----------------------------------------------------------------
@@ -191,7 +205,7 @@ def hand_made() -> dict:
 
 def test_the_readers_over_a_hand_made_pair_of_pulls():
     obs = hand_made()
-    got = {name: reader(name)(obs) for name in [*NEW, *UNLISTED]}
+    got = {name: reader(name)(obs) for name in [*NEW, *LISTED_LATER]}
     approx = pytest.approx
     # the sync thread's stages but starved, decode (the p2p thread's) left out
     assert got["fastsync.cpu_ms_per_block"] == approx(0.5 + 0.75 + 0.25 + 2.0 + 1.0 + 2.5 + 4.0)
@@ -240,12 +254,12 @@ def test_a_program_without_the_series_gives_nothing_to_read():
         return rpc.parse_metrics("\n".join(lines) + "\n")
 
     obs = {"metrics_start": older(100), "metrics_end": older(1100), "window": [1000.0, 1030.0]}
-    assert [reader(name)(obs) for name in [*NEW, *UNLISTED]] == [None] * 19
+    assert [reader(name)(obs) for name in [*NEW, *LISTED_LATER]] == [None] * 19
     # the series there, and no block applied, no /block read, no build: nothing a block or a read
     same = hand_made()["metrics_end"]
     obs = {"metrics_start": same, "metrics_end": same, "window": [1000.0, 1030.0]}
     per_unit = [n for n in NEW if n.startswith("fastsync.") or n.startswith("entry.block_")] + [
-        "entry.rpc_running_share", *UNLISTED,
+        "entry.rpc_running_share", *LISTED_LATER,
     ]
     assert [reader(name)(obs) for name in per_unit] == [None] * len(per_unit)
 
@@ -270,7 +284,7 @@ def test_a_traced_tiny_cell_reports_all_seventeen(tmp_path):
     wall = [f"fastsync.{s}_ms_per_block" for s in ("part_set", "store", "state_save", "decode", "exec", "verify_submit")]
     json.dump(
         {"chain_blocks": 1200, "metrics": ["catchup_blocks_per_s", "setup_s"],
-         "layer_metrics": ["verify.host_fallbacks", "fastsync.accounted_share", *wall, *NEW, *UNLISTED]},
+         "layer_metrics": ["verify.host_fallbacks", "fastsync.accounted_share", *wall, *NEW, *LISTED_LATER]},
         open(tmp_path / "benchmark" / "cells" / "tiny16.trickle.json", "w"),
     )
     json.dump(
@@ -287,10 +301,10 @@ def test_a_traced_tiny_cell_reports_all_seventeen(tmp_path):
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300,
     )
     assert proc.returncode in (0, 1), proc.stdout[-3000:] + proc.stderr[-3000:]
-    # the harness's last-write check races the apply in about one tiny CPU
-    # run in ten (PERF.md section 7); nothing else may be wrong
+    # (nothing may be wrong: the last-write check, which raced the apply in about one
+    # tiny CPU run in ten, asks at the app's own height since PR 42)
     wrong = [row for row in proc.stdout.splitlines() if "NOT CORRECT" in row]
-    assert all("abci_query" in row for row in wrong), wrong
+    assert not wrong, wrong
     got = {k: v["value"] for k, v in json.loads(proc.stdout.strip().splitlines()[-1])["metrics"].items()}
     assert set(NEW) <= set(got), sorted(set(NEW) - set(got))
     assert BUILD not in got  # a static set: no build ends inside a window

@@ -32,6 +32,9 @@ PR31 = [
 ]
 PR36 = ["fastsync.boundary_window_share", "verify.table_cache_miss_share", "verify.table_incremental_share"]
 NEW = ["verify.table_keys_built_per_block", "verify.table_build_joined_share"]
+# listed since PR 42: the table build's reader here alone (a static set builds in set-up only), and in
+# every cell, last, PR 38's seventeen and the reader of a `/block` answer's bytes
+BUILD = "verify.table_build_ms"
 EVENTS = "tendermint_verify_table_cache_total"
 KEYS = "tendermint_verify_table_keys_built_total"
 
@@ -44,6 +47,9 @@ def load(*parts):
 def bench_json():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+LAST = [*load("tests", "withheld", "own_work.json")["layer_metrics"], "entry.block_answer_bytes"]
 
 
 def reader(name):
@@ -66,14 +72,14 @@ def test_the_cell_lists_what_the_issue_names():
     cell, sparse = load("cells", CELL + ".json"), load("cells", "fastsync-1k.sparse.json")
     assert cell["chain_blocks"] == 800 and cell["trace_seconds"] == 6
     assert cell["metrics"] == ["catchup_blocks_per_s", "setup_s"]
-    assert cell["layer_metrics"] == [n for n in sparse["layer_metrics"] if n not in PR31] + PR36 + NEW
-    assert set(PR31) <= set(sparse["layer_metrics"]) and len(cell["layer_metrics"]) == len(sparse["layer_metrics"])
-    # what the withheld cell file asked for, less the five a test pins to the two fastsync-1k cells
+    # `fastsync-1k.sparse`'s list with PR 31's five in it (since PR 42; a test pinned them to the two
+    # `fastsync-1k` cells before), then this cell's six, then what every cell lists last
+    assert cell["layer_metrics"] == [n for n in sparse["layer_metrics"] if n not in LAST] + PR36 + NEW + [BUILD] + LAST
+    assert set(PR31) <= set(sparse["layer_metrics"]) and len(cell["layer_metrics"]) == len(sparse["layer_metrics"]) + 6
+    # what the withheld cell file asked for, and what came after it
     withheld = load("tests", "withheld", CELL + ".json")
-    assert [n for n in withheld["layer_metrics"] if n not in PR31] + NEW == cell["layer_metrics"]
+    assert withheld["layer_metrics"] + NEW + [BUILD] + LAST == cell["layer_metrics"]
     assert {k: v for k, v in withheld.items() if k != "layer_metrics"} == {k: v for k, v in cell.items() if k != "layer_metrics"}
-    # the table build's reader stays a file pair only (test_own_work_metrics.py pins it unlisted)
-    assert "verify.table_build_ms" not in cell["layer_metrics"]
 
 
 def test_the_contract_has_four_cells_three_deployments_and_every_listed_reader_names_the_cell():
@@ -91,7 +97,7 @@ def test_the_contract_has_four_cells_three_deployments_and_every_listed_reader_n
     for name in load("cells", CELL + ".json")["layer_metrics"]:
         assert per_layer[name]["workloads"][-1] == CELL, name
         assert per_layer[name]["moves"] == "catchup_blocks_per_s"
-    for name in PR36 + NEW:
+    for name in PR36 + NEW + [BUILD]:
         assert per_layer[name]["workloads"] == [CELL]
     for name in NEW:
         meta = load("layer_metrics", name + ".json")
@@ -101,7 +107,9 @@ def test_the_contract_has_four_cells_three_deployments_and_every_listed_reader_n
     assert (per_layer[NEW[1]]["unit"], per_layer[NEW[1]]["better"]) == ("%", "higher")
     # the cell is in no list but those of the readers it lists, and the new entries come last
     assert {m["name"] for m in b["per_layer"] if CELL in m.get("workloads", [])} == set(load("cells", CELL + ".json")["layer_metrics"])
-    assert [m["name"] for m in b["per_layer"]][-5:] == PR36 + NEW
+    names = [m["name"] for m in b["per_layer"]]
+    at = names.index(PR36[0])
+    assert names[at : at + 5] == PR36 + NEW and names[at + 5 :] == LAST + [BUILD]
 
 
 # -- the two new readers ---------------------------------------------------------------
@@ -202,8 +210,8 @@ def test_the_listed_cell_rehearsed_on_the_cpu_reports_every_reader_it_can(scratc
                      "--trace", "1", "--allow-cpu-for-tests"])
     out, err = capfd.readouterr()
     assert code in (0, 1), out[-3000:] + err[-3000:]
-    # (the harness's last-write check races the apply in about one tiny CPU run in ten: PERF.md section 7)
-    wrong = [row for row in out.splitlines() if "NOT CORRECT" in row and "abci_query" not in row]
+    # (every row: the last-write check asks at the app's own height since PR 42 and races the apply no more)
+    wrong = [row for row in out.splitlines() if "NOT CORRECT" in row]
     assert not wrong, wrong
     line = json.loads(out.strip().splitlines()[-1])
     got = line["metrics"]
@@ -212,8 +220,10 @@ def test_the_listed_cell_rehearsed_on_the_cpu_reports_every_reader_it_can(scratc
     missing = [n for n in listed if n not in got]
     assert set(missing) <= {"kernel.verify_us_per_sig", "kernel.verify_tables_roofline", "device.idle_share",
                             "fastsync.commits_per_launch", "verify.prep_ms_per_commit",
-                            "verify.finalize_ms_per_launch"}, missing
-    assert not set(PR31) & set(got)
+                            "verify.finalize_ms_per_launch",
+                            # no launch here is the device's: the two launch-shape readers find nothing
+                            "verify.pad_lane_share", "verify.single_commit_launch_share"}, missing
+    assert set(PR31[2:]) <= set(got) and set(LAST) <= set(got) and BUILD in got
     assert got["fastsync.boundary_window_share"]["value"] > 50
     assert got["verify.table_incremental_share"]["value"] > 0
     assert got["verify.table_build_joined_share"] == {"value": 0.0, "unit": "%"}
