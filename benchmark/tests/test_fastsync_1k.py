@@ -78,11 +78,17 @@ def test_the_cell_is_the_mix_sparse_on_one_chip_and_lists_what_it_reports():
     added = [name for name in cell["layer_metrics"] if name not in old["layer_metrics"]]
     assert added == [n for n in SHAPE_READERS + COUNTER_READERS if n != "process.gc_pause_share"]
     assert set(old["layer_metrics"]) < set(cell["layer_metrics"])
-    per_layer = {m["name"]: m for m in b["per_layer"]}
-    # PR 31's five: the two cells of this deployment and, since PR 42, the rotating set's at the same 1,000 validators
+    # PR 31's five: every listed cell whose block carries 1,000 votes (since PR 42 the rotating set's too), and
+    # under them `fastsync-100.sparse` for the collector's share alone
+    from benchmark.tests import listing
+
+    tree = listing.Listing()
+    assert SHAPE_READERS + COUNTER_READERS == listing.PR31
+    at_1k = [c for c in tree.cells if tree.deployment(c)["validators"] >= 1000]
+    assert CELL in at_1k and "fastsync-100.sparse" not in at_1k
     for name in SHAPE_READERS + COUNTER_READERS:
-        at_1k = [CELL, "fastsync-1k.full", "valchange-1k.rotate"]
-        assert per_layer[name]["workloads"] == (["fastsync-100.sparse"] if name not in added else []) + at_1k
+        assert ("fastsync-100.sparse" in tree.per_layer[name]["workloads"]) == (name not in added)
+        assert [c for c in tree.per_layer[name]["workloads"] if c in at_1k] == at_1k
     # the mix is shared: its file names no deployment
     assert load("traffic", "sparse.json")["reads"]["per_s"] == 20
 

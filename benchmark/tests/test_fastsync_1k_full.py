@@ -25,11 +25,10 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, ROOT)
 
+from benchmark.tests import listing  # noqa: E402
+from benchmark.tests.listing import HASH_READERS, VERIFY_KERNEL  # noqa: E402
+
 CELL = "fastsync-1k.full"
-VERIFY_KERNEL = ["kernel.verify_us_per_sig", "kernel.verify_tables_roofline"]
-HASH_READERS = [
-    "hash.tree_ms_per_block", "hash.host_fallbacks", "kernel.merkle_us_per_leaf", "kernel.merkle_tree_roofline",
-]
 
 
 def load(*parts):
@@ -127,9 +126,15 @@ def test_the_cell_is_the_mix_full_on_one_chip_and_lists_what_it_reports():
     per_layer = {m["name"]: m for m in b["per_layer"]}
     for name in cell["layer_metrics"]:
         assert CELL in per_layer[name]["workloads"]
+    # the hash readers: every listed cell whose blocks are trees the device is due, this one among them
+    from benchmark.lib.checks import DEVICE_MIN_LEAVES
+
+    tree = listing.Listing()
+    full_blocks = [c for c in tree.cells if tree.mix(c)["txs"]["per_block"] >= DEVICE_MIN_LEAVES]
+    assert CELL in full_blocks
     for name in HASH_READERS:
         meta = load("layer_metrics", name + ".json")
-        assert per_layer[name]["workloads"] == [CELL] and meta["what"]
+        assert per_layer[name]["workloads"] == full_blocks and meta["what"]
         for key in ("name", "unit", "better", "source", "layer", "moves"):
             assert meta[key] == per_layer[name][key]
     assert per_layer["kernel.merkle_tree_roofline"]["unit"] == "%"

@@ -5,8 +5,9 @@
 Their arithmetic over a hand-made pair of `/metrics` pulls, what they give
 a program that has no such series (the parent of PR 38), the meta files
 against the entries `withheld/own_work.json` held ready for
-`BENCHMARK.json`, the four cells' lists as PR 42 (`benchmark`) left
-them, and a traced run of a tiny cell that lists all seventeen.
+`BENCHMARK.json`, the four accepted cells' lists as PR 42 (`benchmark`)
+left them (data of record, found by name; what any listed cell keeps is
+`listing.py`'s), and a traced run of a tiny cell that lists all seventeen.
 
 PR 38 (`tracing`) could list them in no cell: it may edit no file the
 benchmark already has, and `run.py::find_cell` takes a cell's readers
@@ -30,19 +31,12 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, ROOT)
 
-CELLS = ["fastsync-100.sparse", "fastsync-1k.sparse", "fastsync-1k.full", "valchange-1k.rotate"]
-# the names other rehearsals pin (test_fastsync_1k.py, test_fastsync_1k_full.py)
-VERIFY_KERNEL = ["kernel.verify_us_per_sig", "kernel.verify_tables_roofline"]
-HASH_READERS = [
-    "hash.tree_ms_per_block", "hash.host_fallbacks", "kernel.merkle_us_per_leaf", "kernel.merkle_tree_roofline",
-]
-PR31 = [
-    "verify.pad_lane_share", "verify.single_commit_launch_share",
-    "process.gc_pause_share", "fastsync.valset_roots_per_block", "fastsync.vote_encodes_per_block",
-]
+from benchmark.tests import listing  # noqa: E402
+from benchmark.tests.listing import HASH_READERS, PR31, VERIFY_KERNEL  # noqa: E402
+
 # file pairs only until PR 42: the table build's beside PR 36's three,
-# listed in `valchange-1k.rotate` alone (a static set builds none inside a
-# window: a reader with nothing to read in a cell is not listed there), and
+# listed where the set changes and nowhere else (a static set builds none inside
+# a window: a reader with nothing to read in a cell is not listed there), and
 # the reader of the answers' bytes, which REVIEW.md asked for after ISSUE 38
 # had fixed the seventeen, listed in every cell behind them
 LISTED_LATER = ["verify.table_build_ms", "entry.block_answer_bytes"]
@@ -67,10 +61,10 @@ def reader(name):
 
 def listed(cell: str) -> list[str]:
     """`cells/<cell>.json`'s `layer_metrics` with the seventeen and the
-    answers' bytes appended: at the end, and in `fastsync-1k.full` before
-    the four hash readers, where `fastsync-1k.sparse`'s order puts them."""
+    answers' bytes appended: at the end, and in a cell that lists the four
+    hash readers before those, where `fastsync-1k.sparse`'s order puts them."""
     names = [n for n in load("cells", cell + ".json")["layer_metrics"] if n not in [*NEW, ANSWER]]
-    tail = [n for n in names if n in HASH_READERS] if cell == "fastsync-1k.full" else []
+    tail = [n for n in names if n in HASH_READERS]
     return [n for n in names if n not in tail] + NEW + [ANSWER] + tail
 
 
@@ -79,9 +73,9 @@ def listed(cell: str) -> list[str]:
 
 def test_there_are_seventeen_each_a_file_pair_and_an_entry_ready_for_the_contract():
     assert len(NEW) == 17 == len(set(NEW)) and not set(LISTED_LATER) & set(NEW)
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    known = {m["name"]: m for m in bench["per_layer"]}
-    layers = {m["layer"] for m in bench["per_layer"]}
+    tree = listing.Listing()
+    known = tree.per_layer
+    layers = {m["layer"] for m in known.values()}
     ready = {m["name"]: m for m in OWN["per_layer"]}
     assert list(ready) == NEW
     for name in [*NEW, *LISTED_LATER]:
@@ -95,40 +89,33 @@ def test_there_are_seventeen_each_a_file_pair_and_an_entry_ready_for_the_contrac
         assert (meta["unit"], meta["better"]) in (
             ("ms", "lower"), ("%", "lower"), ("%", "higher"), ("reads", "lower"), ("bytes", "lower"),
         )
-        # BENCHMARK.json has the entry, and for the seventeen it is the one held ready
+        # BENCHMARK.json has the entry, and for the seventeen it is the one held ready, with the cells
+        # whose file lists the reader: every listed cell, but for the table build's, which a changing set's list
         entry = known[name]
-        assert (name in LISTED_LATER and name not in ready) or entry == ready[name]
         assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-        assert entry["workloads"] == (CELLS[-1:] if name == BUILD else CELLS)
+        assert (name in LISTED_LATER and name not in ready) or {k: v for k, v in entry.items() if k != "workloads"} == ready[name]
+        assert entry["workloads"] == ([c for c in tree.cells if "valset" in tree.mix(c)] if name == BUILD else tree.cells)
         for key in ("name", "unit", "better", "source", "layer", "moves"):
             assert entry[key] == meta[key]
 
 
-def test_the_four_cells_list_the_seventeen_in_one_order_and_the_old_pins_hold():
-    small, sparse, full, rotate = (load("cells", cell + ".json")["layer_metrics"] for cell in CELLS)
-    for cell, names in zip(CELLS, (small, sparse, full, rotate)):
+def test_the_four_accepted_cells_list_the_seventeen_in_one_order_and_the_old_pins_hold():
+    accepted = {"small": "fastsync-100.sparse", "sparse": "fastsync-1k.sparse", "full": "fastsync-1k.full", "rotate": "valchange-1k.rotate"}
+    lists = {cell: load("cells", cell + ".json")["layer_metrics"] for cell in accepted.values()}
+    for cell, names in lists.items():
         # the cell's file is the edit `listed()` describes
         assert names == listed(cell)
         assert [n for n in names if n in NEW] == NEW and len(set(names)) == len(names)
+    small, sparse, full, rotate = (lists[accepted[k]] for k in ("small", "sparse", "full", "rotate"))
     # test_fastsync_1k.py: what `fastsync-1k.sparse` lists beyond `fastsync-100.sparse` is PR 31's five,
     # less the collector's share, which `fastsync-100.sparse` lists too since PR 42
     assert [n for n in sparse if n not in small] == [n for n in PR31 if n != "process.gc_pause_share"]
     assert set(small) < set(sparse) and "process.gc_pause_share" in small
     # test_fastsync_1k_full.py: `.full` is `.sparse` less the verify kernel's two, then the hash readers
     assert full == [n for n in sparse if n not in VERIFY_KERNEL] + HASH_READERS
-    # test_valchange_1k_cell.py: the rotating set's cell lists the table build's reader, and no other cell does
+    # test_valchange_1k_cell.py: the rotating set's cell lists the table build's reader, and no static set's does
     assert BUILD in rotate and not any(BUILD in names for names in (small, sparse, full))
     assert small[-18:] == [*NEW, ANSWER] == sparse[-18:] == rotate[-18:] and full[-22:-4] == [*NEW, ANSWER]
-
-
-def test_every_listed_name_has_an_entry_that_holds_the_cell_and_the_reverse():
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    lists = {cell: load("cells", cell + ".json")["layer_metrics"] for cell in CELLS}
-    assert [w["name"] for w in bench["workloads"]] == CELLS
-    for m in bench["per_layer"]:
-        assert m["workloads"] == [cell for cell in CELLS if m["name"] in lists[cell]], m["name"]
-    named = {m["name"] for m in bench["per_layer"]}
-    assert all(set(names) <= named for names in lists.values())
 
 
 # -- the arithmetic ----------------------------------------------------------------

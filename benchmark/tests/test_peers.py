@@ -6,9 +6,10 @@ the CPU:
 That one peer without a rule is started and dialled as it always was and
 serves the chain it always did; the rule `flip_sig` and the peer's lie over
 hand-made arguments; the accounting over hand-made observations; the files
-of the two rehearsal deployments (`withheld/`: the benchmark lists neither,
-as PR 36 withheld `valchange-1k.rotate`); and both rehearsed end to end
-with the validator count cut. Nothing here yields a device number.
+of the withheld deployment `fastsync-1k-p4` and mix `liar` (`withheld/`, as
+PR 36 withheld `valchange-1k.rotate`: listed byte for byte, when a PR lists
+them); and two rehearsal deployments cut from them, one of four peers and
+one of one, rehearsed end to end. Nothing here yields a device number.
 """
 
 from __future__ import annotations
@@ -271,11 +272,9 @@ def test_the_rehearsal_deployment_is_fastsync_1k_from_four_peers():
     assert mix["name"] == "liar" and mix["peers"] == RULE and mix["peers"]["from_height"] > mix["warm_blocks"]
     for name in ("fastsync-1k-p4.sparse", "fastsync-1k-p4.liar"):
         assert withheld(name + ".json") == load("cells", "fastsync-1k.sparse.json")
-    # withheld: the benchmark lists neither the deployment, nor the mix, nor a cell of theirs
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        b = json.load(f)
-    assert not [c for c in b["configs"] if "p4" in c["name"]] and not [w for w in b["workloads"] if "p4" in w["name"]]
-    assert not os.path.exists(os.path.join(BENCH, "traffic", "liar.json"))
+    # withheld, or listed byte for byte with the cell's list begun as withheld: `listing.py`
+    # `withheld_file_is_listed_as_it_is`, which test_listing.py runs over every withheld file, on the
+    # tree and on a copy that lists the cell
 
 
 # -- both rehearsed, with the validator count cut ---------------------------------------------------------
@@ -296,7 +295,8 @@ def scratch(tmp_path_factory):
         b = json.load(f)
     shutil.copy(os.path.join(HERE, "withheld", "liar.json"), top / "benchmark" / "traffic" / "liar.json")
     cell = {**withheld("fastsync-1k-p4.sparse.json"), "chain_blocks": 1500}
-    for name, n_peers in (("fastsync-1k-p4", 4), ("fastsync-1k-p1", 1)):
+    # under names no listed deployment will carry: `BENCHMARK.json` may list `fastsync-1k-p4` itself by then
+    for name, n_peers in (("rehearsal-p4", 4), ("rehearsal-p1", 1)):
         cfg = {**withheld("fastsync-1k-p4.json"), "name": name, "validators": 13, "peers": n_peers}
         with open(top / "benchmark" / "configs" / f"{name}.json", "w") as f:
             json.dump(cfg, f)
@@ -331,24 +331,30 @@ def wrong_rows(text) -> list[str]:
 
 
 def test_four_sound_peers_all_serve_and_the_node_keeps_all_four(scratch):
-    proc, line, detail = run_cell(scratch, "fastsync-1k-p4.sparse", 4200000007)
+    proc, line, detail = run_cell(scratch, "rehearsal-p4.sparse", 4200000007)
     assert not wrong_rows(proc.stdout), wrong_rows(proc.stdout)
     assert line["correct"] is True and line["failed"] == 0
     assert line["peers"] == {"served": 4, "connected_at_close": 4, "pool_peers_at_end": 4}
     for name in ("forged_blocks_applied", "peers_debited_undue", "liars_kept"):
         assert line["compared"][name] == [0, 0]
     assert "4 serving peers on ports" in proc.stdout and "peers: {" in proc.stderr
-    # every height the node asked for went out once, from the peer the pool asked
-    # (the pool takes a block from no other), and every peer served its share
+    # every height the node applied went out, from the peer the pool asked (the pool takes
+    # a block from no other), and every peer served its share. Not held, because the pool
+    # and not the harness decides it: that a height goes out once (three of 548 went out
+    # twice beside eleven other workers: a request the pool gave to a second peer when the
+    # first was slow) and that none is missing past the window's last (662 missing beside
+    # 663 in two of 36 runs of this file under `-n 6`: a request still with its peer when
+    # the run stopped them)
     served = detail["notes"]["peers"]["heights_served"]
-    heights = sorted(h for sent in served.values() for h in sent)
-    assert heights == list(range(1, len(heights) + 1)) and len(heights) > detail["notes"]["heights"][1]
+    heights = {h for sent in served.values() for h in sent}
+    h_close = detail["notes"]["heights"][1]
+    assert set(range(1, h_close + 1)) <= heights and len(heights) > h_close
     assert len(served) == 4 and min(len(sent) for sent in served.values()) > len(heights) // 8
     assert detail["notes"]["peers"]["lies_sent"] == {} and detail["notes"]["peers"]["debited"] == []
 
 
 def test_one_peer_under_the_changed_harness_reads_as_it_did(scratch):
-    proc, line, detail = run_cell(scratch, "fastsync-1k-p1.sparse", 4200000007)
+    proc, line, detail = run_cell(scratch, "rehearsal-p1.sparse", 4200000007)
     assert not wrong_rows(proc.stdout), wrong_rows(proc.stdout)
     assert line["correct"] is True and line["failed"] == 0
     assert line["peers"] == {"served": 1, "connected_at_close": 1, "pool_peers_at_end": 1}
@@ -357,25 +363,34 @@ def test_one_peer_under_the_changed_harness_reads_as_it_did(scratch):
     assert "heights_served" not in detail["notes"]["peers"] and "serving peers on ports" not in proc.stdout
     # the same seed's chain, whoever serves it
     ours = next(row for row in proc.stdout.splitlines() if "peer up after" in row).rsplit("(", 1)[1]
-    four = run_cell(scratch, "fastsync-1k-p4.sparse", 4200000007)[0].stdout
+    four = run_cell(scratch, "rehearsal-p4.sparse", 4200000007)[0].stdout
     assert ours == next(row for row in four.splitlines() if "peer up after" in row).rsplit("(", 1)[1]
 
 
 @pytest.fixture(scope="module")
 def liar_run(scratch):
-    return run_cell(scratch, "fastsync-1k-p4.liar", 4200000011)
+    return run_cell(scratch, "rehearsal-p4.liar", 4200000011)
 
 
 def test_the_liar_cell_runs_and_says_who_lied_who_was_debited_and_when(liar_run):
-    """What holds of the rehearsal whoever the program blames: the rule's
-    first lie is asked for and sent (the pool's first wave hands heights
-    out round-robin, and height 200 falls to the fourth peer), the node
-    gets past it within the window, no forged block is applied, and the
-    run says what happened."""
+    """What holds of the rehearsal whoever the program blames and whoever
+    the pool asked for which height (its first wave hands heights out
+    round-robin and height 200 falls to the fourth peer, when nothing else
+    loads the machine; beside five other workers the node asked another
+    peer for 200 and the first lie was for 214): every lie sent is the
+    liar's and at a height the rule names, the node gets past the first of
+    them, no forged block is applied, and the run says what happened. (That
+    the first lie falls inside the window holds on the chip, where the
+    window opens near the mix's 48 warm blocks; here blocks go by at over
+    a hundred a second while set-up lasts, and beside five other workers
+    one run in 37 opened its window at height 221.)"""
     proc, line, detail = liar_run
     peers = detail["notes"]["peers"]
-    assert peers["lies_sent"]["3"][0][:2] == ["flip_sig", 200] and set(peers["lies_sent"]) == {"3"}
-    assert detail["notes"]["heights"][1] > 200 and "catchup_blocks_per_s" in line["metrics"]
+    assert set(peers["lies_sent"]) == {str(i) for i in RULE["liars"]}
+    lies = [(kind, height) for sent in peers["lies_sent"].values() for kind, height, _wall in sent]
+    assert {kind for kind, _h in lies} == {RULE["kind"]}
+    assert all(h >= RULE["from_height"] and (h - RULE["from_height"]) % RULE["every"] == 0 for _k, h in lies), lies
+    assert min(h for _k, h in lies) < detail["notes"]["heights"][1] and "catchup_blocks_per_s" in line["metrics"]
     assert line["compared"]["forged_blocks_applied"] == [0, 0] and line["peers"]["served"] == 4
     assert peers["debited"] and sum(peers["debits_by_kind"].values()) >= 1
     # who was debited and when: a row a change, all four connected in the first

@@ -82,34 +82,32 @@ def test_the_cell_lists_what_the_issue_names():
     assert {k: v for k, v in withheld.items() if k != "layer_metrics"} == {k: v for k, v in cell.items() if k != "layer_metrics"}
 
 
-def test_the_contract_has_four_cells_three_deployments_and_every_listed_reader_names_the_cell():
-    b = bench_json()
-    assert [w["name"] for w in b["workloads"]] == ["fastsync-100.sparse", "fastsync-1k.sparse", "fastsync-1k.full", CELL]
-    assert [c["name"] for c in b["configs"]] == ["fastsync-100", "fastsync-1k", "valchange-1k"]
-    entry = b["workloads"][-1]
+def test_the_cells_entries_say_what_its_files_say_and_its_six_readers_are_a_changing_sets():
+    """The cell's own entries, found by name. What every listed cell's
+    entries keep, whichever cells there are, is `listing.py`'s, run by
+    test_listing.py: an entry's `workloads` is the cells whose file lists
+    the reader, and the six of a changing set are listed exactly where
+    the mix carries `valset`."""
+    from benchmark.tests import listing
+
+    tree = listing.Listing()
+    entry = tree.workload(CELL)
     assert (entry["config"], entry["traffic"], entry["chips"]) == ("valchange-1k", "rotate", 1)
     assert len(entry["why"]) <= 200 and "\n" not in entry["why"]
-    config, doc = b["configs"][-1], load("configs", "valchange-1k.json")
+    config, doc = tree.config_entry("valchange-1k"), load("configs", "valchange-1k.json")
     assert config["file"] == "benchmark/configs/valchange-1k.json" and config["reduced"] == ["source_blocks"] == doc["reduced"]
     assert config["source"] == doc["source"] and len(config["source"]) <= 200 and len(config["why"]) <= 200
     assert doc["app"] == "persistent_kvstore" and doc["validators"] == 1000 and load("traffic", "rotate.json")["name"] == "rotate"
-    per_layer = {m["name"]: m for m in b["per_layer"]}
-    for name in load("cells", CELL + ".json")["layer_metrics"]:
-        assert per_layer[name]["workloads"][-1] == CELL, name
-        assert per_layer[name]["moves"] == "catchup_blocks_per_s"
+    assert "valset" in tree.mix(CELL) and PR36 + NEW + [BUILD] == listing.VALSET_READERS
+    per_layer = tree.per_layer
     for name in PR36 + NEW + [BUILD]:
-        assert per_layer[name]["workloads"] == [CELL]
+        assert per_layer[name]["workloads"] == [c for c in tree.cells if "valset" in tree.mix(c)]
     for name in NEW:
         meta = load("layer_metrics", name + ".json")
         assert (meta["layer"], meta["source"], meta["what"] != "") == ("verify spine", "program_counter", True)
         assert {k: per_layer[name][k] for k in ("unit", "better")} == {k: meta[k] for k in ("unit", "better")}
     assert (per_layer[NEW[0]]["unit"], per_layer[NEW[0]]["better"]) == ("keys/block", "lower")
     assert (per_layer[NEW[1]]["unit"], per_layer[NEW[1]]["better"]) == ("%", "higher")
-    # the cell is in no list but those of the readers it lists, and the new entries come last
-    assert {m["name"] for m in b["per_layer"] if CELL in m.get("workloads", [])} == set(load("cells", CELL + ".json")["layer_metrics"])
-    names = [m["name"] for m in b["per_layer"]]
-    at = names.index(PR36[0])
-    assert names[at : at + 5] == PR36 + NEW and names[at + 5 :] == LAST + [BUILD]
 
 
 # -- the two new readers ---------------------------------------------------------------
