@@ -557,6 +557,13 @@ class Node:
         self._p2p_running = True
         for addr in self._persistent_addrs:
             self._spawn_persistent_dial(addr)
+        # what the process holds by now it holds for life (the modules, the
+        # traced programs of every executable met so far): the collector
+        # stops walking it (a no-op when nothing was traced since the last
+        # settle of this process)
+        from tendermint_tpu.telemetry.process import settle_heap
+
+        settle_heap()
 
     def dial_seed(self, addr: str) -> None:
         """Dial one seed address; failures are logged, not raised (the

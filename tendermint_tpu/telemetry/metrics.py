@@ -512,8 +512,10 @@ PROCESS_THREADS = Gauge(
 )
 PROCESS_GC_PAUSE = Histogram(
     "tendermint_process_gc_pause_seconds",
-    "Stop-the-world GC collection pauses (gc.callbacks timing; "
-    "installed by telemetry/process.py install_gc_telemetry)",
+    "Stop-the-world GC collection pauses by generation (gc.callbacks "
+    "timing; installed by telemetry/process.py install_gc_telemetry): "
+    "gen=2 walks everything that is not frozen",
+    labelnames=("gen",),
     buckets=LATENCY_BUCKETS,
 )
 PROCESS_GC_COLLECTIONS = Counter(
@@ -522,8 +524,10 @@ PROCESS_GC_COLLECTIONS = Counter(
     labelnames=("gen",),
 )
 
+# the hook never takes a family lock, so it finds its children here
 for _gen in ("0", "1", "2"):
     PROCESS_GC_COLLECTIONS.labels(gen=_gen).inc(0)
+    PROCESS_GC_PAUSE.labels(gen=_gen)
 
 # live views cost nothing between scrapes (same discipline as the
 # node-bound gauges below, but process-scoped so no node is needed)
@@ -532,6 +536,20 @@ from tendermint_tpu.telemetry import process as _process  # noqa: E402
 PROCESS_RSS.set_function(_process.rss_bytes)
 PROCESS_FDS.set_function(_process.open_fds)
 PROCESS_THREADS.set_function(_process.thread_count)
+PROCESS_GC_FROZEN = Gauge(
+    "tendermint_process_gc_frozen_objects",
+    "Objects the collector no longer walks (gc.get_freeze_count, read at "
+    "scrape time): what telemetry/process.py settle_heap froze, JAX's "
+    "traced programs before all; hundreds of thousands on a node that has "
+    "met its executables, 0 on one whose collector policy never engaged",
+)
+PROCESS_GC_FROZEN.set_function(_process.frozen_objects)
+PROCESS_HEAP_SETTLES = CallbackCounter(
+    "tendermint_process_heap_settles_total",
+    "Times the heap was frozen (telemetry/process.py settle_heap): once "
+    "at node start and once more after each executable met later",
+    _process.heap_settles,
+)
 PROCESS_CPU_SECONDS = CallbackCounter(
     "tendermint_process_cpu_seconds_total",
     "CPU time of this process, every thread of it (time.process_time, "

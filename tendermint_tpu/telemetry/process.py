@@ -17,7 +17,7 @@ Exported through the catalog (`telemetry/metrics.py`):
   interpreter: the process's CPU clock and every live thread's, summed
   by the contention profiler's thread classes; read at scrape time
   only, nothing on any hot path (`cpu_seconds`, `thread_cpu_seconds`).
-* ``tendermint_process_gc_pause_seconds`` +
+* ``tendermint_process_gc_pause_seconds{gen}`` +
   ``tendermint_process_gc_collections_total{gen}`` — a `gc.callbacks`
   hook stamps `perf_counter` across each collection. CPython invokes
   the callbacks on whichever thread triggered the collection, start
@@ -30,6 +30,12 @@ Exported through the catalog (`telemetry/metrics.py`):
   and waiting there is waiting for oneself, which left ``/metrics`` and
   ``dump_telemetry`` unanswered for the rest of the node's life. What
   it cannot count at once it keeps and counts at the next collection.
+* ``tendermint_process_gc_frozen_objects`` +
+  ``tendermint_process_heap_settles_total`` — the collector's policy
+  (`settle_heap`): what a process keeps for its whole life, JAX's traced
+  programs before all, is frozen once its executables are met, so a
+  collection of the oldest generation walks the blocks in flight and not
+  half a million jaxpr nodes. Both are read at scrape time only.
 """
 
 from __future__ import annotations
@@ -151,36 +157,45 @@ _install_lock = threading.Lock()
 _gc_started_at: float | None = None
 # collections seen but not yet in the registry (its lock was held)
 _uncounted_gens: "deque[str]" = deque()
-_uncounted_pauses: "deque[float]" = deque()
+_uncounted_pauses: "deque[tuple[str, float]]" = deque()
 _GENERATIONS = ("0", "1", "2")
 
 
 def _gc_callback(phase: str, info: dict) -> None:
-    global _gc_started_at
+    global _gc_started_at, _heap_unsettled
     if phase == "start":
         _gc_started_at = time.perf_counter()
         return
     started = _gc_started_at
     _gc_started_at = None
     gen = str(info.get("generation", "?"))
-    if gen in _GENERATIONS:
-        _uncounted_gens.append(gen)
+    if gen not in _GENERATIONS:
+        return
+    _uncounted_gens.append(gen)
     if started is not None:
-        _uncounted_pauses.append(time.perf_counter() - started)
+        _uncounted_pauses.append((gen, time.perf_counter() - started))
+    if gen == "2" and _heap_unsettled and _settles:
+        # an executable met since the last settle left its jaxprs on the
+        # heap, and this collection has just paid for walking them
+        _heap_unsettled = False
+        _freeze()
     _count_what_can_be()
 
 
 def _count_what_can_be() -> None:
     """Move the kept collections into the two families without ever
-    waiting: `Counter.labels()` takes the family lock too, so the
-    pre-seeded children are read straight from the map."""
+    waiting: `labels()` takes the family lock too, so the pre-seeded
+    children are read straight from the map."""
     from tendermint_tpu.telemetry import metrics as _m
 
     collections = _m.PROCESS_GC_COLLECTIONS._children
     while _uncounted_gens and collections[(_uncounted_gens[0],)].try_inc():
         _uncounted_gens.popleft()
-    pause = _m.PROCESS_GC_PAUSE._child0()
-    while _uncounted_pauses and pause.try_observe(_uncounted_pauses[0]):
+    pauses = _m.PROCESS_GC_PAUSE._children
+    while _uncounted_pauses:
+        gen, seconds = _uncounted_pauses[0]
+        if not pauses[(gen,)].try_observe(seconds):
+            break
         _uncounted_pauses.popleft()
 
 
@@ -195,3 +210,91 @@ def install_gc_telemetry() -> bool:
         gc.callbacks.append(_gc_callback)
         _installed = True
         return True
+
+
+# -- the collector's policy: a static heap is walked once ------------------------
+
+# True until the first settle, and again once an executable has been met
+# (`utils/jax_cache.py` hears every one built or loaded): what tracing
+# left on the heap is not frozen yet
+_heap_unsettled = True
+_settles = 0
+_settle_lock = threading.Lock()
+# The young generation's threshold once the heap is frozen (CPython's own
+# is 700). With the jaxprs frozen the survivors of a full collection are
+# the blocks in flight alone, CPython's quarter rule is met at once, and
+# at 700 a catching-up node at 1,000 validators (3,000 containers a
+# block) ran 4,800 young, 440 middle and 33 full collections in 30 s:
+# 5.2-5.7% of the window (12.1% before the freeze), the full ones 0.03-
+# 0.09 s each. At 50,000, about a pool batch's worth of votes, most of a
+# block's containers die by reference count before any collection looks
+# at them: 40 young, 4 middle and no full collection in 30 s, 2.0-2.2%
+# of the window, the longest pause 0.056 s (my chip runs, PR 44:
+# `fastsync-1k.sparse`, same seeds).
+YOUNG_GENERATION_THRESHOLD = 50_000
+
+
+def mark_heap_unsettled() -> None:
+    global _heap_unsettled
+    _heap_unsettled = True
+
+
+def _freeze() -> None:
+    global _settles
+    gc.freeze()
+    _settles += 1
+
+
+def settle_heap() -> bool:
+    """Freeze what the process holds now, if anything was traced since
+    the last settle; True when it did. JAX keeps every traced program for
+    the life of the process (one `verify_commits` leaves 455,000 tracked
+    objects: `JaxprEqn`, `SourceInfo`, `Var`, their lists and tuples), and
+    CPython walks all of them at each collection of the oldest generation:
+    0.3-0.4 s with every thread of the node stopped, eight times in 30 s
+    of catch-up at 1,000 validators. One full collection first, so that
+    no cyclic garbage is frozen in, then `gc.freeze()`: a frozen object is
+    still freed when its last reference goes, it is only never walked
+    again. What that costs: a reference cycle made of objects that were
+    alive at a settle is never collected, so a settle happens once a
+    process and once more for each executable met later (`Node.start`
+    calls this; after a later compile the collector's own hook freezes at
+    the end of its next full collection, which has just paid for the
+    walk), never per block, per peer or per validator-set change. RSS
+    therefore keeps what was frozen and later fell into a cycle: for a
+    node, the reactors of a peer that left, bounded by the settles.
+    A second call with nothing traced since is a no-op: tests start
+    hundreds of nodes in one process.
+
+    With the static heap gone from the walk, the young generation is
+    sized to `YOUNG_GENERATION_THRESHOLD` (the other two thresholds stay
+    the interpreter's). What can wait: a reference cycle that dies young
+    is found once 50,000 more containers have been allocated than freed
+    (700 before), one that had survived a young collection at the middle
+    generation's turn, ten young collections on; so in the worst case,
+    garbage that is all cycles, half a million containers and what they
+    alone hold stand uncollected, a hundred megabytes at a vote's size.
+    What dies by reference count is freed at once as before, and a
+    block, its parts and its votes hold no cycle: the node's RSS over a
+    window of catch-up reads within 0.3% of what it read before."""
+    global _heap_unsettled
+    with _settle_lock:
+        if not _heap_unsettled:
+            return False
+        # the flag first: an executable met from here on is frozen next
+        # time, and the hook leaves this collection to its caller
+        _heap_unsettled = False
+        gc.collect()
+        _freeze()
+        gc.set_threshold(YOUNG_GENERATION_THRESHOLD, *gc.get_threshold()[1:])
+        return True
+
+
+def heap_settles() -> dict:
+    return {(): float(_settles)}
+
+
+def frozen_objects() -> float:
+    """Objects the collector no longer walks (`gc.get_freeze_count()`
+    walks their list: scrape time only)."""
+    return float(gc.get_freeze_count())

@@ -23,7 +23,7 @@ from __future__ import annotations
 import os
 import pathlib
 
-from tendermint_tpu.telemetry import metrics
+from tendermint_tpu.telemetry import metrics, process
 
 CHECKOUT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
 
@@ -35,27 +35,33 @@ _CACHE_EVENTS = {
 _listening = False
 
 
+def _on_event(event: str, **_kw) -> None:
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is not None:
+        metrics.XLA_CACHE_EVENTS.labels(event=outcome).inc()
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        metrics.XLA_COMPILE_SECONDS.labels(
+            fun=str(kw.get("fun_name", ""))
+        ).observe(duration)
+        # what tracing it left on the heap is not frozen yet
+        process.mark_heap_unsettled()
+
+
 def _listen() -> None:
     """Feed JAX's own compile / persistent-cache events into the metric
     catalog (once per process): compile seconds per jitted function,
     apart from any launch's run time, and cache hits vs misses — what
-    lets an outside process see that a restart found its executables."""
+    lets an outside process see that a restart found its executables.
+    Every executable built or loaded also marks the heap unsettled
+    (`telemetry/process.py` `settle_heap`)."""
     global _listening
     if _listening:
         return
     _listening = True
     import jax.monitoring
-
-    def _on_event(event: str, **_kw) -> None:
-        outcome = _CACHE_EVENTS.get(event)
-        if outcome is not None:
-            metrics.XLA_CACHE_EVENTS.labels(event=outcome).inc()
-
-    def _on_duration(event: str, duration: float, **kw) -> None:
-        if event == _COMPILE_EVENT:
-            metrics.XLA_COMPILE_SECONDS.labels(
-                fun=str(kw.get("fun_name", ""))
-            ).observe(duration)
 
     jax.monitoring.register_event_listener(_on_event)
     jax.monitoring.register_event_duration_secs_listener(_on_duration)

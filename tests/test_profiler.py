@@ -45,7 +45,9 @@ def _hist_count(name: str, **labels) -> int:
     fam = REGISTRY.get(name)
     if fam is None:
         return 0
-    want = tuple(str(labels[n]) for n in fam.labelnames) if labels else ()
+    if not labels:  # every series of the family (the gc pauses are by `gen`)
+        return sum(snap["count"] for _values, snap in fam.samples())
+    want = tuple(str(labels[n]) for n in fam.labelnames)
     for values, snap in fam.samples():
         if values == want:
             return snap["count"]

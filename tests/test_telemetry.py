@@ -599,3 +599,152 @@ class TestHistogramExemplars:
         h.labels(stage="verify").observe(0.2)
         assert h.labels(stage="drain").value["exemplar"] == "aaaa"
         assert "exemplar" not in h.labels(stage="verify").value
+
+
+class _Node:
+    """Something the collector tracks and a `weakref` can watch."""
+
+
+@pytest.fixture
+def heap():
+    """The collector's policy is the process's: every test here leaves
+    it as it found it (nothing frozen, the old thresholds, the old mark),
+    so the rest of the suite runs as before."""
+    import gc
+
+    from tendermint_tpu.telemetry import process
+
+    process.install_gc_telemetry()
+    thresholds, unsettled = gc.get_threshold(), process._heap_unsettled
+    gc.unfreeze()
+    process.mark_heap_unsettled()
+    yield process
+    gc.unfreeze()
+    gc.set_threshold(*thresholds)
+    process._heap_unsettled = unsettled
+
+
+def _compile_event() -> None:
+    """What `jax.monitoring` tells the listener of `utils/jax_cache.py`
+    when an executable has been built or loaded."""
+    from tendermint_tpu.utils import jax_cache
+
+    jax_cache._on_duration(jax_cache._COMPILE_EVENT, 0.01, fun_name="heap_settle_probe")
+
+
+def _gen2_collections() -> float:
+    return REGISTRY.counter_value("tendermint_process_gc_collections_total", gen="2")
+
+
+def _settles() -> float:
+    return REGISTRY.counter_value("tendermint_process_heap_settles_total")
+
+
+def _frozen() -> float:
+    return REGISTRY.counter_value("tendermint_process_gc_frozen_objects")
+
+
+class TestHeapSettle:
+    """`telemetry/process.py` `settle_heap`: what the process keeps for
+    life is frozen once, and again only after an executable was met."""
+
+    def test_a_settle_freezes_what_was_tracked(self, heap):
+        import gc
+
+        gc.collect()  # the garbage is not counted below
+        tracked, settles = len(gc.get_objects()), _settles()
+        assert heap.settle_heap() is True
+        assert gc.get_freeze_count() >= 0.95 * tracked
+        assert len(gc.get_objects()) < 0.05 * tracked
+        assert _frozen() == gc.get_freeze_count()
+        assert _settles() == settles + 1
+
+    def test_the_thresholds_in_force_after_a_settle_are_the_constants(self, heap):
+        import gc
+
+        _young, middle, old = gc.get_threshold()
+        assert heap.settle_heap() is True
+        assert gc.get_threshold() == (heap.YOUNG_GENERATION_THRESHOLD, middle, old)
+        assert heap.YOUNG_GENERATION_THRESHOLD == 50_000
+
+    def test_a_second_settle_with_nothing_traced_since_is_a_no_op(self, heap):
+        assert heap.settle_heap() is True
+        collections, settles = _gen2_collections(), _settles()
+        assert heap.settle_heap() is False
+        assert _gen2_collections() == collections  # no collection was paid for
+        assert _settles() == settles
+
+    @pytest.mark.parametrize("by", ["settle_heap", "the_hook"])
+    def test_a_compile_event_unsettles_and_the_next_settle_freezes_again(self, heap, by):
+        """Explicitly (`Node.start`), or at the end of the collector's
+        own next full collection, which has just paid for the walk."""
+        import gc
+
+        assert heap.settle_heap() is True
+        frozen, settles = gc.get_freeze_count(), _settles()
+        traced = [[i] for i in range(2_000)]  # stands for an executable's jaxprs
+        _compile_event()
+        assert heap._heap_unsettled
+        if by == "settle_heap":
+            assert heap.settle_heap() is True
+        else:
+            gc.collect()
+        assert not heap._heap_unsettled
+        assert gc.get_freeze_count() >= frozen + len(traced)
+        assert _settles() == settles + 1
+
+    def test_the_hook_leaves_a_process_that_never_settled_alone(self, heap, monkeypatch):
+        import gc
+
+        monkeypatch.setattr(heap, "_settles", 0)
+        gc.collect()
+        frozen = gc.get_freeze_count()  # CPython's own few hundred, not 0
+        _compile_event()
+        gc.collect()
+        assert gc.get_freeze_count() == frozen and heap._heap_unsettled
+
+    def test_a_frozen_object_is_freed_when_its_last_reference_goes(self, heap):
+        import gc
+        import weakref
+
+        node = _Node()
+        watch = weakref.ref(node)
+        assert heap.settle_heap() is True
+        collections = _gen2_collections()
+        gc.disable()
+        try:
+            del node
+            assert watch() is None  # by reference count: nothing collected it
+        finally:
+            gc.enable()
+        assert _gen2_collections() == collections
+
+    def test_a_cycle_made_after_the_settle_is_collected(self, heap):
+        import gc
+        import weakref
+
+        assert heap.settle_heap() is True
+        node = _Node()
+        node.me = node
+        watch = weakref.ref(node)
+        del node
+        assert watch() is not None
+        gc.collect()
+        assert watch() is None
+
+    def test_the_pause_sum_the_benchmark_reads_is_the_sum_over_gen(self, heap):
+        """`process.gc_pause_share` takes the rise of `..._seconds_sum`
+        over whatever labels the series carries (`benchmark/lib/rpc.py`
+        `metric`): with `gen` on it, still every pause of every generation."""
+        import gc
+
+        from benchmark.lib import rpc
+
+        for generation in (0, 1, 2):
+            gc.collect(generation)
+        parsed = rpc.parse_metrics(REGISTRY.prometheus_text())
+        name = "tendermint_process_gc_pause_seconds_sum"
+        assert sorted(labels["gen"] for labels, _v in parsed[name]) == ["0", "1", "2"]
+        by_gen = [rpc.metric(parsed, name, gen=g) for g in ("0", "1", "2")]
+        assert all(seconds > 0 for seconds in by_gen)
+        assert rpc.metric(parsed, name) == pytest.approx(sum(by_gen))
