@@ -659,6 +659,18 @@ VOTE_WIRE_KEPT = Counter(
     "bytes): the validator count a fast-synced block, 0 for a vote with "
     "a padded varint, which is encoded for itself on first use",
 )
+COMMIT_VOTES_DECODED = Counter(
+    "tendermint_commit_votes_decoded_total",
+    "Precommits Commit.decode_from read, added once a commit decoded: "
+    "shared (read against the commit's first vote: the same layout, height, "
+    "round, type and block_id by their bytes, one BlockID among them) or "
+    "plain (Vote.decode: the first vote present, and any whose bytes differ "
+    "in more than address, index, timestamp and signature): N - 1 and 1 a "
+    "commit whose validators all signed the block, canonical bytes",
+    labelnames=("path",),
+)
+for _path in ("shared", "plain"):
+    COMMIT_VOTES_DECODED.labels(path=_path).inc(0)
 
 # -- a validator set's root (types/validator_set.py) --------------------------
 

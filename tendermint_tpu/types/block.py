@@ -16,7 +16,7 @@ from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
 from tendermint_tpu.types.part_set import DEFAULT_PART_SIZE, PartSet
 from tendermint_tpu.types.tx import Txs
-from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT, Vote
+from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT, Vote, decode_commit_votes
 from tendermint_tpu.utils.bit_array import BitArray
 
 
@@ -182,13 +182,16 @@ class Commit:
 
     @classmethod
     def decode_from(cls, r: Reader) -> "Commit":
+        """The commit off `r`. By `validate_basic`'s own rule its votes have
+        one height, round and type, and those for the block one `block_id`,
+        so the votes are read against the first of them where their bytes
+        say they may be (`decode_commit_votes`: canonical bytes that differ
+        from the first's in address, index, timestamp and signature alone)
+        and by `Vote.decode` where not. Either way each vote is the one
+        `Vote.decode` gives, its kept bytes with it; the votes read against
+        the first share its `BlockID`. Nothing is validated here."""
         block_id = BlockID.decode_from(r)
-        n = r.uvarint()
-        precommits: list[Vote | None] = []
-        for _ in range(n):
-            b = r.bytes()
-            precommits.append(Vote.decode(b) if b else None)
-        return cls(block_id=block_id, precommits=precommits)
+        return cls(block_id=block_id, precommits=decode_commit_votes(r, r.uvarint()))
 
     @classmethod
     def empty(cls) -> "Commit":
