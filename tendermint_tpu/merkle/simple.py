@@ -22,7 +22,7 @@ Roots are therefore NOT bit-compatible with reference roots (the
 domain separation alone guarantees that); within this framework, host
 (`merkle.simple`) and device (`ops/merkle_kernel.py`) trees implement
 the identical rule and are bit-equal — asserted by tests and by
-`bench.py`'s device-vs-host root check.
+`chip_smoke.py`'s device-vs-host root check.
 """
 
 from __future__ import annotations

@@ -53,13 +53,15 @@ from tendermint_tpu.ops.ed25519_kernel import (
     pt_decompress,
     pt_neg,
 )
-from tendermint_tpu.ops.ed25519_tables import (
+from tendermint_tpu.ops.ed25519_pallas import (
     _addc_planes,
     _carry_planes,
-    _finish_encode_compare,
     _madd_planes,
     _mul_planes,
     _sub_planes,
+)
+from tendermint_tpu.ops.ed25519_tables import (
+    _finish_encode_compare,
     fe_batch_invert,
 )
 
@@ -78,8 +80,8 @@ MIN_LANES = 1024  # smallest plane geometry (8, 128)
 
 def use_pallas_ladder(padded_size: int) -> bool:
     """THE routing rule for generic verifies — shared by batch_verify
-    and bench so they can't drift: pallas ladder iff the padded bucket
-    clears the plane geometry and a TPU is the backend."""
+    and `chip_smoke.py` so they can't drift: pallas ladder iff the padded
+    bucket clears the plane geometry and a TPU is the backend."""
     import jax
 
     return padded_size >= MIN_LANES and jax.default_backend() == "tpu"

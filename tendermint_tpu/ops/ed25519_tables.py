@@ -33,15 +33,17 @@ Two device pipelines share these tables:
 
 * the FUSED pallas kernel (production, TPU): selection happens inside
   the kernel from int16 table blocks, the accumulator lives in VMEM,
-  and the table streams from HBM exactly once per launch — see the
-  "fused select+accumulate" section below (rates on v5e: not
-  measured; chip_smoke.py checks bits, not speed);
+  and the table streams from HBM exactly once per launch — see
+  `ed25519_pallas.py`, where every `pallas_call` of this path lives
+  (rates on v5e: not measured; chip_smoke.py checks bits, not speed);
 * the materialized-entries path (XLA scan or the earlier pallas madd
   chain): portable, used for shapes that don't tile the fused kernel
   (single commits, tiny valsets) and by the CPU test mesh.
 """
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -445,10 +447,18 @@ def build_key_tables(pub_bytes: np.ndarray, chunk: int = 2048):
 # then run either as an XLA scan (portable; CPU tests) or as a Pallas
 # kernel that keeps the accumulator in VMEM across all steps (TPU fast
 # path — XLA's scan materializes the carry through HBM every step).
+#
+# `jax.named_scope` names the XLA stages in the profiler's trace
+# (`ed25519.select_entries/while` where it said `while.249`). Never put
+# one around a `pallas_call`: the name stack is in a Mosaic kernel's
+# serialized body, and that body is in the executable's compile-cache
+# key. (The table build and layout need no scope: each is an executable
+# of its own, named in the trace's `XLA Modules` line.)
 
 NSTEPS = B_NWIN + A_NWIN  # 96 mixed adds per signature
 
 
+@jax.named_scope("ed25519.select_entries")
 def _select_entries(a_tables, s, h):
     """Gather-free operand selection -> (NSTEPS, B, 60) int32.
 
@@ -498,6 +508,7 @@ def _select_entries(a_tables, s, h):
     return jnp.stack(outs, axis=0)
 
 
+@jax.named_scope("ed25519.madd_chain")
 def _sum_entries_xla(ent):
     """Portable scan over the NSTEPS mixed adds; ent (NSTEPS, B, 60)."""
     acc = _identity_like(ent[0, :, :1])
@@ -510,164 +521,31 @@ def _sum_entries_xla(ent):
     return acc
 
 
-# ---- pallas fast path -------------------------------------------------------
-#
-# Layout: the batch is tiled into (8, 128) VPU tiles; every field-element
-# limb is a separate (8, 128) plane so each vector op runs at full lane
-# occupancy. The accumulator lives in a VMEM scratch (80 planes = X,Y,Z,T
-# x 20 limbs) that persists across the NSTEPS minor grid steps; entry
-# planes stream in as (60, 8, 128) blocks double-buffered by the Pallas
-# pipeline. HBM traffic is therefore one read of the entries and one
-# write of the final accumulator — the XLA scan's per-step carry
-# round-trips are gone.
-
-_LANES = 1024  # 8 x 128 batch elements per grid tile
-
-
-def _carry_planes(t):
-    """fe_carry on a list of 20 (8,128) planes (3 rounds, like fe_carry)."""
-    for _ in range(3):
-        c = [v >> 13 for v in t]
-        r = [v & 8191 for v in t]
-        t = [r[0] + 608 * c[-1]] + [r[i] + c[i - 1] for i in range(1, 20)]
-    return t
-
-
-def _mul_planes(a, b):
-    """fe_mul on lists of 20 (8,128) planes (mirrors fe_mul exactly)."""
-    cols = []
-    for k in range(39):
-        lo, hi = max(0, k - 19), min(k, 19)
-        t = a[lo] * b[k - lo]
-        for i in range(lo + 1, hi + 1):
-            t = t + a[i] * b[k - i]
-        cols.append(t)
-    c = [v >> 13 for v in cols]
-    r = [v & 8191 for v in cols]
-    out = [r[0]] + [r[i] + c[i - 1] for i in range(1, 39)]
-    lo_ = out[:20]
-    hi_ = out[20:] + [c[-1]]
-    return _carry_planes([lo_[i] + 608 * hi_[i] for i in range(20)])
-
-
-def _sub_planes(a, b):
-    d = [x - y for x, y in zip(a, b)]
-    return _carry_planes(_carry_planes(d))
-
-
-def _addc_planes(a, b):
-    return _carry_planes([x + y for x, y in zip(a, b)])
-
-
-def _madd_planes(acc, ypx, ymx, t2d):
-    x1, y1, z1, t1 = acc
-    a = _mul_planes(_sub_planes(y1, x1), ymx)
-    b = _mul_planes(_addc_planes(y1, x1), ypx)
-    c = _mul_planes(t1, t2d)
-    d = _carry_planes([v + v for v in z1])
-    e = _sub_planes(b, a)
-    f = _sub_planes(d, c)
-    g = _addc_planes(d, c)
-    h = _addc_planes(b, a)
-    return (
-        _mul_planes(e, f),
-        _mul_planes(g, h),
-        _mul_planes(f, g),
-        _mul_planes(e, h),
-    )
-
-
-def _madd_chain_kernel(ent_ref, out_ref, acc_ref):
-    from jax.experimental import pallas as pl
-
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _():
-        # identity (0, 1, 1, 0): Y limb 0 and Z limb 0 are 1 (scatter is
-        # not lowerable in pallas, so build via an iota select)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (80, 8, 128), 0)
-        acc_ref[:] = jnp.where((rows == 20) | (rows == 40), 1, 0)
-
-    ent = ent_ref[0, 0]  # (60, 8, 128)
-    acc = tuple(
-        [acc_ref[20 * ci + i] for i in range(20)] for ci in range(4)
-    )
-    ypx = [ent[i] for i in range(20)]
-    ymx = [ent[20 + i] for i in range(20)]
-    t2d = [ent[40 + i] for i in range(20)]
-    nxt = _madd_planes(acc, ypx, ymx, t2d)
-    acc_ref[:] = jnp.stack([p for coord in nxt for p in coord])
-
-    @pl.when(t == NSTEPS - 1)
-    def _():
-        out_ref[0] = acc_ref[:]
-
-
-def _sum_entries_pallas(ent):
-    """ent (NSTEPS, B, 60) -> extended acc, B a multiple of 1024 lanes."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    bsz = ent.shape[1]
-    tiles = bsz // _LANES
-    # (NSTEPS, B, 60) -> (tiles, NSTEPS, 60, 8, 128)
-    e = ent.reshape(NSTEPS, tiles, 8, 128, 60)
-    e = jnp.transpose(e, (1, 0, 4, 2, 3))
-    out = pl.pallas_call(
-        _madd_chain_kernel,
-        grid=(tiles, NSTEPS),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, 60, 8, 128),
-                lambda i, t: (i, t, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            )
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 80, 8, 128), lambda i, t: (i, 0, 0, 0), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (tiles, 80, 8, 128), jnp.int32, vma=jax.typeof(e).vma
-        ),
-        scratch_shapes=[pltpu.VMEM((80, 8, 128), jnp.int32)],
-    )(e)
-    # (tiles, 80, 8, 128) -> 4 coords of (B, 20)
-    coords = out.reshape(tiles, 4, 20, 8, 128)
-    coords = jnp.transpose(coords, (1, 0, 3, 4, 2)).reshape(4, bsz, NLIMBS)
-    return coords[0], coords[1], coords[2], coords[3]
-
-
-from functools import partial  # noqa: E402
-
-# ---- fused select+accumulate pallas path ------------------------------------
+# ---- the fused path's geometry ------------------------------------------------
 #
 # The materialized-entries pipeline above streams a (96, B, 60) int32
 # array through HBM twice (write at selection, read at accumulation) —
-# 7.6 GB of traffic at the K=16 x 10,240 bench shape. The fused kernel
-# removes that array entirely: each grid step selects its operands
-# INSIDE the kernel from the (int16, read-once) table block and feeds
-# them straight to the VMEM-resident mixed-add accumulator. To make
-# every step's selection the same cheap 16-way masked sum, the S comb
-# uses a w=4 fixed-base table too: 128 identical steps (64 S windows +
-# 64 h windows) instead of 96 asymmetric ones.
+# 7.6 GB of traffic at K=16 x 10,240 validators. The fused kernel
+# (`ed25519_pallas._fused_chain_pallas`) removes that array entirely:
+# each grid step selects its operands INSIDE the kernel from the (int16,
+# read-once) table block. To make every step's selection the same cheap
+# 16-way masked sum, the S comb uses a w=4 fixed-base table too: 128
+# identical steps (64 S windows + 64 h windows) instead of 96 asymmetric
+# ones.
 #
-# Lane geometry: one grid tile covers V_TILE=128 validators x ALL K
-# stacked commits (lane planes are (8, 16*K) — the plane width scales
-# with K instead of adding commit-blocks to the grid), so each table
-# block serves every lane that ever needs it and the full table is read
-# EXACTLY ONCE per launch, independent of K. 128 is the smallest
-# validator block pallas can address on the table's minor axis, which
-# maximizes how much stacking a given VMEM budget allows.
+# One grid tile covers V_TILE=128 validators x ALL K stacked commits.
+# 128 is the smallest validator block pallas can address on the table's
+# minor axis, which maximizes how much stacking a given VMEM budget
+# allows.
 
-NSTEPS_W4 = 2 * SB_NWIN  # 128: steps 0..63 = S comb, 64..127 = h comb
-A_START = SB_NWIN
 V_TILE = 128
 MAX_FUSED_STACK = 64  # VMEM: acc+ent scratch = 140 * (8, 16K) planes
 
 
+@jax.named_scope("ed25519.fused_digits")
 def _digits_w4(s, h):
-    """(B, 32) int32 byte arrays -> (B, 128) int32 nibble-per-step."""
+    """(B, 32) int32 byte arrays -> (B, 128) int32 nibble-per-step:
+    steps 0..63 the S comb's, 64..127 the h comb's."""
     cols = []
     for i in range(SB_NWIN):
         cols.append((s[:, i // 2] >> (4 * (i % 2))) & 0xF)
@@ -708,123 +586,6 @@ def _fused_tile_geometry(bsz: int, n_vals: int):
     return None
 
 
-def _make_fused_kernel(c_tile: int):
-    from jax.experimental import pallas as pl
-
-    w = V_TILE * c_tile // 8  # lane plane shape (8, w)
-
-    def kernel(sb_ref, atab_ref, dig_ref, out_ref, acc_ref, ent_ref):
-        t = pl.program_id(1)
-
-        @pl.when(t == 0)
-        def _():
-            rows = jax.lax.broadcasted_iota(jnp.int32, (80, 8, w), 0)
-            acc_ref[:] = jnp.where((rows == 20) | (rows == 40), 1, 0)
-
-        dig = dig_ref[0, 0]  # (8, w) int32 nibbles for this step
-        masks = [dig == d for d in range(16)]
-
-        @pl.when(t < A_START)
-        def _():
-            sb = sb_ref[0]  # (16, 60) int32 — shared by every lane
-            planes = []
-            for limb in range(60):
-                acc = jnp.zeros((8, w), jnp.int32)
-                for d in range(16):
-                    acc = acc + jnp.where(masks[d], sb[d, limb], 0)
-                planes.append(acc)
-            ent_ref[:] = jnp.stack(planes)
-
-        @pl.when(t >= A_START)
-        def _():
-            at = atab_ref[0].astype(jnp.int32)  # (16, 60, V_TILE)
-            reps = w // V_TILE  # commits per plane row (= c_tile/8)
-            planes = []
-            for limb in range(60):
-                acc = jnp.zeros((8, w), jnp.int32)
-                for d in range(16):
-                    col = at[d, limb]  # (V_TILE,) — this tile's validators
-                    # lanes are commit-major (lane = c*128 + v), so the
-                    # column expands by row-splat + minor concat — the
-                    # only vector reshapes Mosaic supports here
-                    bv = jnp.broadcast_to(col[None, :], (8, V_TILE))
-                    if reps > 1:
-                        bv = jnp.concatenate([bv] * reps, axis=1)
-                    acc = acc + jnp.where(masks[d], bv, 0)
-                planes.append(acc)
-            ent_ref[:] = jnp.stack(planes)
-
-        ent = ent_ref[:]
-        acc = tuple(
-            [acc_ref[20 * ci + i] for i in range(20)] for ci in range(4)
-        )
-        ypx = [ent[i] for i in range(20)]
-        ymx = [ent[20 + i] for i in range(20)]
-        t2d = [ent[40 + i] for i in range(20)]
-        nxt = _madd_planes(acc, ypx, ymx, t2d)
-        acc_ref[:] = jnp.stack([p for coord in nxt for p in coord])
-
-        @pl.when(t == NSTEPS_W4 - 1)
-        def _():
-            out_ref[0] = acc_ref[:]
-
-    return kernel
-
-
-def _fused_chain_pallas(a_tables, digits, v_tile, c_tile, interpret=False):
-    """a_tables (64,16,60,N) int16, digits (B,128) int32 kernel-order
-    -> extended acc coords, each (B, 20) int32 kernel-order."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    bsz = digits.shape[0]
-    lanes_per_tile = v_tile * c_tile
-    tiles = bsz // lanes_per_tile  # == N / V_TILE validator blocks
-    w = lanes_per_tile // 8
-    # digits -> (tiles, NSTEPS_W4, 8, w) step-major planes so the
-    # pipeline hands each step its (8, w) nibble plane directly
-    dig = digits.reshape(tiles, 8, w, NSTEPS_W4)
-    dig = jnp.transpose(dig, (0, 3, 1, 2))
-    sb = jnp.asarray(sb_table_w4())
-
-    grid = (tiles, NSTEPS_W4)
-    out = pl.pallas_call(
-        _make_fused_kernel(c_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, 16, 60),
-                lambda i, t: (jnp.minimum(t, A_START - 1), 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, 16, 60, v_tile),
-                lambda i, t: (jnp.maximum(t - A_START, 0), 0, 0, i),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, 1, 8, w),
-                lambda i, t: (i, t, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 80, 8, w), lambda i, t: (i, 0, 0, 0), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (tiles, 80, 8, w), jnp.int32, vma=jax.typeof(dig).vma
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((80, 8, w), jnp.int32),
-            pltpu.VMEM((60, 8, w), jnp.int32),
-        ],
-        interpret=interpret,
-    )(sb, a_tables, dig)
-    coords = out.reshape(tiles, 4, 20, 8, w)
-    coords = jnp.transpose(coords, (1, 0, 3, 4, 2)).reshape(4, bsz, NLIMBS)
-    return coords[0], coords[1], coords[2], coords[3]
-
-
 @partial(jax.jit, static_argnames=("impl",))
 def verify_tables_kernel(a_tables, s_bytes, h_bytes, r_bytes, impl="auto"):
     """Batched verify against cached tables.
@@ -848,6 +609,13 @@ def verify_tables_kernel(a_tables, s_bytes, h_bytes, r_bytes, impl="auto"):
     forces the materialized-entries pallas chain; "xla" the portable
     scan.
     """
+    # pallas takes a second to import, and only a trace needs it
+    from tendermint_tpu.ops.ed25519_pallas import (
+        _LANES,
+        _fused_chain_pallas,
+        _sum_entries_pallas,
+    )
+
     s = s_bytes.astype(jnp.int32)
     h = h_bytes.astype(jnp.int32)
     r = r_bytes.astype(jnp.int32)
@@ -866,7 +634,12 @@ def verify_tables_kernel(a_tables, s_bytes, h_bytes, r_bytes, impl="auto"):
         v_tile, c_tile = geom
         digits = _to_kernel_order(_digits_w4(s, h), n_vals, v_tile, c_tile)
         x, y, z, _t = _fused_chain_pallas(
-            a_tables, digits, v_tile, c_tile, interpret=not on_tpu
+            jnp.asarray(sb_table_w4()),
+            a_tables,
+            digits,
+            v_tile,
+            c_tile,
+            interpret=not on_tpu,
         )
         r = _to_kernel_order(r, n_vals, v_tile, c_tile)
         verdict = _finish_encode_compare(x, y, z, r)
@@ -893,6 +666,7 @@ def verify_tables_kernel(a_tables, s_bytes, h_bytes, r_bytes, impl="auto"):
     return _finish_encode_compare(x, y, z, r)
 
 
+@jax.named_scope("ed25519.tally")
 def _finish_encode_compare(x, y, z, r):
     """Affine-normalize via one tree inversion, encode y, compare to R."""
     zinv = fe_batch_invert(fe_carry(z))
@@ -968,24 +742,3 @@ def prepare_commit_lanes(pubkeys, commits):
                 b"".join(blobs), dtype=np.uint8
             ).reshape(len(blobs), 32)
     return s, h, r, precheck
-
-
-# -- names in the profiler's trace --------------------------------------------
-#
-# `jax.named_scope` around the XLA stages of `verify_tables_kernel`, so a
-# device trace says `ed25519.select_entries/while` where it said
-# `while.249`. Applied HERE, by rebinding below every Pallas kernel and
-# call site, and never around a `pallas_call`: a Mosaic kernel's
-# serialized body carries the scope stack and the file lines of its
-# Python frames, and that body is part of the executable's compile-cache
-# key. A scope on plain XLA ops is metadata the key leaves out, so these
-# names change no executable; a line added above would recompile every
-# verify shape. (The table build and layout need no scope: each is an
-# executable of its own, named in the trace's `XLA Modules` line.)
-
-_select_entries = jax.named_scope("ed25519.select_entries")(_select_entries)
-_sum_entries_xla = jax.named_scope("ed25519.madd_chain")(_sum_entries_xla)
-_digits_w4 = jax.named_scope("ed25519.fused_digits")(_digits_w4)
-_finish_encode_compare = jax.named_scope("ed25519.tally")(
-    _finish_encode_compare
-)

@@ -21,11 +21,14 @@ from __future__ import annotations
 
 import logging
 import os
+import threading
 import time
+from concurrent.futures import Future
 from typing import Sequence
 
 import numpy as np
 
+from tendermint_tpu.telemetry import TRACER
 from tendermint_tpu.telemetry import launchlog as _launchlog
 from tendermint_tpu.telemetry import metrics as _metrics
 from tendermint_tpu.utils.log import kv, logger
@@ -137,7 +140,7 @@ class HostBatchVerifier(BatchVerifier):
 # count is k * n as handed in, never of the padded launch shape
 # (`commit_launch_shape`): at 100 validators windows of K <= 5 stay on the
 # host library; at 1,000 every window, K = 1 too, is a device launch (the cell
-# `fastsync-1k.sparse`). Not measured on v5e (ROADMAP Queue 1 item 2(d)).
+# `fastsync-1k.sparse`). Not measured on v5e (ROADMAP Queue 1 item 5).
 DEVICE_MIN_BATCH = int(os.environ.get("TENDERMINT_TPU_MIN_DEVICE_BATCH", "512"))
 
 # What a pad column of a commit window's launch carries, and what stands
@@ -290,6 +293,29 @@ class TableBuildError(RuntimeError):
     crypto."""
 
 
+def _host_keys(keys):
+    """The comb tables of `keys`, a column each, by the host's Python
+    integers (no executable of its own), put on the device; and which
+    of the keys are well-formed."""
+    import jax.numpy as jnp
+
+    from tendermint_tpu.ops.ed25519_tables import host_build_key_tables
+
+    tables, ok = host_build_key_tables(list(keys))
+    _metrics.TABLE_KEYS_BUILT.labels(how="host").inc(len(keys))
+    return jnp.asarray(tables), ok
+
+
+def _device_keys(keys):
+    """The same by the device's build kernel."""
+    from tendermint_tpu.ops.ed25519_tables import build_key_tables
+
+    pub = np.frombuffer(b"".join(keys), dtype=np.uint8).reshape(len(keys), 32)
+    built = build_key_tables(pub)
+    _metrics.TABLE_KEYS_BUILT.labels(how="device").inc(len(keys))
+    return built
+
+
 class TableBatchVerifier(DeviceBatchVerifier):
     """Valset-table-cached backend: the steady-state consensus fast path.
 
@@ -354,7 +380,8 @@ class TableBatchVerifier(DeviceBatchVerifier):
         table; each new key is built once, the placeholder among them
         only when the cached set has none (it fills its tile); the `ok`
         returned is that of every column of `pubkeys`, pad columns
-        (well-formed: the placeholder decompresses) included."""
+        (well-formed: the placeholder decompresses) included. Returns
+        as `_build_tables` does."""
         import jax.numpy as jnp
 
         real = set(pubkeys) - {PLACEHOLDER_KEY}
@@ -378,14 +405,17 @@ class TableBatchVerifier(DeviceBatchVerifier):
             ok = np.concatenate([ok, new_ok])
             column.update((pk, len(old_pubs) + j) for j, pk in enumerate(missing))
         perm = np.array([column[pk] for pk in pubkeys], dtype=np.int32)
-        return jnp.take(tables, jnp.asarray(perm), axis=3), ok[perm]
+        gathered = jnp.take(tables, jnp.asarray(perm), axis=3)
+        return gathered, ok[perm], "incremental", len(missing)
 
-    def _tables_for(self, pubkeys: tuple[bytes, ...]):
+    def _tables_for(self, pubkeys: tuple[bytes, ...], kind: str | None = None):
         """The set's table and its columns' well-formedness: from the
         cache (`hit`); from a build of this very set in flight on
         another thread, `prebuild`'s or a launch's (`joined`: it waits
         and builds nothing); else by building it (`miss`). A build that
-        fails fails its waiters the same way (`TableBuildError`)."""
+        fails fails its waiters the same way (`TableBuildError`). `kind`
+        is the build's in the histogram and the span where the caller
+        has a name for it (`prebuild`); else how the build went."""
         key = self._cache_key(pubkeys)
         with self._cache_lock:
             hit = self._tables.get(key)
@@ -401,7 +431,7 @@ class TableBatchVerifier(DeviceBatchVerifier):
             return flight.result()
         _metrics.TABLE_CACHE.labels(event="miss").inc()
         try:
-            built = _timed_build(self, pubkeys)
+            built = self._timed_build(pubkeys, kind)
         except BaseException as e:
             with self._cache_lock:
                 del self._building[key]
@@ -415,11 +445,41 @@ class TableBatchVerifier(DeviceBatchVerifier):
         mine.set_result(built)
         return built
 
+    def _timed_build(self, pubkeys: tuple[bytes, ...], kind: str | None = None):
+        """`_build_tables` under the `tables.build` stage:
+        `tendermint_verify_table_build_seconds{kind}`, and a stretch in the
+        profiler's host plane, so a launch that has to build its table no
+        longer hides the build in its `host_prep_s` and a prebuild is timed
+        at all. One `tables.build` span a build says how it went: its
+        `kind` (the histogram's), the keys whose tables it computed
+        (`keys_new`: 1 or 2 where a validator joins, the table's width
+        where nothing cached overlapped) and the table's `columns`. A
+        build that fails is timed as `full` with no key built."""
+        how, keys_new = "full", 0
+        t0 = time.time()
+        try:
+            with TRACER.stage("tables.build") as stage:
+                tables, ok, how, keys_new = self._build_tables(pubkeys)
+            return tables, ok
+        finally:
+            kind = kind or how
+            _metrics.TABLE_BUILD_SECONDS.labels(kind=kind).observe(stage.seconds)
+            TRACER.add(
+                "tables.build",
+                t0,
+                time.time(),
+                kind=kind,
+                keys_new=keys_new,
+                columns=len(pubkeys),
+            )
+
     def _build_tables(self, pubkeys: tuple[bytes, ...]):
         """Construct tables for an uncached set, behind the table-build
         breaker: on the device (incremental when a cached set overlaps),
         else on the host where the set is small enough to afford it, else
-        `TableBuildError`, and `verify_commits` answers with host crypto."""
+        `TableBuildError`, and `verify_commits` answers with host crypto.
+        Returns (tables, ok, how the build went: `full`, `incremental` or
+        `host_build`, the count of keys it built)."""
         from tendermint_tpu.utils.fail import device_fail_point
 
         if self._build_breaker.allow():
@@ -427,9 +487,9 @@ class TableBatchVerifier(DeviceBatchVerifier):
                 device_fail_point("tables")
                 built = self._incremental_build(pubkeys)
                 if built is not None:
-                    _built_as("incremental")
+                    _metrics.TABLE_CACHE.labels(event="incremental").inc()
                 else:
-                    built = _device_keys(pubkeys)
+                    built = (*_device_keys(pubkeys), "full", len(pubkeys))
                 self._build_breaker.record_success()
                 return built
             except Exception as e:
@@ -444,8 +504,8 @@ class TableBatchVerifier(DeviceBatchVerifier):
                     breaker=self._build_breaker.state,
                 )
         if len(pubkeys) <= self.MAX_INCREMENTAL_KEYS:
-            _built_as("host_build")
-            return _host_keys(pubkeys)
+            _metrics.TABLE_CACHE.labels(event="host_build").inc()
+            return (*_host_keys(pubkeys), "host_build", len(pubkeys))
         raise TableBuildError(
             f"table build unavailable for {len(pubkeys)} keys "
             f"(breaker {self._build_breaker.state})"
@@ -457,8 +517,6 @@ class TableBatchVerifier(DeviceBatchVerifier):
         pay the per-process compile (or, with a warm persistent cache —
         utils/jax_cache.py — the executable load). Called by the node at
         startup on TPU backends."""
-        import threading
-
         import jax
 
         if jax.default_backend() != "tpu":
@@ -500,9 +558,15 @@ class TableBatchVerifier(DeviceBatchVerifier):
             if key in self._tables or key in self._building:
                 return
 
-        threading.Thread(
-            target=_prebuild, args=(self, pubs), daemon=True, name=_PREBUILD
-        ).start()
+        def build():
+            # a build that cannot be had was logged where it failed and
+            # fails the launch that needs the table the same way
+            try:
+                self._tables_for(pubs, kind="prebuild")
+            except TableBuildError:
+                pass
+
+        threading.Thread(target=build, daemon=True, name="table-prebuild").start()
 
     @staticmethod
     def _fused(force_fused: bool | None) -> bool:
@@ -1102,100 +1166,3 @@ def default_verifier() -> BatchVerifier:
 def set_default_verifier(v: BatchVerifier) -> None:
     global _DEFAULT
     _DEFAULT = v
-
-
-# -- a table build's clock ------------------------------------------------------
-#
-# Down here, and the lines above changed one for one: line numbers above
-# the launches' callers are in the compile-cache key of every executable
-# that holds a Pallas kernel (PERF.md section 6).
-
-import threading  # noqa: E402
-from concurrent.futures import Future  # noqa: E402
-
-from tendermint_tpu.telemetry import TRACER  # noqa: E402
-
-_PREBUILD = "table-prebuild"  # the thread `prebuild` starts
-# how the build this thread is in went (.kind), and the keys whose
-# tables it has computed so far (.keys_new)
-_BUILD = threading.local()
-
-
-def _prebuild(verifier: "TableBatchVerifier", pubs) -> None:
-    """The `prebuild` thread's whole work. A build that cannot be had
-    was logged where it failed and fails the launch that needs the
-    table the same way: nothing to add from here."""
-    try:
-        verifier._tables_for(pubs)
-    except TableBuildError:
-        pass
-
-
-def _built_as(event: str) -> None:
-    """Count a build that went another way than whole on the device
-    (`incremental`, `host_build`), and tell `_timed_build` on this
-    thread."""
-    _metrics.TABLE_CACHE.labels(event=event).inc()
-    _BUILD.kind = event
-
-
-def _keys_built(how: str, n: int) -> None:
-    _metrics.TABLE_KEYS_BUILT.labels(how=how).inc(n)
-    _BUILD.keys_new = getattr(_BUILD, "keys_new", 0) + n
-
-
-def _host_keys(keys):
-    """The comb tables of `keys`, a column each, by the host's Python
-    integers (no executable of its own), put on the device; and which
-    of the keys are well-formed."""
-    import jax.numpy as jnp
-
-    from tendermint_tpu.ops.ed25519_tables import host_build_key_tables
-
-    tables, ok = host_build_key_tables(list(keys))
-    _keys_built("host", len(keys))
-    return jnp.asarray(tables), ok
-
-
-def _device_keys(keys):
-    """The same by the device's build kernel."""
-    from tendermint_tpu.ops.ed25519_tables import build_key_tables
-
-    pub = np.frombuffer(b"".join(keys), dtype=np.uint8).reshape(len(keys), 32)
-    built = build_key_tables(pub)
-    _keys_built("device", len(keys))
-    return built
-
-
-def _build_kind() -> str:
-    prebuild = threading.current_thread().name == _PREBUILD
-    return "prebuild" if prebuild else _BUILD.kind
-
-
-def _observe_build(seconds: float, _cpu_seconds: float) -> None:
-    _metrics.TABLE_BUILD_SECONDS.labels(kind=_build_kind()).observe(seconds)
-
-
-def _timed_build(verifier: TableBatchVerifier, pubkeys):
-    """`_build_tables` under the `tables.build` stage:
-    `tendermint_verify_table_build_seconds{kind}`, and a stretch in the
-    profiler's host plane, so a launch that has to build its table no
-    longer hides the build in its `host_prep_s` and a prebuild is timed
-    at all. One `tables.build` span a build says how it went: its
-    `kind` (the histogram's), the keys whose tables it computed
-    (`keys_new`: 1 or 2 where a validator joins, the table's width
-    where nothing cached overlapped) and the table's `columns`."""
-    _BUILD.kind, _BUILD.keys_new = "full", 0
-    t0 = time.time()
-    try:
-        with TRACER.stage("tables.build", _observe_build):
-            return verifier._build_tables(pubkeys)
-    finally:
-        TRACER.add(
-            "tables.build",
-            t0,
-            time.time(),
-            kind=_build_kind(),
-            keys_new=_BUILD.keys_new,
-            columns=len(pubkeys),
-        )

@@ -12,9 +12,21 @@ it must not move between runs:
   (derived from this file's location, git-ignored) — the same path for
   every process, whatever its CWD.
 
-Called from every entry point that compiles kernels (node CLI, bench,
-graft entries, tools). Never initializes a JAX backend: a parent that
-only orchestrates children must stay off the chip. Opt out with
+What is in an executable's key, beside the directory: its program. The
+outer module's source locations are left out (JAX's default,
+`jax_compilation_cache_include_metadata_in_key=False`), but a Mosaic
+kernel's serialized body is an operand of its `tpu_custom_call`, and the
+body names the file and line of each op's Python frames. So
+`enable_persistent_cache()` keeps the callers' frames out of it
+(`_CALL_FRAMES_IN_LOCATIONS`): the key of an executable that holds a
+Pallas kernel reads the kernel's own file (`ops/ed25519_pallas.py`,
+`ops/ed25519_ladder_pallas.py`) and no other. `tests/test_kernel_cache_key.py`
+holds that.
+
+Called from every entry point that compiles kernels (node CLI,
+`chip_smoke.py`, the benchmark's node, graft entries), before anything
+lowers. Never initializes a JAX backend: a parent that only
+orchestrates children must stay off the chip. Opt out with
 TENDERMINT_TPU_XLA_CACHE=off.
 """
 
@@ -33,6 +45,13 @@ _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_misses": "miss",
 }
 _listening = False
+
+# `jax_include_full_tracebacks_in_locations`: an op's location is its own
+# line, not the ten frames that called it. With the frames in, a line
+# added above a launch's caller in `services/` re-keyed every executable
+# with a Pallas kernel (a 20 s compile each, 165-241 s a cold set-up).
+# JAX reads it when it first lowers; set later it changes nothing.
+_CALL_FRAMES_IN_LOCATIONS = False
 
 
 def _on_event(event: str, **_kw) -> None:
@@ -69,14 +88,19 @@ def _listen() -> None:
 
 def enable_persistent_cache() -> str | None:
     """Arm the on-disk executable cache. Idempotent; returns the cache
-    dir in effect, or None when disabled (TENDERMINT_TPU_XLA_CACHE=off)
-    or when the process is held to the CPU."""
+    dir in effect, or None when disabled (TENDERMINT_TPU_XLA_CACHE=off:
+    such a process keys nothing, and its locations stay JAX's own) or
+    when the process is held to the CPU (which lowers the same kernel
+    bodies as the chip's process, and caches none)."""
     if os.environ.get("TENDERMINT_TPU_XLA_CACHE", "").lower() in (
         "off", "0", "disable", "false", "no",
     ):
         return None
     import jax
 
+    jax.config.update(
+        "jax_include_full_tracebacks_in_locations", _CALL_FRAMES_IN_LOCATIONS
+    )
     _listen()
     # TPU executables only: XLA:CPU AOT results bake in host machine
     # features (loading them on a different host warns of SIGILL), and

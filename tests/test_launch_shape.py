@@ -157,7 +157,7 @@ def _recorded(monkeypatch, verifier, commits, built=None):
     def build(keys):
         if built is not None:
             built.append(keys)
-        return _Tables(keys), np.ones(len(keys), dtype=bool)
+        return _Tables(keys), np.ones(len(keys), dtype=bool), "full", len(keys)
 
     monkeypatch.setattr(verifier, "_build_tables", build)
     return rec
@@ -493,7 +493,9 @@ class TestShardedPaddedPath:
         privs, pubs, v, mgr, calls = self._verifier(monkeypatch, 16)
         built = []
         monkeypatch.setattr(
-            v, "_build_tables", lambda keys: (built.append(keys), (_Tables(keys), np.ones(len(keys), dtype=bool)))[1]
+            v,
+            "_build_tables",
+            lambda keys: (built.append(keys), (_Tables(keys), np.ones(len(keys), dtype=bool), "full", len(keys)))[1],
         )
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         pubs_at_launch = v._launch_keys(pubs, True, v._launch_chips(16))[0]

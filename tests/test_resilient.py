@@ -312,8 +312,9 @@ class TestTableBuildBreaker:
         tv = TableBatchVerifier(min_device_batch=1)
         pubs, _ = self._commit_shape(3)
         fail.set_device_fault("tables", 1)
-        tables, ok = tv._build_tables(tuple(pubs))  # degrades, no raise
+        tables, ok, how, keys_new = tv._build_tables(tuple(pubs))  # degrades, no raise
         assert ok.all() and tables is not None
+        assert (how, keys_new) == ("host_build", 3)
         snap = tv._build_breaker.snapshot()
         assert snap["total_failures"] == 1
         assert snap["state"] == CLOSED  # one fault < threshold

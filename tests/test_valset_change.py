@@ -334,6 +334,60 @@ def test_many_threads_asking_for_a_few_sets_build_each_once():
         assert tables is v._tables[v._cache_key(keys)][1]
 
 
+def build_kinds() -> dict:
+    series = REGISTRY.to_dict()["tendermint_verify_table_build_seconds"]["series"]
+    return {s["labels"]["kind"]: s["count"] for s in series}
+
+
+def on_a_thread(name: str, fn) -> None:
+    t = threading.Thread(target=fn, name=name)
+    t.start()
+    t.join(WAIT_S)
+    assert not t.is_alive()
+
+
+@pytest.mark.parametrize(
+    "thread, kind, observed",
+    [("blockchain-sync", "prebuild", "prebuild"), ("table-prebuild", None, "full")],
+    ids=["a_prebuild_on_a_thread_of_another_name", "a_launchs_build_on_a_thread_of_that_name"],
+)
+def test_a_builds_kind_is_what_its_caller_says_whatever_its_threads_name(thread, kind, observed):
+    v = TableBatchVerifier(min_device_batch=1)
+    keys = v._launch_keys(keys_of(G), True)[0]
+    before, t0 = build_kinds(), time.time()
+    on_a_thread(thread, lambda: v._tables_for(keys, kind=kind))
+    assert rise(before, build_kinds()) == {observed: 1}
+    assert [(s["attrs"]["kind"], s["attrs"]["keys_new"]) for s in spans_since("tables.build", t0)] == [(observed, 128)]
+
+
+def test_two_builds_interleaved_on_two_threads_each_report_their_own_keys():
+    """One set joins a key to the cached one, the other shares nothing
+    with it; neither build ends before both are under way."""
+    v = TableBatchVerifier(min_device_batch=1)
+    v._tables_for(v._launch_keys(keys_of(G), True)[0])
+    joined = v._launch_keys(keys_of(JOIN_1), True)[0]
+    apart = v._launch_keys(keys_of(range(100, 116)), True)[0]
+    both, real = threading.Barrier(2), v._incremental_build
+
+    def meet(pubkeys):
+        both.wait(WAIT_S)
+        built = real(pubkeys)
+        both.wait(WAIT_S)
+        return built
+
+    v._incremental_build = meet
+    before, t0 = keys_built(), time.time()
+    threads = [threading.Thread(target=v._tables_for, args=(keys,)) for keys in (joined, apart)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(WAIT_S)
+    assert not any(t.is_alive() for t in threads)
+    spans = {s["attrs"]["kind"]: s["attrs"]["keys_new"] for s in spans_since("tables.build", t0)}
+    assert spans == {"incremental": 1, "full": 128}
+    assert rise(before, keys_built()) == {"host": 1, "device": 128}
+
+
 # -- (d) fast-sync across the changes, the padded shape forced ---------------------
 
 
