@@ -71,12 +71,26 @@ class BlockPool:
             else:
                 rec.height = height
 
-    def remove_peer(self, peer_id: str) -> None:
-        """Forget the peer; its in-flight requests become reassignable."""
+    def remove_peer(self, peer_id: str) -> int:
+        """Forget the peer, the blocks it delivered that are not applied
+        yet and its requests in flight; all of those heights become
+        assignable to the peers that remain. Returns how many delivered
+        blocks went. (A block kept from a peer that was dropped for lying
+        would be found false later, and a peer already gone debited for
+        it a second time.)"""
         with self._lock:
             self._peers.pop(peer_id, None)
-            for h in [h for h, r in self._requests.items() if r.peer_id == peer_id]:
-                del self._requests[h]
+            return self._forget(peer_id)
+
+    def _forget(self, peer_id: str) -> int:
+        """Drop what `peer_id` delivered or owes (lock held); the count
+        of delivered blocks dropped."""
+        delivered = [h for h, (_, p) in self._blocks.items() if p == peer_id]
+        for h in delivered:
+            del self._blocks[h]
+        for h in [h for h, r in self._requests.items() if r.peer_id == peer_id]:
+            del self._requests[h]
+        return len(delivered)
 
     def max_peer_height(self) -> int:
         with self._lock:
@@ -132,10 +146,7 @@ class BlockPool:
                     evict.append(peer_id)
             for peer_id in evict:
                 self._peers.pop(peer_id, None)
-                for h in [
-                    h for h, r in self._requests.items() if r.peer_id == peer_id
-                ]:
-                    del self._requests[h]
+                self._forget(peer_id)
             # new + freed requests
             h = self.height
             target = self.max_peer_height()
@@ -204,21 +215,29 @@ class BlockPool:
             self._requests.pop(self.height, None)
             self.height += 1
 
-    def redo(self, height: int) -> str | None:
-        """A block failed verification: drop it (and everything after —
-        they chain off it) and return the peer that sent it so the
-        switch can drop the peer (reference `RedoRequest`)."""
+    def redo(self, height: int) -> tuple[str | None, int, int]:
+        """The block at `height` cannot be what the chain committed: name
+        the peer that delivered it and forget that block, every other
+        block that peer delivered and every request in flight to it, and
+        nothing any other peer served. Returns (the peer, its blocks
+        forgotten, other peers' blocks forgotten); the last is counted
+        from the pool's size before and after, so that it says what was
+        done and not what was meant: 0. The peer itself stays until the
+        reactor drops it (`remove_peer`), and the freed heights go to
+        the remaining peers at the next `schedule_requests`.
+
+        The reference's `RedoRequest` (`blockchain/pool.go`) likewise
+        removes the peer and redoes the requesters that were that peer's
+        and no others; quoted from memory, there is no network here to
+        read it from."""
         with self._lock:
-            bad_peer = None
-            if height in self._blocks:
-                bad_peer = self._blocks[height][1]
-            for h in list(self._blocks):
-                if h >= height:
-                    del self._blocks[h]
-            for h in list(self._requests):
-                if h >= height:
-                    del self._requests[h]
-            return bad_peer
+            held = self._blocks.get(height)
+            if held is None:
+                return None, 0, 0
+            before = len(self._blocks)
+            blamed = sum(1 for _, p in self._blocks.values() if p == held[1])
+            self._forget(held[1])
+            return held[1], blamed, before - len(self._blocks) - blamed
 
     def is_caught_up(self) -> bool:
         """Within one block of every peer's tip (with at least one peer

@@ -13,6 +13,12 @@ Trust rule per reference: block H is applied only once +2/3 of the
 current validators are seen precommitting it — H's commit travels as
 block H+1's LastCommit, so the window always holds one more block than
 it applies.
+
+Who is blamed when that fails (`_redo`; docs/BYZANTINE.md): a commit
+that does not verify was carried by block H+1, so H+1's server is
+debited, not H's and not the window's first; the entries of the window
+before the one the verdict names are verified and are applied; and the
+pool forgets what the debited peer delivered and nothing else.
 """
 
 from __future__ import annotations
@@ -32,6 +38,10 @@ from tendermint_tpu.telemetry import launchlog as _launchlog
 from tendermint_tpu.telemetry.metrics import (
     FASTSYNC_BLOCKS_APPLIED,
     FASTSYNC_CHILD_STAGES,
+    FASTSYNC_PREFIX_BLOCKS_APPLIED,
+    FASTSYNC_REDO_BLOCKS_DROPPED,
+    FASTSYNC_REDO_RECOVER_SECONDS,
+    FASTSYNC_REDOS,
     FASTSYNC_STAGE_CPU_SECONDS,
     FASTSYNC_STAGE_SECONDS,
     FASTSYNC_STAGES,
@@ -167,6 +177,10 @@ class BlockchainReactor(Reactor):
         self._thread: threading.Thread | None = None
         self.blocks_synced = 0
         self._progress_mark = time.monotonic()
+        # height a redo was called at -> when: closed by that height's
+        # apply (tendermint_fastsync_redo_recover_seconds)
+        self._redone: dict[int, float] = {}
+        self._redo_pending = False
 
     # -- reactor interface -------------------------------------------------
 
@@ -278,6 +292,11 @@ class BlockchainReactor(Reactor):
                 if self.on_caught_up is not None:
                     self.on_caught_up(self.state)
                 return
+            if self._redo_pending:
+                # a redo freed heights: hand them to the remaining peers
+                # now, as an evicted peer's are, not a tick later
+                self._redo_pending = False
+                continue
             with _stage("starved"):
                 time.sleep(_SYNC_TICK_S)
 
@@ -305,9 +324,11 @@ class BlockchainReactor(Reactor):
         run as a SOFTWARE PIPELINE over the async dispatch layer: while
         window K's commit verdict is in flight on device, the host preps
         window K+1's part sets/lanes and applies window K-1's blocks
-        through ABCI. Windows join strictly in submission order; any
-        redo / valset change / verdict failure drains the in-flight
-        suffix WITHOUT applying its blocks (they chain off the fault).
+        through ABCI. Windows join strictly in submission order; a
+        verdict failure applies the failed window's verified prefix and
+        drains the younger windows in flight WITHOUT applying their
+        blocks (they chain off the fault, and are prepared again from
+        the pool once the redone heights are back).
         """
         from collections import deque
 
@@ -326,10 +347,10 @@ class BlockchainReactor(Reactor):
                     pipeline.append(entry)
                     continue  # keep filling until depth / no window
                 if entry == "redo":
-                    # linkage broke at `cursor`'s window: its suffix is
-                    # already dropped from the pool, but the OLDER
-                    # windows in flight verified under intact linkage —
-                    # apply them before leaving
+                    # `cursor`'s window began with a refuted block, which
+                    # the pool has forgotten with its server's others; the
+                    # OLDER windows in flight stand on their own verdicts
+                    # — apply them before leaving
                     self._drain(pipeline, apply=True)
                     return
                 if pipeline:
@@ -364,33 +385,54 @@ class BlockchainReactor(Reactor):
 
         Returns the in-flight window entry, None (no full window there
         yet), "boundary" (valset changes at `cursor` — needs a drained
-        pipeline + `_sync_one`), or "redo" (linkage mismatch; the pool
-        suffix is already dropped)."""
+        pipeline + `_sync_one`), or "redo" (a block of the window was
+        refuted and no commit before it is left to verify).
+
+        A block refuted here (its id is not the one its successor's
+        commit carries, or the commit it carries is malformed) is redone
+        at once, and the window goes on without it: the blocks before it
+        and the commits they carry, which the verdict still has to
+        pass. The pool has a gap where the block was, so no younger
+        window is prepared until it is fetched again."""
         t0 = time.time()
         stages: dict = {}
         with _stage("part_set", stages):
             claimed = self._claim_window(cursor)
         if not isinstance(claimed, tuple):
             return claimed
-        blocks, parts, entries, cut = claimed
-        try:
-            # the launch record names the heights it covers, so the
-            # ledger can say which backend answered for each height
-            with _stage("verify_submit", stages), _launchlog.tag(
-                height_lo=entries[0][1], height_hi=entries[-1][1]
-            ):
-                handle = self.state.validators.verify_commit_batched_async(
-                    self.state.chain_id,
-                    entries,
-                    verifier=self.verifier,
-                    queue=self._queue(),
-                    consumer="fastsync",
+        blocks, parts, entries, cut, mismatch = claimed
+        while True:
+            if mismatch is not None:
+                refuted = mismatch + self._who_lied(
+                    blocks[mismatch + 1].last_commit,
+                    blocks[mismatch].header.height,
+                    stages,
                 )
-        except ValidationError:
-            # malformed commit caught during prep — same treatment as a
-            # failed verdict on this window
-            self._redo(blocks[0].header.height)
-            return "redo"
+                cause, mismatch = "block_id", None
+            else:
+                try:
+                    # the launch record names the heights it covers, so the
+                    # ledger can say which backend answered for each height
+                    with _stage("verify_submit", stages), _launchlog.tag(
+                        height_lo=entries[0][1], height_hi=entries[-1][1]
+                    ):
+                        handle = self.state.validators.verify_commit_batched_async(
+                            self.state.chain_id,
+                            entries,
+                            verifier=self.verifier,
+                            queue=self._queue(),
+                            consumer="fastsync",
+                        )
+                    break
+                except ValidationError as e:
+                    # malformed commit caught during prep: the block that
+                    # carried it is the refused entry's successor
+                    refuted, cause = getattr(e, "entry", 0) + 1, "prep"
+            self._redo(blocks[refuted].header.height, cause)
+            blocks, entries = blocks[:refuted], entries[: max(refuted - 1, 0)]
+            cut = "pool_gap"
+            if not entries:
+                return "redo"
         return {
             "blocks": blocks,
             "parts": parts,
@@ -405,8 +447,10 @@ class BlockchainReactor(Reactor):
 
     def _claim_window(self, cursor: int):
         """The `part_set` stage of `_prep_window`: (blocks, part sets,
-        verify entries, cut) for the window at `cursor`, or one of
-        `_prep_window`'s None / "boundary" / "redo"."""
+        verify entries, cut, mismatch) for the window at `cursor`, or
+        one of `_prep_window`'s None / "boundary". `mismatch` is None,
+        or the index of the first block whose id is not the one its
+        successor's commit carries; the entries then stop before it."""
         window = self.pool.peek(VERIFY_WINDOW + 1, from_height=cursor)
         if len(window) < 2:
             return None
@@ -442,15 +486,48 @@ class BlockchainReactor(Reactor):
         for i in range(apply_n):
             commit = blocks[i + 1].last_commit
             if commit.block_id != block_ids[i]:
-                self._redo(blocks[i].header.height)
-                return "redo"
+                return blocks, parts, entries, cut, i
             entries.append((block_ids[i], blocks[i].header.height, commit))
-        return blocks, parts, entries, cut
+        return blocks, parts, entries, cut, None
+
+    def _who_lied(self, commit, height: int, stages: dict) -> int:
+        """`commit`, the last_commit of the block at `height` + 1, carries
+        another id than the block's at `height`: 0 if that block's server
+        lied, 1 if its successor's did. Decidable: either the commit's
+        signatures verify over the id it carries (then more than 2/3
+        signed another block at that height, and ours is not it), or
+        they do not (the successor made its commit up). One synchronous
+        K=1 verify against the current set, which is that height's
+        (`_claim_window` holds a window to one set), on the default
+        queue: the reactor's own may be full of windows in flight. Only
+        this path pays for it."""
+        with _stage("verify_wait", stages):
+            signed = self._commit_verifies(commit.block_id, height, commit)
+        return 0 if signed else 1
+
+    def _commit_verifies(self, block_id, height: int, commit) -> bool:
+        """One commit against the current set, synchronously (the K=1
+        launch consensus makes too); its launch record names the height,
+        as a window's names its own."""
+        try:
+            with _launchlog.tag(height_lo=height, height_hi=height):
+                self.state.validators.verify_commit(
+                    self.state.chain_id,
+                    block_id,
+                    height,
+                    commit,
+                    verifier=self.verifier,
+                    consumer="fastsync",
+                )
+        except ValidationError:
+            return False
+        return True
 
     def _join_and_apply(self, entry) -> bool:
         """Join one window's in-flight verdict, then store + apply its
-        blocks. False means the window failed and the pool suffix was
-        redone — the caller must discard younger in-flight windows."""
+        blocks. False means the window failed past its verified prefix
+        and the refuted block was redone — the caller must discard
+        younger in-flight windows."""
         try:
             return self._apply_window(entry)
         finally:
@@ -458,25 +535,39 @@ class BlockchainReactor(Reactor):
 
     def _apply_window(self, entry) -> bool:
         stages = entry["stages"]
+        blocks, parts = entry["blocks"], entry["parts"]
+        refused = None
+        verified = entry["apply_n"]
         try:
             with _stage("verify_wait", stages):
                 entry["handle"].result()
-        except ValidationError:
-            self._redo(entry["start_height"])
-            return False
-        blocks, parts = entry["blocks"], entry["parts"]
-        for i in range(entry["apply_n"]):
+        except ValidationError as e:
+            # the verdict names the first entry that failed; the ones
+            # before it passed (`ErrCommitRefused`) and are applied
+            refused = getattr(e, "entry", 0)
+            verified = refused if getattr(e, "prefix_verified", False) else 0
+        for i in range(verified):
             commit = blocks[i + 1].last_commit
             try:
                 self._store_and_apply(blocks[i], parts[i], commit, stages)
             except ValidationError:
                 # commit verified but the block body is inconsistent
                 # (possible only past a 2/3-byzantine signer set):
-                # drop the suffix + serving peer rather than spin
-                self._redo(blocks[i].header.height)
+                # redo the block + drop its server rather than spin
+                self._redo(blocks[i].header.height, "body")
                 return False
             self._log_progress()
-        return True
+        if refused is None:
+            return True
+        # the commit that failed travelled in the NEXT block: that
+        # block's server made it up. The refused entry's own block,
+        # whose id the commit does carry, is neither proved nor refuted
+        # and stays in the pool
+        FASTSYNC_PREFIX_BLOCKS_APPLIED.inc(verified)
+        self._redo(
+            blocks[refused + 1].header.height, "verdict", prefix_applied=verified
+        )
+        return False
 
     def _store_and_apply(self, block, parts, commit, stages: dict) -> None:
         with _stage("store", stages):
@@ -495,6 +586,8 @@ class BlockchainReactor(Reactor):
         self.pool.pop()
         self.blocks_synced += 1
         FASTSYNC_BLOCKS_APPLIED.inc()
+        if self._redone:
+            self._recovered(block.header.height)
 
     def _close_window(self, entry) -> None:
         """One `fastsync.window` span and one count a window, from its
@@ -564,41 +657,61 @@ class BlockchainReactor(Reactor):
         with _stage("part_set", stages):
             parts = block.make_part_set()
             block_id = BlockID(block.hash(), parts.header)
+        height = block.header.height
+        # synchronous: the whole verify is a wait nothing hides
         if commit.block_id != block_id:
-            self._redo(block.header.height)
+            self._redo(height + self._who_lied(commit, height, stages), "block_id")
+            return
+        with _stage("verify_wait", stages):
+            signed = self._commit_verifies(block_id, height, commit)
+        if not signed:
+            # the commit is the successor's last_commit: its server's lie
+            self._redo(height + 1, "verdict")
             return
         try:
-            # synchronous: the whole verify is a wait nothing hides; its
-            # launch record names the height, as a window's names its own
-            height = block.header.height
-            with _stage("verify_wait", stages), _launchlog.tag(
-                height_lo=height, height_hi=height
-            ):
-                self.state.validators.verify_commit(
-                    self.state.chain_id,
-                    block_id,
-                    block.header.height,
-                    commit,
-                    verifier=self.verifier,
-                    consumer="fastsync",
-                )
             self._store_and_apply(block, parts, commit, stages)
         except ValidationError:
-            self._redo(block.header.height)
+            self._redo(height, "body")
 
-    def _redo(self, height: int) -> None:
-        """Bad block/commit: drop the chain suffix and the peer that
-        served it (reference `RedoRequest` + peer eviction). A block
-        whose commit fails verification cannot be produced honestly —
-        debit the server's misbehavior score so a lying fast-sync peer
-        gets banned, not just disconnected-and-redialed."""
-        bad_peer = self.pool.redo(height)
+    def _redo(self, height: int, cause: str, prefix_applied: int = 0) -> None:
+        """The block at `height` cannot be what the chain committed:
+        forget it with whatever else its server delivered, and debit and
+        drop that server (reference `RedoRequest` + peer eviction). Such
+        a block cannot be produced honestly, so the server's misbehavior
+        score is debited and a lying fast-sync peer gets banned, not just
+        disconnected-and-redialed. One `fastsync.redo` span and one count
+        a call; the time to the apply of `height` is the redo's cost
+        (`_recovered`)."""
+        t0 = time.time()
+        bad_peer, blamed, others = self.pool.redo(height)
+        FASTSYNC_REDOS.labels(cause=cause).inc()
+        FASTSYNC_REDO_BLOCKS_DROPPED.labels(whose="blamed").inc(blamed)
+        FASTSYNC_REDO_BLOCKS_DROPPED.labels(whose="others").inc(others)
+        self._redone.setdefault(height, time.monotonic())
+        self._redo_pending = True
         if bad_peer:
             if self.switch is not None:
                 self.switch.report_misbehavior(
                     bad_peer, "forged_block", detail=f"height {height}"
                 )
             self._drop_peer(bad_peer, "bad fast-sync block")
+        TRACER.add(
+            "fastsync.redo",
+            t0,
+            time.time(),
+            height=height,
+            cause=cause,
+            peer=(bad_peer or "")[:12],
+            dropped_blamed=blamed,
+            dropped_others=others,
+            prefix_applied=prefix_applied,
+        )
+
+    def _recovered(self, height: int) -> None:
+        """`height` was applied: close every redo called at or below it."""
+        now = time.monotonic()
+        for h in [h for h in self._redone if h <= height]:
+            FASTSYNC_REDO_RECOVER_SECONDS.observe(now - self._redone.pop(h))
 
     def _drop_peer(self, peer_id: str, reason: str) -> None:
         self.pool.remove_peer(peer_id)

@@ -425,6 +425,7 @@ SPAN_CATALOG = frozenset(
         "scenario.run",
         "batcher.flush",
         "dispatch.launch",
+        "fastsync.redo",
         "fastsync.window",
         "tables.build",
         "tx.e2e",
@@ -625,6 +626,49 @@ for _stage in FASTSYNC_STAGES + FASTSYNC_CHILD_STAGES:
     FASTSYNC_STAGE_CPU_SECONDS.labels(stage=_stage).inc(0)
 for _cut in FASTSYNC_CUTS:
     FASTSYNC_WINDOWS.labels(cut=_cut).inc(0)
+
+# A redo (`BlockchainReactor._redo`): a block that cannot be what the
+# chain committed is forgotten with whatever else its server delivered,
+# and the server is debited and dropped. `cause` is what found it:
+# `block_id` (a block's id is not the one its successor's commit
+# carries), `verdict` (a window's commit failed verification), `prep`
+# (a malformed commit, refused before any launch), `body` (a verified
+# block the state refused).
+
+FASTSYNC_REDO_CAUSES = ("block_id", "verdict", "prep", "body")
+
+FASTSYNC_REDOS = Counter(
+    "tendermint_fastsync_redos_total",
+    "Fast-sync redos, by what found the fault: block_id (a block's id "
+    "against its successor's commit), verdict (a commit's signatures or "
+    "power), prep (a malformed commit), body (a verified block the "
+    "state refused)",
+    labelnames=("cause",),
+)
+FASTSYNC_REDO_BLOCKS_DROPPED = Counter(
+    "tendermint_fastsync_redo_blocks_dropped_total",
+    "Downloaded blocks a redo made the pool forget: blamed (the debited "
+    "peer served them) or others (anyone else did: fetched again for "
+    "nothing; 0 since a redo forgets the blamed peer's blocks alone)",
+    labelnames=("whose",),
+)
+FASTSYNC_PREFIX_BLOCKS_APPLIED = Counter(
+    "tendermint_fastsync_prefix_blocks_applied_total",
+    "Blocks applied from the verified prefix of a window whose verdict "
+    "failed (the entries before the one the verdict names)",
+)
+FASTSYNC_REDO_RECOVER_SECONDS = Histogram(
+    "tendermint_fastsync_redo_recover_seconds",
+    "From a redo to the apply of the height it was called at: the "
+    "standstill a fault costs (the refetch from another peer, the "
+    "window prepared again)",
+    buckets=LATENCY_BUCKETS,
+)
+
+for _cause in FASTSYNC_REDO_CAUSES:
+    FASTSYNC_REDOS.labels(cause=_cause).inc(0)
+for _whose in ("blamed", "others"):
+    FASTSYNC_REDO_BLOCKS_DROPPED.labels(whose=_whose).inc(0)
 
 # -- a vote's bytes (types/vote.py, types/block.py) ---------------------------
 #

@@ -12,6 +12,33 @@ class ValidationError(TMError):
     """A structure failed ValidateBasic-style checks."""
 
 
+class ErrCommitRefused(ValidationError):
+    """A commit of a batch was refused, and which: `entry` is its index in
+    the batch and `height` its height, `validator` the index of the
+    validator whose signature failed (None where the commit is malformed
+    or carries too little voting power). `prefix_verified` says whether
+    every entry before `entry` passed: it does when the tally refused the
+    commit (`ValidatorSet._tally_commit_verdicts` walks a batch in order
+    and raises at the first commit that fails), and not when the commit
+    was malformed, which is found before anything is verified. Fast-sync
+    applies a failed window's verified prefix and blames the server of
+    the block that carried this commit, the one at height + 1."""
+
+    def __init__(
+        self,
+        message: str,
+        entry: int,
+        height: int,
+        validator: int | None = None,
+        prefix_verified: bool = False,
+    ) -> None:
+        super().__init__(message)
+        self.entry = entry
+        self.height = height
+        self.validator = validator
+        self.prefix_verified = prefix_verified
+
+
 class VoteError(TMError):
     pass
 

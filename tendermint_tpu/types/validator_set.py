@@ -16,7 +16,11 @@ from tendermint_tpu.crypto import PubKey
 from tendermint_tpu.merkle import simple_hash_from_byte_slices
 from tendermint_tpu.telemetry.metrics import VALSET_HASHES
 from tendermint_tpu.types.block_id import BlockID
-from tendermint_tpu.types.errors import ErrTooMuchChange, ValidationError
+from tendermint_tpu.types.errors import (
+    ErrCommitRefused,
+    ErrTooMuchChange,
+    ValidationError,
+)
 from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT
 
 
@@ -317,10 +321,7 @@ class ValidatorSet:
                 chain_id, entries, verifier, consumer=consumer
             ).result()
             return
-        collected = [
-            self._collect_commit_sigs(chain_id, bid, h, c)
-            for bid, h, c in entries
-        ]
+        collected = self._collect_entries(chain_id, entries)
         n = len(self.validators)
         if hasattr(verifier, "verify_commits") and any(
             triples for triples, _ in collected
@@ -358,10 +359,7 @@ class ValidatorSet:
             from tendermint_tpu.services.verifier import default_verifier
 
             verifier = default_verifier()
-        collected = [
-            self._collect_commit_sigs(chain_id, bid, h, c)
-            for bid, h, c in entries
-        ]
+        collected = self._collect_entries(chain_id, entries)
         n = len(self.validators)
 
         from tendermint_tpu.services.batcher import consumer_kwargs
@@ -427,28 +425,48 @@ class ValidatorSet:
             for ei, (_, indices) in enumerate(collected)
         ]
 
+    def _collect_entries(self, chain_id: str, entries) -> list:
+        """`_collect_commit_sigs` over a batch; a malformed commit is
+        refused by its place in the batch, as a failed verdict is."""
+        collected = []
+        for ei, (block_id, height, commit) in enumerate(entries):
+            try:
+                collected.append(
+                    self._collect_commit_sigs(chain_id, block_id, height, commit)
+                )
+            except ValidationError as e:
+                raise ErrCommitRefused(str(e), entry=ei, height=height) from e
+        return collected
+
     def _tally_commit_verdicts(self, entries, collected, ok_by_entry) -> None:
-        """Shared quorum walk: raises naming the failing validator (and
-        entry, when K > 1), else requires >2/3 power per entry."""
+        """Shared quorum walk, in the batch's order: raises at the first
+        entry that fails, naming it (`ErrCommitRefused`: its index, its
+        height and, for a bad signature, the validator; in the message
+        the entry when K > 1), else requires >2/3 power per entry. So
+        every entry before the one named is verified."""
         for ei, ((block_id, height, commit), (_, indices), oks) in enumerate(
             zip(entries, collected, ok_by_entry)
         ):
             tallied = 0
             for ok, idx in zip(oks, indices):
                 if not ok:
-                    where = (
-                        f" (batch entry {ei}, height {height})"
-                        if len(entries) > 1
-                        else ""
-                    )
-                    raise ValidationError(
-                        f"invalid commit signature from validator {idx}{where}"
+                    raise ErrCommitRefused(
+                        f"invalid commit signature from validator {idx}"
+                        + _where(entries, ei, height),
+                        entry=ei,
+                        height=height,
+                        validator=idx,
+                        prefix_verified=True,
                     )
                 if commit.precommits[idx].block_id == block_id:
                     tallied += self.validators[idx].voting_power
             if not tallied * 3 > self._total * 2:
-                raise ValidationError(
+                raise ErrCommitRefused(
                     f"insufficient voting power: {tallied} of {self._total}"
+                    + _where(entries, ei, height),
+                    entry=ei,
+                    height=height,
+                    prefix_verified=True,
                 )
 
     def verify_commit_any(
@@ -520,6 +538,11 @@ class ValidatorSet:
 
     def __repr__(self) -> str:
         return f"ValidatorSet(n={len(self.validators)}, power={self._total})"
+
+
+def _where(entries, ei: int, height: int) -> str:
+    """A refused commit's place in its batch, for the message (K > 1)."""
+    return f" (batch entry {ei}, height {height})" if len(entries) > 1 else ""
 
 
 def _verify_triples(

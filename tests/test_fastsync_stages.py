@@ -162,7 +162,7 @@ class TestWindows:
         assert sum(rise["cut." + c] for c in FASTSYNC_CUTS) == len(spans)
         assert [w["height_lo"] for w in spans] == sorted(w["height_lo"] for w in spans)
 
-    def test_a_window_refused_at_its_join_is_counted_and_applies_nothing(self):
+    def test_a_window_refused_at_its_join_is_counted_and_applies_its_verified_prefix(self):
         sim = chain(40)
         # height 39's commit rides in the last block, which nothing links
         # past: its signatures are well formed and wrong, so the window
@@ -174,13 +174,16 @@ class TestWindows:
             )
         before = Readings()
         _reactor, store = synced(sim)
-        assert store.height == 32
+        # the verdict names entry 6, height 39: the six entries before it
+        # are verified and applied (none was, until PR 47)
+        assert store.height == 38
         rise, spans = before.rise(), before.windows()
-        assert rise["blocks"] == 32
+        assert rise["blocks"] == 38
         assert [(w["height_lo"], w["cut"], "store_s" in w) for w in spans] == [
-            (1, "full", True), (17, "full", True), (33, "pool_gap", False)
+            (1, "full", True), (17, "full", True), (33, "pool_gap", True)
         ]
         assert sum(rise["cut." + c] for c in FASTSYNC_CUTS) == 3
+        assert rise["count.store"] == 38
 
 
 class TestApplyBlock:
