@@ -703,6 +703,18 @@ VOTE_WIRE_KEPT = Counter(
     "bytes): the validator count a fast-synced block, 0 for a vote with "
     "a padded varint, which is encoded for itself on first use",
 )
+BLOCK_DATA_ENCODES = Counter(
+    "tendermint_block_data_encodes_total",
+    "Data.encode calls (a block's data section: the tx count and each tx "
+    "under its length), one a call: kept (the section Data.decode_from read "
+    "the txs from was handed back: no varint in it padded, nothing after "
+    "its last tx, txs not reassigned) or walked (encoded tx by tx: a block "
+    "that was made, or a section a decoder takes and an encoder would not "
+    "write). A fast-synced block is encoded once, for its part set",
+    labelnames=("how",),
+)
+for _how in ("kept", "walked"):
+    BLOCK_DATA_ENCODES.labels(how=_how).inc(0)
 COMMIT_VOTES_DECODED = Counter(
     "tendermint_commit_votes_decoded_total",
     "Precommits Commit.decode_from read, added once a commit decoded: "

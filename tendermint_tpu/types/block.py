@@ -8,20 +8,23 @@ batchable through the TreeHasher.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import ClassVar
 
 from tendermint_tpu.codec import Reader, Writer, encode_string, encode_uvarint
 from tendermint_tpu.merkle import simple_hash_from_byte_slices, simple_hash_from_map
-from tendermint_tpu.telemetry.metrics import COMMIT_SIGNBYTES
+from tendermint_tpu.telemetry.metrics import BLOCK_DATA_ENCODES, COMMIT_SIGNBYTES
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.errors import ValidationError
 from tendermint_tpu.types.part_set import DEFAULT_PART_SIZE, PartSet
-from tendermint_tpu.types.tx import Txs
+from tendermint_tpu.types.tx import FrozenTxs, Txs
 from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT, Vote, decode_commit_votes
 from tendermint_tpu.utils.bit_array import BitArray
 
 
 _SIGNBYTES_ENCODED = COMMIT_SIGNBYTES.labels(source="encoded")
 _SIGNBYTES_SHARED = COMMIT_SIGNBYTES.labels(source="shared")
+_DATA_ENCODES_KEPT = BLOCK_DATA_ENCODES.labels(how="kept")
+_DATA_ENCODES_WALKED = BLOCK_DATA_ENCODES.labels(how="walked")
 
 
 @dataclass
@@ -236,10 +239,28 @@ class EvidenceData:
 class Data:
     txs: Txs = field(default_factory=Txs)
 
+    # The section `decode_from` read `txs` from, kept where it is what
+    # `encode()` would write. No dataclass field (`__eq__` and `repr` never
+    # see it), and it belongs to those txs: they are a `FrozenTxs`, and
+    # assigning `txs` drops it.
+    _section: ClassVar[bytes | None] = None
+
+    def __setattr__(self, name: str, value) -> None:
+        if name == "txs":
+            self.__dict__.pop("_section", None)
+        object.__setattr__(self, name, value)
+
     def hash(self, hasher=None) -> bytes:
         return self.txs.hash(hasher)
 
     def encode(self) -> bytes:
+        """The count and each tx under its length. A section that came off
+        the wire in this very form is handed back and not rebuilt."""
+        section = self._section
+        if section is not None:
+            _DATA_ENCODES_KEPT.inc()
+            return section
+        _DATA_ENCODES_WALKED.inc()
         w = Writer().uvarint(len(self.txs))
         for tx in self.txs:
             w.bytes(tx)
@@ -247,8 +268,40 @@ class Data:
 
     @classmethod
     def decode_from(cls, r: Reader) -> "Data":
+        """The section off `r`, split in one loop: a tx's length is most
+        often one byte, read in place; a longer one goes through
+        `Reader.uvarint`, which also says whether it was padded. The bounds
+        are `Reader.bytes`' own, and nothing is sized by the count before
+        the bytes for it are there. The section is kept as the encoding
+        when, and only when, it is what `encode()` would write: all of
+        `r`'s bytes, no varint in them padded, and none after the last tx
+        (a decoder ignores bytes that trail, an encoder writes none)."""
+        data, start = r.data, r.offset
         n = r.uvarint()
-        return cls(txs=Txs(r.bytes() for _ in range(n)))
+        at, size = r.offset, len(data)
+        txs = Txs()
+        append = txs.append
+        for _ in range(n):
+            if at < size and (k := data[at]) < 0x80:
+                at += 1
+            else:
+                r.offset = at
+                k = r.uvarint()
+                at = r.offset
+            end = at + k
+            if end > size:
+                raise ValueError("truncated bytes")
+            append(data[at:end])
+            at = end
+        r.offset = at
+        if type(data) is not bytes:
+            return cls(txs=Txs(map(bytes, txs)))
+        if r.padded or start or at != size:
+            return cls(txs=txs)
+        d = cls(txs=FrozenTxs(txs))
+        # the object `Block.decode` copied out of the block, not a copy of it
+        d._section = data
+        return d
 
 
 @dataclass

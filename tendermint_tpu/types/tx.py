@@ -44,6 +44,19 @@ class Txs(list):
         return -1
 
 
+class FrozenTxs(Txs):
+    """The txs `Data.decode_from` read from a section whose bytes the `Data`
+    keeps as its encoding: a `Txs` that refuses change in place, so the kept
+    bytes stay the bytes of these txs. `Txs(them)` is a copy that can be
+    changed; assigning it to `data.txs` drops the kept bytes."""
+
+    def _frozen(self, *args, **kwargs):
+        raise TypeError("the txs of a decoded data section are read-only: assign data.txs a new Txs")
+
+    __setitem__ = __delitem__ = __iadd__ = __imul__ = _frozen
+    append = extend = insert = pop = remove = clear = sort = reverse = _frozen
+
+
 @dataclass
 class TxProof:
     """Inclusion proof of one tx in a block's data hash
